@@ -20,6 +20,7 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mlcomp_tpu.models.base import register_model
@@ -27,7 +28,6 @@ from mlcomp_tpu.models.transformer import TransformerConfig
 from mlcomp_tpu.parallel.pipeline import (
     merge_microbatches, pipeline_apply, split_microbatches, stage_apply,
 )
-from mlcomp_tpu.parallel.ring import shard_map
 
 
 def _rms_norm(h, scale, eps=1e-6):
@@ -164,7 +164,8 @@ class PipelinedTransformerLM(nn.Module):
 
             run = shard_map(
                 pipelined, mesh=self.mesh,
-                in_specs=(param_spec, act_spec), out_specs=act_spec)
+                in_specs=(param_spec, act_spec), out_specs=act_spec,
+                check_vma=False)
             h = run(raw, h)
         else:
             h = stage_apply(layer_fn, raw, h)

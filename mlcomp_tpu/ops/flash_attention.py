@@ -51,14 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas is TPU/GPU-oriented; tolerate CPU-only installs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from mlcomp_tpu.ops._compat import tpu_compiler_params
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -262,7 +256,7 @@ def flash_attention_forward(q, k, v, causal: bool = True,
             pltpu.VMEM((block_q, 128), jnp.float32),   # normaliser
             pltpu.VMEM((block_q, d), jnp.float32),     # output accum
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(qf, kf, vf)
@@ -413,7 +407,7 @@ def flash_attention_backward(q, k, v, out, lse, do,
         ],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
@@ -440,7 +434,7 @@ def flash_attention_backward(q, k, v, out, lse, do,
         out_specs=[k_spec, k_spec],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
@@ -538,15 +532,10 @@ def fused_attention(q, k, v, causal: bool = True,
     t, d = q.shape[1], q.shape[3]
     tiles = t >= 128 and t % 128 == 0
     if impl == 'auto':
-        impl = 'pallas' if (_PALLAS_OK and tiles
-                            and jax.default_backend() == 'tpu') \
+        impl = 'pallas' if (tiles and jax.default_backend() == 'tpu') \
             else 'dense'
     if impl == 'dense':
         return reference_attention(q, k, v, causal=causal, scale=scale)
-    if not _PALLAS_OK:
-        raise ImportError(
-            'jax.experimental.pallas failed to import in this '
-            'environment — use impl="dense"')
     if not tiles:
         raise ValueError(
             f'pallas attention needs seq divisible by 128, got {t}')

@@ -40,14 +40,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is TPU/GPU-oriented; tolerate CPU-only installs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from mlcomp_tpu.ops._compat import tpu_compiler_params
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def reference_norm_act(x2d, gamma, beta, eps: float = 1e-5,
@@ -148,7 +142,7 @@ def _pallas_norm_act(x2d, gamma, beta, eps, act, block_r,
             pltpu.VMEM((1, c), jnp.float32),
             pltpu.VMEM((1, c), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary', 'arbitrary')),
         interpret=interpret,
     )(x2d, gamma.reshape(1, c), beta.reshape(1, c))
@@ -161,13 +155,8 @@ def _use_pallas(impl: str, r: int, c: int) -> bool:
     # C=64, and they carry the LARGEST activations; refusing them
     # would exempt the biggest byte sites from the fused kernel
     c_ok = (c % 128 == 0) or (c >= 8 and 128 % c == 0)
-    tiles = c_ok and (r % 8 == 0) and _PALLAS_OK
+    tiles = c_ok and (r % 8 == 0)
     if impl == 'pallas' or impl == 'interpret':
-        if not _PALLAS_OK:
-            raise ValueError(
-                f'impl={impl!r} requires pallas, which failed to '
-                f'import on this install — use impl="dense" or fix '
-                f'the jax.experimental.pallas import')
         if not tiles:
             raise ValueError(
                 f'[{r}, {c}] does not tile for the fused-norm kernel '

@@ -59,30 +59,6 @@ def _probe_coordinator(address: str, timeout_s: float, rank: int,
         f'coordinator {address} after {timeout_s:.0f}s: {last_err}')
 
 
-def _enable_cpu_collectives(jax):
-    """CPU multi-process: XLA's CPU client has NO cross-process
-    collectives unless an implementation is selected BEFORE the
-    backend initializes ("Multiprocess computations aren't implemented
-    on the CPU backend" otherwise) — gloo ships in jaxlib. Real TPU
-    runs never reach the condition (their platform list doesn't lead
-    with cpu; TPU collectives ride ICI/DCN in the TPU client), and an
-    explicit user choice ('mpi') is left alone."""
-    import os
-    try:
-        platforms = str(
-            jax.config.jax_platforms
-            or os.environ.get('JAX_PLATFORMS') or '')
-        if platforms.split(',')[0].strip().lower() != 'cpu':
-            return
-        from jax._src import xla_bridge
-        if xla_bridge.CPU_COLLECTIVES_IMPLEMENTATION.value in (
-                None, 'none'):
-            jax.config.update(
-                'jax_cpu_collectives_implementation', 'gloo')
-    except Exception:
-        pass        # older/newer jax layouts: join without the assist
-
-
 def initialize_from_distr_info(distr_info: Optional[dict]) -> bool:
     """Idempotently initialize the jax distributed runtime from the
     supervisor's distr_info {coordinator_address, process_index,
@@ -94,7 +70,7 @@ def initialize_from_distr_info(distr_info: Optional[dict]) -> bool:
     whose sibling died at dispatch strands every survivor at the
     coordinator forever — with it the stranded rank fails fast as
     ``GangPeerLost`` (taxonomy ``gang-peer-lost``) where the failure
-    is catchable (dead-coordinator TCP probe, jax versions that raise)
+    is catchable (dead-coordinator TCP probe, a join that raises)
     and as a bounded process abort where xla's coordination client
     ``LOG(FATAL)``s (a missing middle peer) — either way the rank
     dies within the bound, the gang verdict aggregates, and the whole
@@ -107,7 +83,6 @@ def initialize_from_distr_info(distr_info: Optional[dict]) -> bool:
     if _state['initialized']:
         return True
     import jax
-    _enable_cpu_collectives(jax)
     timeout = distr_info.get('join_timeout_s')
     rank = int(distr_info.get('process_index') or 0)
     gang = distr_info.get('gang') or {}
@@ -129,13 +104,7 @@ def initialize_from_distr_info(distr_info: Optional[dict]) -> bool:
     if remaining:
         kwargs['initialization_timeout'] = max(1, int(remaining))
     try:
-        try:
-            jax.distributed.initialize(**kwargs)
-        except TypeError:
-            # older jax without initialization_timeout: join unbounded
-            # (the gang-stall watchdog still reaps the strand)
-            kwargs.pop('initialization_timeout', None)
-            jax.distributed.initialize(**kwargs)
+        jax.distributed.initialize(**kwargs)
     except Exception as e:
         from mlcomp_tpu.recovery import GangPeerLost
         text = f'{type(e).__name__}: {e}'.lower()

@@ -21,22 +21,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# jax >= 0.8 renamed check_rep -> check_vma
-_CHECK_KW = ('check_vma' if 'check_vma'
-             in inspect.signature(_shard_map).parameters else 'check_rep')
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check})
 
 NEG_INF = -1e30
 
@@ -137,7 +123,7 @@ def make_ring_attention(mesh: Mesh, causal: bool = False,
         # dense off-TPU, inside the same spec
         @functools.partial(
             shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=spec)
+            out_specs=spec, check_vma=False)
         def sharded_local(q, k, v):
             return fused_attention(q, k, v, causal=causal,
                                    impl=attn_impl)
@@ -160,7 +146,7 @@ def make_ring_attention(mesh: Mesh, causal: bool = False,
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=spec)
+        out_specs=spec, check_vma=False)
     def sharded(q, k, v):
         return ring_attention(q, k, v, axis_name='sp', axis_size=sp,
                               causal=causal)

@@ -43,6 +43,11 @@ oom             permanent  RESOURCE_EXHAUSTED / device out-of-memory
                            TPU slot re-deriving the same crash. The
                            flight recorder persists a postmortem
                            bundle at the failure (telemetry/memory.py)
+no-accelerator  permanent  a task the supervisor placed on TPU cores came
+                           up on the CPU backend (worker/tasks.py
+                           ``pin_cores``): the TPU runtime did not
+                           start — a host fault to look at, never a run
+                           to carry on with on the host
 executor-error  permanent  any other executor exception (a bug retries
                            into the same bug — fail fast instead)
 ==============  =========  ==================================================
@@ -99,6 +104,12 @@ class GangPeerLost(RuntimeError):
     jax coordinator (bounded join timeout, parallel/distributed.py).
     Classified ``gang-peer-lost``: transient collateral — the gang
     verdict retries on the ROOT cause a sibling carries."""
+
+
+class AcceleratorMissing(RuntimeError):
+    """A task placed on TPU cores found no TPU in its process (jax fell
+    back to the CPU backend). Classified ``no-accelerator``:
+    permanent."""
 
 
 def is_transient(reason) -> bool:
@@ -165,6 +176,8 @@ def classify_exception(exc, gang: bool = False) -> str:
         seen.add(id(cur))
         if isinstance(cur, GangPeerLost):
             return 'gang-peer-lost'
+        if isinstance(cur, AcceleratorMissing):
+            return 'no-accelerator'
         if isinstance(cur, MemoryError):
             return 'oom'        # host-side exhaustion: same verdict
         if isinstance(cur, RuntimeError):

@@ -11,7 +11,7 @@ submit time; this module catches the *events* at runtime:
   event-duration listeners (``jax.monitoring``) and records every
   backend compile as a ``compile.backend_ms`` metric sample carrying
   the triggering train step — with a conservative no-op fallback when
-  the hooks are unavailable (older/newer jax, stripped builds): the
+  the hooks are unavailable (stripped builds): the
   loop runs exactly as before, just without compile telemetry.
   The watchdog's **recompile-storm** rule (telemetry/watchdog.py)
   turns the series into action: N compiles after warmup inside a time
@@ -36,9 +36,9 @@ import statistics
 import time
 from collections import deque
 
-#: monitoring keys that mean "XLA compiled a program" (observed on
-#: jax 0.4.x; matching is by exact name so unrelated durations —
-#: tracing, lowering — never count as compiles)
+#: monitoring keys that mean "XLA compiled a program" (matching is by
+#: exact name so unrelated durations — tracing, lowering — never
+#: count as compiles)
 COMPILE_EVENTS = ('/jax/core/compile/backend_compile_duration',)
 
 

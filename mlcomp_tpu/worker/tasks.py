@@ -1,15 +1,17 @@
 """Worker task runtime (parity: reference worker/tasks.py:29-368).
 
 ``ExecuteBuilder`` is the per-task pipeline: fetch task+dag → check status →
-mark InProgress (pid, worker index) → download code from the DB → import
-the executor → pin TPU cores → run → store the result → handle multi-stage
+mark InProgress (pid, worker index) → download code from the DB → pin TPU
+cores → import the executor → run → store the result → handle multi-stage
 requeue → Success. ``execute_by_id(id, exit=False)`` is the in-process
 debug path used by ``mlcomp_tpu execute`` (reference __main__.py:90-123).
 
 TPU specifics: instead of remapping ``CUDA_VISIBLE_DEVICES``
 (reference worker/tasks.py:188-194) we pin the runtime to the assigned TPU
-chips via ``TPU_VISIBLE_CHIPS``/``TPU_PROCESS_BOUNDS`` before jax import,
-and per-task process hygiene (reference ``os._exit(0)``,
+chips via ``TPU_VISIBLE_CHIPS`` and the process/chip bounds before the
+jax backend starts (``chip_pin_env``), a task placed on TPU cores that
+comes up without a TPU fails ``no-accelerator`` instead of training on
+the host, and per-task process hygiene (reference ``os._exit(0)``,
 worker/tasks.py:279) stays optional because TPU runtime init is expensive —
 a persistent worker keeps the device client alive between tasks when
 ``exit=False``.
@@ -92,6 +94,49 @@ def _install_crash_flush(session):
         signal.signal(signal.SIGTERM, _on_term)
     except (ValueError, OSError):
         pass
+
+
+#: TPU_CHIPS_PER_PROCESS_BOUNDS for a process that takes n of a host's
+#: chips (x,y,z extents of the block; jax's own multi-process TPU tests
+#: use the same table)
+_CHIP_BOUNDS = {1: '1,1,1', 2: '1,2,1', 4: '2,2,1', 8: '2,4,1'}
+
+#: first port of the per-process TPU runtime endpoints: side-by-side
+#: one-chip processes must not share libtpu's default port
+_TPU_PROCESS_PORT_BASE = 8476
+
+
+def chip_pin_env(cores, host_cores: int) -> dict:
+    """Environment that restricts the TPU runtime to the chips
+    ``cores`` of a host with ``host_cores`` chips; set before the jax
+    backend starts. A task that owns the WHOLE host gets nothing — the
+    runtime's defaults describe the host — and takes libtpu's
+    host-wide lock, which is right: nobody else may use a chip of it.
+    A subset is described as a one-process slice of that shape;
+    libtpu then skips the host-wide lock, so processes on disjoint
+    chips run side by side, each with its own runtime port."""
+    cores = [int(c) for c in cores]
+    if not cores or len(cores) >= int(host_cores or 0):
+        return {}
+    if len(cores) not in _CHIP_BOUNDS:
+        raise ValueError(
+            f'cannot describe {len(cores)} chips {cores} as a TPU '
+            f'process block (supported: {sorted(_CHIP_BOUNDS)})')
+    if len(cores) == 2 and (
+            min(cores) % 2 or max(cores) != min(cores) + 1):
+        # found on a 2x2 v5e host: [0,1] and [2,3] start (side by side,
+        # too); [0,2] and [1,2] die at runtime start-up under 1,2,1
+        raise ValueError(
+            f'chips {cores} are not an aligned pair ([0,1], [2,3], ...): '
+            f'the TPU runtime does not start on them as a 1,2,1 block')
+    port = _TPU_PROCESS_PORT_BASE + min(cores)
+    return {
+        'TPU_VISIBLE_CHIPS': ','.join(str(c) for c in cores),
+        'TPU_CHIPS_PER_PROCESS_BOUNDS': _CHIP_BOUNDS[len(cores)],
+        'TPU_PROCESS_BOUNDS': '1,1,1',
+        'TPU_PROCESS_ADDRESSES': f'localhost:{port}',
+        'TPU_PROCESS_PORT': str(port),
+    }
 
 
 class ExecuteBuilder:
@@ -178,20 +223,46 @@ class ExecuteBuilder:
         os.makedirs(folder, exist_ok=True)
         return folder
 
+    def assigned_cores(self) -> list:
+        try:
+            return list(json.loads(self.task.cores_assigned or '[]'))
+        except (TypeError, ValueError):
+            return []
+
     def pin_cores(self):
         """Restrict the TPU runtime to the assigned chips before jax init
         (TPU analogue of CUDA_VISIBLE_DEVICES remapping,
         reference worker/tasks.py:188-194)."""
-        if not self.task.cores_assigned:
+        cores = self.assigned_cores()
+        if not cores:
             return
-        try:
-            cores = json.loads(self.task.cores_assigned)
-        except (TypeError, ValueError):
+        from mlcomp_tpu.db.providers import ComputerProvider
+        from mlcomp_tpu.utils.misc import hostname
+        host = ComputerProvider(self.session).by_name(
+            self.task.computer_assigned or hostname())
+        os.environ.update(chip_pin_env(
+            cores, host.cores if host is not None else 0))
+
+    def require_accelerator(self):
+        """Hold a task the supervisor placed on TPU cores to them: jax
+        falls back to the CPU backend when the TPU runtime cannot
+        start, and such a task must fail (``no-accelerator``,
+        permanent) rather than train on the host. Runs after the
+        distributed join, which must precede the first backend use. An
+        explicit ``JAX_PLATFORMS=cpu`` is the emulated-device mode of
+        the tests and is left alone."""
+        cores = self.assigned_cores()
+        if not cores or os.environ.get('JAX_PLATFORMS') == 'cpu':
             return
-        if cores:
-            os.environ['TPU_VISIBLE_CHIPS'] = ','.join(
-                str(c) for c in cores)
-            os.environ['TPU_CHIPS_PER_PROCESS_BOUNDS'] = f'1,1,{len(cores)}'
+        import jax
+        if jax.default_backend() == 'cpu':
+            from mlcomp_tpu.recovery import AcceleratorMissing
+            raise AcceleratorMissing(
+                f'task {self.task.id} was placed on TPU cores {cores} '
+                f'of {self.task.computer_assigned} but its process came '
+                f'up on the CPU backend (devices {jax.devices()}): the '
+                f'TPU runtime did not start — is another process '
+                f'holding the chip?')
 
     def init_distributed(self):
         """Join the multi-host job this service task belongs to
@@ -371,6 +442,7 @@ class ExecuteBuilder:
                 self.pin_cores()
                 with span('task.init_distributed'):
                     self.init_distributed()
+                self.require_accelerator()
                 with span('task.create_executor',
                           tags={'executor': self.task.executor}):
                     self.create_executor(folder)

@@ -516,7 +516,7 @@ def scenario_fleet_self_healing(session):
     import json as _json
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     victim = replicas[0]
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS='cpu')     # jax-free child
     env['MLCOMP_FAULTS'] = _json.dumps({'replica.crash': {
         'action': 'exit', 'after': 10,
         'when': {'replica': victim.id}}})
@@ -798,7 +798,7 @@ def scenario_supervisor_failover(session):
         tasks.append(task)
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS='cpu')     # jax-free child
     env['MLCOMP_FAULTS'] = _json.dumps({'supervisor.dispatch': {
         'action': 'exit', 'after': kill_at}})
     proc = subprocess.run(
@@ -938,7 +938,7 @@ def scenario_sweep_prune_failover(session):
         cells.append(cell)
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS='cpu')     # jax-free child
     env['MLCOMP_FAULTS'] = _json.dumps({'sweep.prune': {
         'action': 'exit', 'after': 1}})
     proc = subprocess.run(

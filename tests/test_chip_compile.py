@@ -1,0 +1,130 @@
+"""The Pallas kernels of the main path, compiled at real widths for a
+DESCRIBED v5e chip — no chip attached, nothing runs. Catches what
+interpret mode cannot: a slice off the tiling, a kernel over its VMEM
+budget, a lowering the installed jax/libtpu refuses. A compile that
+passes is not a chip run and says nothing about results or times
+(``chip_smoke.py`` runs the same kernels against their references on
+the chip).
+
+Rules this file keeps (only one process at a time may load the TPU
+library, and xdist workers each import every test file): the topology
+is described inside a module-scoped fixture — never at import, never in
+``conftest.py``, never ``autouse`` — everything compiles in the test's
+own process, and every chip-compile test lives in THIS file so one
+worker owns the library. Kernels are called with ``impl='pallas'`` /
+directly: under the fixture ``jax.default_backend()`` is still ``cpu``,
+so ``auto`` would take the dense branch.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(
+            platform='tpu', topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update('jax_enable_compilation_cache', enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip; returns the kernel-call
+    count of the compiled program."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('tpu_custom_call')
+
+
+def _flash(grad):
+    from mlcomp_tpu.ops.flash_attention import fused_attention
+
+    def fwd(q, k, v):
+        return fused_attention(q, k, v, causal=True, impl='pallas')
+
+    if not grad:
+        return fwd
+    return jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+@pytest.mark.parametrize('shape', [
+    (1, 8192, 8, 128),      # the LM flagship's T, wide heads
+    (1, 8192, 16, 64),      # the LM flagship itself (d=1024, 16 heads)
+    (4, 1024, 8, 64),
+], ids=lambda s: 'x'.join(map(str, s)))
+def test_flash_attention(one_chip, shape, grad):
+    qkv = [(shape, jnp.bfloat16)] * 3
+    # fwd is one kernel; bwd adds the dq and the dk/dv kernels
+    assert _compile(_flash(grad), one_chip, *qkv) >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+@pytest.mark.parametrize('r,c', [
+    # the four norm sites of CIFAR ResNet-18 at batch 512
+    (524288, 64), (131072, 128), (32768, 256), (8192, 512),
+])
+def test_fused_norm(one_chip, r, c, grad):
+    from mlcomp_tpu.ops.fused_norm import fused_norm_act
+
+    def fwd(x, gamma, beta):
+        return fused_norm_act(x, gamma, beta, 1e-5, True, 'pallas')
+
+    fn = fwd if not grad else jax.grad(
+        lambda x, g, b: fwd(x, g, b)[0].astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    assert _compile(fn, one_chip, ((r, c), jnp.bfloat16),
+                    ((c,), jnp.float32), ((c,), jnp.float32)) >= 1
+
+
+@pytest.mark.parametrize('w_dtype', [jnp.int8, jnp.bfloat16],
+                         ids=['int8', 'bf16'])
+def test_serving_stack(one_chip, w_dtype):
+    from mlcomp_tpu.ops.serving_stack import serving_stack
+    shapes = [((64, 8192), jnp.bfloat16), ((8, 8192, 8192), w_dtype)]
+    if w_dtype == jnp.int8:
+        shapes.append(((8, 8192), jnp.float32))
+    assert _compile(serving_stack, one_chip, *shapes) == 1
+
+
+def test_int8_matmul(one_chip):
+    from mlcomp_tpu.ops.int8_matmul import int8_matmul
+
+    def fn(x, w_qt, scale):
+        return int8_matmul(x, w_qt, scale, impl='pallas')
+
+    assert _compile(fn, one_chip, ((64, 8192), jnp.bfloat16),
+                    ((8192, 8192), jnp.int8),
+                    ((8192,), jnp.float32)) == 1
+
+
+def test_fused_ce(one_chip):
+    from mlcomp_tpu.ops.fused_ce import softmax_ce_per_example
+
+    def loss(logits, labels):
+        return softmax_ce_per_example(
+            logits, labels, impl='pallas').sum()
+
+    # fwd kernel + bwd kernel
+    assert _compile(jax.grad(loss), one_chip,
+                    ((8192, 32768), jnp.bfloat16),
+                    ((8192,), jnp.int32)) >= 2

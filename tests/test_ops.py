@@ -636,16 +636,6 @@ class TestFusedNorm:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_pallas_unavailable_message(self, monkeypatch):
-        """With pallas unimportable, an explicit impl='pallas' must
-        name the missing backend, not misreport a shape problem."""
-        from mlcomp_tpu.ops import fused_norm
-        monkeypatch.setattr(fused_norm, '_PALLAS_OK', False)
-        x, gamma, beta = self._case(r=256, c=128)
-        with pytest.raises(ValueError, match='requires pallas'):
-            fused_norm.fused_norm_act(x, gamma, beta, 1e-5, True,
-                                      'pallas')
-
     def test_narrow_channel_block(self):
         """C=64 (the CIFAR stage-1 width) rides a lane-padded block —
         the biggest byte sites must not be exempt from the kernel."""

@@ -131,6 +131,12 @@ class TestClassification:
         assert classify_exception(ConnectionResetError()) == 'io-error'
         assert classify_exception(TimeoutError()) == 'io-error'
         assert classify_exception(ValueError('bug')) == 'executor-error'
+        # a task placed on TPU cores that came up on the CPU backend:
+        # permanent, never a run carried on with on the host
+        from mlcomp_tpu.recovery import AcceleratorMissing, is_transient
+        assert classify_exception(
+            AcceleratorMissing('no TPU')) == 'no-accelerator'
+        assert not is_transient('no-accelerator')
         # deterministic OS errors never classify transient
         assert classify_exception(
             FileNotFoundError('gone')) == 'executor-error'

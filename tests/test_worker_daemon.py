@@ -458,3 +458,141 @@ class TestTpuTelemetry:
         usage = json.loads(provider.by_name(wmain.HOSTNAME).usage)
         assert usage['tpu'] == fake
         assert 'cpu' in usage and 'memory' in usage
+
+
+class TestOneProcessPerChip:
+    """Device selection for a chip that ONE process owns at a time
+    (docs/deployment.md "TPU process model")."""
+
+    def test_chip_pin_env_on_a_2x2_host(self):
+        from mlcomp_tpu.worker.tasks import chip_pin_env
+        # the whole host: the runtime's defaults, nothing set
+        assert chip_pin_env([0, 1, 2, 3], 4) == {}
+        assert chip_pin_env([0], 1) == {}
+        assert chip_pin_env([], 4) == {}
+        one = chip_pin_env([2], 4)
+        assert one['TPU_VISIBLE_CHIPS'] == '2'
+        assert one['TPU_CHIPS_PER_PROCESS_BOUNDS'] == '1,1,1'
+        assert one['TPU_PROCESS_BOUNDS'] == '1,1,1'
+        two = chip_pin_env([2, 3], 4)
+        assert two['TPU_VISIBLE_CHIPS'] == '2,3'
+        assert two['TPU_CHIPS_PER_PROCESS_BOUNDS'] == '1,2,1'
+        # side-by-side processes never share a runtime port
+        ports = {chip_pin_env([c], 4)['TPU_PROCESS_PORT']
+                 for c in range(4)}
+        assert len(ports) == 4
+        import pytest
+        with pytest.raises(ValueError, match='3 chips'):
+            chip_pin_env([0, 1, 2], 4)
+        # found on the chips: only aligned pairs start as a 1,2,1 block
+        for pair in ([0, 2], [1, 2]):
+            with pytest.raises(ValueError, match='aligned pair'):
+                chip_pin_env(pair, 4)
+
+    def test_core_probe_failure_is_an_error_not_zero(
+            self, session, monkeypatch):
+        """A probe child that dies names its stderr; an already
+        registered host keeps its count, an unknown one is fatal."""
+        import subprocess
+
+        import pytest
+
+        import mlcomp_tpu.worker.__main__ as wmain
+        from mlcomp_tpu.db.providers import ComputerProvider
+        monkeypatch.delenv('MLCOMP_TPU_CORES', raising=False)
+        # forced CPU: a CPU host by declaration, no probe at all
+        monkeypatch.setenv('JAX_PLATFORMS', 'cpu')
+        monkeypatch.setattr(
+            subprocess, 'run',
+            lambda *a, **k: pytest.fail('probed a forced-CPU host'))
+        assert wmain._tpu_core_count() == 0
+        monkeypatch.delenv('JAX_PLATFORMS')
+        monkeypatch.setattr(
+            subprocess, 'run', lambda *a, **k:
+            subprocess.CompletedProcess(a, 1, '', 'libtpu: in use'))
+        with pytest.raises(wmain.CoreProbeError, match='libtpu: in use'):
+            wmain._tpu_core_count()
+        logger = create_logger(session)
+        with pytest.raises(wmain.CoreProbeError):
+            wmain._host_cores(session, logger)       # no row: fatal
+        wmain.register_computer(session, cores=4)
+        assert wmain._host_cores(session, logger) == 4
+        assert ComputerProvider(session).by_name(
+            wmain.HOSTNAME).cores == 4
+        monkeypatch.setattr(
+            subprocess, 'run', lambda *a, **k:
+            subprocess.CompletedProcess(a, 0, 'noise\n4\n', ''))
+        assert wmain._tpu_core_count() == 4
+
+    def test_coreless_task_is_pinned_to_cpu(self, session, monkeypatch):
+        """The run-task child of a task the supervisor gave no cores
+        gets JAX_PLATFORMS=cpu; one with cores keeps the daemon's."""
+        import mlcomp_tpu.worker.__main__ as wmain
+        from mlcomp_tpu.db.models import Task
+        provider = TaskProvider(session)
+        seen = []
+
+        class FakePopen:
+            returncode = 0
+
+            def __init__(self, cmd, env):
+                seen.append(env.get('JAX_PLATFORMS'))
+
+            def wait(self):
+                pass
+
+        monkeypatch.setattr(wmain.subprocess, 'Popen', FakePopen)
+        monkeypatch.setenv('JAX_PLATFORMS', 'tpu')
+        for cores in ('[]', '[0]'):
+            task = Task(name='t', executor='e', cores_assigned=cores)
+            provider.add(task)
+            wmain._run_subprocess(task.id, 0, None, session)
+        assert seen == ['cpu', 'tpu']
+
+    def test_task_on_cores_without_a_tpu_fails(self, session,
+                                               monkeypatch):
+        """Placed on TPU cores, came up on the CPU backend: fails
+        ``no-accelerator``; the explicit emulated mode is left alone."""
+        import pytest
+
+        from mlcomp_tpu.db.models import Task
+        from mlcomp_tpu.recovery import (
+            AcceleratorMissing, classify_exception,
+        )
+        from mlcomp_tpu.worker.tasks import ExecuteBuilder
+        task = Task(name='t', executor='e', cores_assigned='[0]')
+        TaskProvider(session).add(task)
+        builder = ExecuteBuilder(task.id, session=session)
+        builder.task = task
+        builder.require_accelerator()       # JAX_PLATFORMS=cpu: emulated
+        monkeypatch.delenv('JAX_PLATFORMS')
+        with pytest.raises(AcceleratorMissing) as err:
+            builder.require_accelerator()
+        assert classify_exception(err.value) == 'no-accelerator'
+        task.cores_assigned = '[]'
+        builder.require_accelerator()       # no cores: nothing to hold
+
+    def test_compile_cache_placed_from_outside(self, tmp_path):
+        """Set: left alone. Unset: one fixed directory in the
+        checkout. Forced CPU: no default. jax is never imported."""
+        import subprocess
+        import sys
+        code = ('import os, sys, mlcomp_tpu; '
+                'assert "jax" not in sys.modules; '
+                'print(os.environ.get("JAX_COMPILATION_CACHE_DIR"))')
+        base = {k: v for k, v in os.environ.items()
+                if k not in ('JAX_COMPILATION_CACHE_DIR',
+                             'JAX_PLATFORMS')}
+        base['MLCOMP_TPU_ROOT'] = str(tmp_path / 'root')
+
+        def cache_dir(**env):
+            return subprocess.run(
+                [sys.executable, '-c', code], env=dict(base, **env),
+                capture_output=True, text=True, check=True,
+                timeout=60).stdout.strip()
+
+        import mlcomp_tpu
+        repo = os.path.dirname(os.path.dirname(mlcomp_tpu.__file__))
+        assert cache_dir() == os.path.join(repo, '.jax_cache')
+        assert cache_dir(JAX_COMPILATION_CACHE_DIR='/given') == '/given'
+        assert cache_dir(JAX_PLATFORMS='cpu') == 'None'
