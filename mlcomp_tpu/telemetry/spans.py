@@ -26,6 +26,16 @@ DB until ``flush_spans(session)`` (typically once per task, or on a
 flush cadence) hands the drained batch to one ``executemany``. When the
 ring overflows, the OLDEST spans drop and ``dropped_count`` says so —
 telemetry must never grow without bound inside a worker.
+
+One clock with the device trace: a process that trains installs an
+annotation factory (``set_annotation_factory``, from ``JaxTrain.work``
+with ``jax.profiler.TraceAnnotation``), and from then on every
+``span()`` also opens an annotation of the same name, so that in any
+``jax.profiler`` trace of that process the span is an event on a host
+line, on the device events' clock. With no trace open such an
+annotation is an inactive ``TraceMe``. This module itself never imports
+jax: the supervisor and worker daemons import it and must not bring up
+a TPU client, so without a factory the hook is one ``None`` check.
 """
 
 import itertools
@@ -49,6 +59,18 @@ _trace_context = {
     'trace_id': os.environ.get(TRACE_ID_ENV) or None,
     'process_role': os.environ.get(PROCESS_ROLE_ENV) or None,
 }
+
+
+#: ``name -> context manager`` opened around every ``span()``; None in a
+#: process that never trained (see the module doc)
+_annotation_factory = None
+
+
+def set_annotation_factory(factory):
+    """Install (or, with None, remove) the callable that gives each
+    ``span()`` its twin on the profiler's host line."""
+    global _annotation_factory
+    _annotation_factory = factory
 
 
 def new_trace_id() -> str:
@@ -171,6 +193,10 @@ def span(name: str, task: int = None, tags: dict = None,
     handle = _SpanHandle(_new_span_id(), dict(tags or {}))
     if task is None:
         task = parent_task
+    annotation = None
+    if _annotation_factory is not None:
+        annotation = _annotation_factory(name)
+        annotation.__enter__()
     stack.append((handle.span_id, task))
     started = time.time()
     t0 = time.perf_counter()
@@ -182,6 +208,8 @@ def span(name: str, task: int = None, tags: dict = None,
         raise
     finally:
         duration = time.perf_counter() - t0
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         stack.pop()
         buf.add({
             'span_id': handle.span_id, 'parent_id': parent_id,
@@ -205,8 +233,9 @@ def record_span(name: str, started: float, duration: float,
                 buffer: SpanBuffer = None, trace_id: str = None,
                 role: str = None) -> str:
     """Record an ALREADY-measured interval as a span — for code that
-    timed a phase itself (e.g. the train loop's epoch timer) and would
-    otherwise need a whole-body re-indent to use the context manager.
+    timed a phase itself (the serving gateway's and replica's request
+    timers) and has no block to put a context manager around. It opens
+    no profiler annotation: the interval is over when it is recorded.
     Parents to the enclosing open span like a nested ``with span``
     would; returns the new span id."""
     buf = buffer if buffer is not None else DEFAULT_BUFFER
@@ -250,4 +279,5 @@ def flush_spans(session, buffer: SpanBuffer = None) -> int:
 __all__ = ['span', 'record_span', 'flush_spans', 'SpanBuffer',
            'DEFAULT_BUFFER', 'current_span_id', 'new_trace_id',
            'set_trace_context', 'get_trace_context',
-           'trace_context_env', 'TRACE_ID_ENV', 'PROCESS_ROLE_ENV']
+           'trace_context_env', 'set_annotation_factory',
+           'TRACE_ID_ENV', 'PROCESS_ROLE_ENV']

@@ -31,6 +31,7 @@ Example spec::
       model_name: my_model      # optional Model-registry entry
 """
 
+import itertools
 import json
 import os
 import time
@@ -316,31 +317,36 @@ class JaxTrain(Executor):
         self._compile_events = None
         ok = False
         # the train loop's leg of the cross-process trace: a
-        # `train.work` root (role='train') with per-epoch child spans
-        # (record_span below), joined to the supervisor dispatch and
-        # worker pipeline spans by the trace id the task environment /
-        # additional_info carries (telemetry/spans.py trace context)
-        self._span_cm = None
-        if self.telemetry_spec is not None and self.session is not None \
-                and getattr(self, 'task', None) is not None:
-            from mlcomp_tpu.telemetry import span
+        # `train.work` root (role='train') with the set-up and
+        # per-epoch phase spans below it (_open_span), joined to the
+        # supervisor dispatch and worker pipeline spans by the trace id
+        # the task environment / additional_info carries
+        # (telemetry/spans.py trace context)
+        self._open_spans = []
+        self._spans_on = self.telemetry_spec is not None \
+            and self.session is not None \
+            and getattr(self, 'task', None) is not None
+        if self._spans_on:
+            from mlcomp_tpu.telemetry import spans
             info = dict(getattr(self, 'additional_info', None) or {})
-            self._span_cm = span(
-                'train.work', task=self.task.id, role='train',
-                trace_id=info.get('trace_id') or None,
-                tags={'model': self.model_spec.get('name')})
-            self._span_cm.__enter__()
+            self._span_trace_id = info.get('trace_id') or None
+            # one clock with the device trace: from here on every span
+            # of this process is also an event on the host line of any
+            # jax.profiler trace (spans.py stays importable without jax)
+            spans.set_annotation_factory(jax.profiler.TraceAnnotation)
+            self._open_span('train.work',
+                            model=self.model_spec.get('name'))
         try:
             result = self._work()
             ok = True
             return result
         finally:
-            if self._span_cm is not None:
+            if self._open_spans:
                 import sys as _sys
-                try:
-                    self._span_cm.__exit__(*_sys.exc_info())
-                except BaseException:
-                    pass       # the span re-raises the active error
+                # an exception left the phase it was raised in (and
+                # its parents) open: they close here, as errors
+                while self._open_spans:
+                    self._close_span(_sys.exc_info())
                 from mlcomp_tpu.telemetry import flush_spans
                 try:
                     flush_spans(self.session)
@@ -391,6 +397,35 @@ class JaxTrain(Executor):
                     if ok:
                         raise
 
+    # ---------------------------------------------------------------- spans
+    # The phases of _work are a flat sequence inside one long body, so
+    # they are opened and closed by statement rather than by `with`
+    # blocks (which would re-indent the whole epoch loop); work()'s
+    # `finally` closes whatever an exception left open.
+    def _open_span(self, name, **tags):
+        """Open ``name`` as a child of the innermost open span (the
+        DB row, and the profiler annotation: telemetry/spans.py). Does
+        nothing in a run without telemetry, which has no `train.work`
+        root to hang it on."""
+        if not self._spans_on:
+            return
+        from mlcomp_tpu.telemetry import span
+        cm = span(name, task=self.task.id, role='train',
+                  trace_id=self._span_trace_id, tags=tags or None)
+        cm.__enter__()
+        self._open_spans.append(cm)
+
+    def _close_span(self, exc_info=(None, None, None)):
+        """Close the innermost open span; given the active exception
+        (work()'s `finally`) it is recorded as an error."""
+        if self._open_spans:
+            self._open_spans.pop().__exit__(*exc_info)
+
+    def _next_span(self, name):
+        """The open phase ends where the next one starts."""
+        self._close_span()
+        self._open_span(name)
+
     def _drain_ckpt_writer(self):
         if self._ckpt_writer is not None:
             self._ckpt_writer.wait()
@@ -421,14 +456,17 @@ class JaxTrain(Executor):
         loss_fn = loss_for_task(self.loss_spec)
         self_supervised = self.loss_name == 'lm_ce'
 
+        # set-up spans (children of train.work, like the epochs below):
+        # `data` is the dataset's load plus its way onto the device,
+        # `state` the model and its train state (fresh, restored or
+        # pretrained), `introspect` the AOT compile of the step
+        self._open_span('train.setup.data')
         data = create_dataset(**self.dataset_spec) \
             if self.dataset_spec.get('name') else \
             create_dataset('synthetic_images')
         x_train, y_train = data['x_train'], data['y_train']
         x_valid, y_valid = data['x_valid'], data['y_valid']
         seq_dim = 1 if self_supervised and 'sp' in mesh.axis_names else None
-
-        model = create_model(mesh=mesh, **self.model_spec)
 
         # input path selection: device-resident dataset (HBM) with
         # on-device augmentation when possible — per-step host→device
@@ -478,6 +516,7 @@ class JaxTrain(Executor):
         elif self.augment:
             from mlcomp_tpu.contrib.transform import parse_transforms
             transform = parse_transforms(self.augment)
+        self._close_span()
 
         # resume (reference catalyst.py:218-296): restore last checkpoint,
         # trim completed stages
@@ -580,11 +619,13 @@ class JaxTrain(Executor):
             if not any(wants.values()):
                 return
             self._introspected = True
+            self._open_span('train.setup.introspect')
             try:
                 compiled = step_fn.lower(*abstract_args).compile()
             except Exception as e:
                 self.info(f'telemetry: step introspection skipped '
                           f'({e})')
+                self._close_span()
                 return
             if wants['cost_analysis']:
                 try:
@@ -644,6 +685,7 @@ class JaxTrain(Executor):
                             f'{stats["total_count"]} ops, '
                             f'{stats["total_bytes"] / 1e6:.1f} MB '
                             f'per device{probe}')
+            self._close_span()
 
         def stage_opt_spec(stage):
             return stage.get('optimizer') or \
@@ -657,6 +699,8 @@ class JaxTrain(Executor):
         dispatch_stage = info.get('stage') if self.stage_per_dispatch \
             else None
 
+        self._open_span('train.setup.state')
+        model = create_model(mesh=mesh, **self.model_spec)
         stage_names = [s['name'] for s in self.stages]
         # Read the checkpoint meta FIRST: the restore target's opt_state
         # structure must match the optimizer of the stage that SAVED the
@@ -801,6 +845,7 @@ class JaxTrain(Executor):
             self.info(
                 f'resumed from checkpoint: stage={meta.get("stage")} '
                 f'epoch={meta.get("epoch")} best={best}')
+        self._close_span()
         remaining, start_epoch = resume_plan(self.stages, meta)
         if dispatch_stage is not None:
             remaining = [s for s in remaining
@@ -883,10 +928,22 @@ class JaxTrain(Executor):
                     opt_state=optimizer.init(state.params))
             self.step.start(1, f'stage {stage_name}', stage_idx)
             for epoch in range(first_epoch, int(stage.get('epochs', 1))):
-                self.step.start(2, f'epoch {epoch}', epoch)
-                ep_rng = np.random.RandomState(self.seed * 1000 + epoch)
+                # a static `profile:` trace opens first, so that it
+                # holds the whole train.epoch annotation
                 profiling = self._maybe_start_profile(global_epoch,
                                                       ck_dir)
+                # the epoch's phases, in order, as spans (DB rows for
+                # every epoch, host-line annotations in any profiler
+                # trace): begin (up to the first dispatch) -> steps ->
+                # drain (wait for the last step, pull its metrics) ->
+                # valid -> report (nothing queued on the device) ->
+                # checkpoint. Per-step phases stay the step.phase.*
+                # counters.
+                self._open_span('train.epoch', epoch=global_epoch,
+                                stage=stage_name)
+                self._open_span('train.epoch.begin')
+                self.step.start(2, f'epoch {epoch}', epoch)
+                ep_rng = np.random.RandomState(self.seed * 1000 + epoch)
                 t_ep = time.time()
                 if steps_per_epoch * self.batch_size > len(x_train):
                     raise ValueError(
@@ -907,15 +964,18 @@ class JaxTrain(Executor):
                     if self.epoch_scan:
                         perm_dev = jax.device_put(
                             perm, batch_sharding(mesh, 2, batch_dim=1))
+                        self._next_span('train.epoch.steps')
                         # one XLA dispatch runs the whole epoch
                         state, metric_arrays = epoch_fn(
                             state, x_all, y_all, perm_dev)
+                        self._next_span('train.epoch.drain')
                         train_agg = {
                             k: float(np.mean(np.asarray(v)))
                             for k, v in metric_arrays.items()}
                     else:
                         train_metrics = []
                         attr = self._attribution
+                        self._next_span('train.epoch.steps')
                         for s in range(steps_per_epoch):
                             # device-data path attribution: permutation
                             # slicing is the data wait, the index
@@ -931,6 +991,7 @@ class JaxTrain(Executor):
                             state, metrics = train_step(
                                 state, x_all, y_all, idx)
                             train_metrics.append(metrics)
+                        self._next_span('train.epoch.drain')
                         train_agg = aggregate_metrics(train_metrics)
                     images_seen += steps_per_epoch * self.batch_size
                 else:
@@ -940,10 +1001,17 @@ class JaxTrain(Executor):
                         transform=transform,
                         logger=self.info if global_epoch ==
                         epochs_done_global else None)
-                    for x, y in prefetch_batches(
-                            batches, mesh, seq_dim=seq_dim,
-                            depth=self.prefetch,
-                            attribution=self._attribution):
+                    placed = iter(prefetch_batches(
+                        batches, mesh, seq_dim=seq_dim,
+                        depth=self.prefetch,
+                        attribution=self._attribution))
+                    # the first batches are shuffled, augmented and
+                    # placed before anything is dispatched: that is
+                    # still the epoch's beginning
+                    first = next(placed, None)
+                    self._next_span('train.epoch.steps')
+                    for x, y in itertools.chain(
+                            () if first is None else (first,), placed):
                         state, metrics = train_step(state, x, y)
                         train_metrics.append(metrics)
                         images_seen += self.batch_size
@@ -953,8 +1021,10 @@ class JaxTrain(Executor):
                             f'— fewer than batch_size='
                             f'{self.batch_size}; no full batch')
                     # metrics: device→host ONCE per epoch
+                    self._next_span('train.epoch.drain')
                     train_agg = aggregate_metrics(train_metrics)
                 train_dt = time.time() - t_ep
+                self._next_span('train.epoch.valid')
                 # evaluate EVERY validation sample: tail batches are
                 # padded (duplicate samples) up to a multiple of the
                 # data-parallel width, with zero weights on the padding so
@@ -992,6 +1062,7 @@ class JaxTrain(Executor):
                 valid_agg = aggregate_metrics(valid_metrics,
                                               weights=valid_weights)
 
+                self._next_span('train.epoch.report')
                 n_train = steps_per_epoch * self.batch_size
                 for k, v in train_agg.items():
                     self._report_series(k, v, global_epoch, 'train',
@@ -1044,17 +1115,6 @@ class JaxTrain(Executor):
                         self._attribution.emit_epoch(
                             tel, epoch=global_epoch)
                     tel.flush()
-                    # per-epoch child span under train.work — the
-                    # epoch timer already measured the interval, so
-                    # this is a buffered append, not a re-indent of
-                    # the whole epoch body
-                    from mlcomp_tpu.telemetry import record_span
-                    record_span(
-                        'train.epoch', started=t_ep,
-                        duration=time.time() - t_ep,
-                        task=self.task.id, role='train',
-                        tags={'epoch': global_epoch,
-                              'stage': stage_name})
                 if self._profiler is not None:
                     self._profiler.poll()
                 self.info(
@@ -1100,6 +1160,7 @@ class JaxTrain(Executor):
                     or (global_epoch + 1) % self.checkpoint_every == 0
                     or last_of_stage or sweep_rung)
                 if should_save:
+                    self._next_span('train.epoch.checkpoint')
                     meta_d = {'stage': stage_name,
                               'stage_epoch': epoch,
                               'epoch': global_epoch, 'score': score,
@@ -1141,6 +1202,8 @@ class JaxTrain(Executor):
                         else:
                             save_checkpoint(ck_dir, host_state, meta_d,
                                             best=is_best)
+                self._close_span()      # report, or checkpoint
+                self._close_span()      # train.epoch
                 if profiling:
                     self._stop_profile(global_epoch)
                 global_epoch += 1
