@@ -1760,7 +1760,7 @@ def main():
     if use_scan:
         epoch_fn = make_device_epoch_fn(
             model, optimizer, loss_fn, mesh=mesh, augment=augment,
-            dequantize=dequant)
+            dequantize=dequant, row_shape=x_train.shape[1:])
 
         def run_epoch(state, seed):
             perm_dev = jax.device_put(
@@ -1771,7 +1771,7 @@ def main():
     else:
         dev_step = make_device_train_step(
             model, optimizer, loss_fn, mesh=mesh, augment=augment,
-            dequantize=dequant)
+            dequantize=dequant, row_shape=x_train.shape[1:])
 
         def run_epoch(state, seed):
             perm = epoch_perm(seed)

@@ -1,19 +1,27 @@
 """Device-resident dataset path: the TPU-native input pipeline for
 datasets that fit in HBM.
 
-A fresh 3 MB batch per step is a host->device transfer on the critical
-path of a ~10 ms ResNet-18 step (how much of it a local chip hides
-behind the prefetch is not measured). The device path is structural,
-not incremental: put the WHOLE dataset in HBM once
-(CIFAR-10 as uint8 = 150 MB vs 16 GB HBM), then each step ships only a
-[B] int32 index vector (1 KB) and does the batch gather, dequantization,
-and augmentation ON DEVICE inside the jitted step, where XLA fuses them
-into the conv pipeline.
+Put the WHOLE dataset in HBM once (CIFAR-10 as uint8 = 150 MB vs 16 GB
+HBM); each step then ships only a [B] int32 index vector (8 KB at batch
+2,048, against a 6 MB batch) and does the batch gather, augmentation
+and dequantization ON DEVICE inside the jitted step. On the v5e trace
+(PERF.md) the host's share of a 60 ms ResNet-18 step at batch 2,048 is
+0.3 ms; how much of a per-step batch transfer a prefetch would have
+hidden is not measured.
+
+The set is held FLAT, ``[N, prod(row_shape)]`` (``place_dataset``), and
+only the gathered batch is given its row shape back
+(``gather_rows``). The chip's default layout for a resident
+``uint8[N,32,32,3]`` puts N minor-most, and the compiler answered the
+row gather by copying all N rows into a row-major layout in EVERY
+step: 3.8 ms of a 63.5 ms step whatever the batch, 0.9 ms of each
+validation step. From ``uint8[N,3072]`` the gather reads the rows where
+they lie (0.07 ms for 2,048 of them) and the step holds no other
+operation over the set.
 
 The on-device augmentations mirror contrib/transform/numpy_aug.py's
 pad-crop/flip/cutout semantics, expressed as vectorized lax ops under
-``jax.random`` so they trace once, shard over dp, and add ~zero step
-time.
+``jax.random`` so they trace once and shard over dp.
 """
 
 from typing import Optional, Sequence
@@ -144,14 +152,30 @@ def make_device_augment(augments: Sequence, image_shape):
 def place_dataset(x: np.ndarray, y: Optional[np.ndarray], mesh):
     """Put the full dataset on device, replicated across the mesh (each
     device gathers its batch shard by index — replication keeps the
-    gather local, and HBM-resident uint8 CIFAR is 150 MB/device)."""
+    gather local, and HBM-resident uint8 CIFAR is 150 MB/device). Rows
+    of rank > 1 are held flat, ``[N, prod(row_shape)]``: the step
+    makers take the ``row_shape`` and restore it on the batch."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
     rep = NamedSharding(mesh, PartitionSpec())
-    x_dev = jax.device_put(x, rep)
+    # a view on the host; why flat: the module docstring
+    x_dev = jax.device_put(x.reshape(len(x), -1) if x.ndim > 2 else x,
+                           rep)
     y_dev = jax.device_put(y, rep) if y is not None else None
     return x_dev, y_dev
+
+
+def gather_rows(x_all, idx, row_shape=None):
+    """Rows ``idx`` of the set ``place_dataset`` holds, as a
+    ``[B, *row_shape]`` batch, inside a jitted step: the gather is the
+    step's only operation over the set, the reshape touches the batch
+    alone."""
+    import jax.numpy as jnp
+    x = jnp.take(x_all, idx, axis=0)
+    if row_shape is not None:
+        x = x.reshape((x.shape[0],) + tuple(row_shape))
+    return x
 
 
 def dataset_fits_hbm(x: np.ndarray, budget_bytes: int = 2 << 30,
@@ -160,5 +184,5 @@ def dataset_fits_hbm(x: np.ndarray, budget_bytes: int = 2 << 30,
 
 
 __all__ = ['quantize_dataset', 'normalize_augment_spec',
-           'make_device_augment', 'place_dataset', 'dataset_fits_hbm',
-           'DEVICE_AUGMENTS']
+           'make_device_augment', 'place_dataset', 'gather_rows',
+           'dataset_fits_hbm', 'DEVICE_AUGMENTS']

@@ -864,11 +864,13 @@ class JaxTrain(Executor):
                 if self.epoch_scan:
                     epoch_fn = make_device_epoch_fn(
                         model, optimizer, loss_fn, mesh=mesh,
-                        augment=dev_augment, dequantize=dequant)
+                        augment=dev_augment, dequantize=dequant,
+                        row_shape=x_train.shape[1:])
                 else:
                     train_step = make_device_train_step(
                         model, optimizer, loss_fn, mesh=mesh,
-                        augment=dev_augment, dequantize=dequant)
+                        augment=dev_augment, dequantize=dequant,
+                        row_shape=x_train.shape[1:])
             else:
                 train_step = make_train_step(
                     model, optimizer, loss_fn, mesh=mesh,
@@ -919,7 +921,8 @@ class JaxTrain(Executor):
             if use_device_data:
                 from mlcomp_tpu.train.loop import make_device_eval_step
                 eval_step_dev = make_device_eval_step(
-                    model, loss_fn, mesh=mesh, dequantize=dequant_v)
+                    model, loss_fn, mesh=mesh, dequantize=dequant_v,
+                    row_shape=x_valid.shape[1:])
             first_epoch = start_epoch if stage is remaining[0] else 0
             if first_epoch == 0 and stage is not self.stages[0]:
                 # stage boundary: fresh optimizer state, keep params

@@ -25,6 +25,7 @@ from jax.sharding import Mesh
 from mlcomp_tpu.parallel.sharding import (
     logical_rules, logical_to_sharding,
 )
+from mlcomp_tpu.train.device_data import gather_rows
 
 
 class TrainState(struct.PyTreeNode):
@@ -225,20 +226,23 @@ def make_train_step(model, optimizer, loss_fn: Callable,
 def make_device_train_step(model, optimizer, loss_fn: Callable,
                            mesh: Optional[Mesh] = None,
                            augment=None, dequantize: bool = False,
-                           compute_dtype=None):
+                           compute_dtype=None, row_shape=None):
     """Device-resident-data variant of make_train_step: the step takes
-    the FULL dataset (already in HBM) plus a [B] index vector; gather,
-    dequantization, and augmentation run inside the jit where XLA fuses
-    them ahead of the first conv. Host→device traffic per step is the
-    index vector (~1 KB) instead of the batch (~MBs): the transfer
-    leaves the step's critical path (see bench.py).
+    the FULL dataset (in HBM, held flat by ``place_dataset``) plus a
+    [B] vector of row indices in the set's own order; the gather, the
+    reshape of the batch to ``row_shape``, augmentation and
+    dequantization run inside the jit. Host→device traffic per step is
+    the index vector (8 KB at batch 2,048) instead of the batch (6 MB).
+    On the v5e trace (PERF.md, PR 26) the gather of 2,048 CIFAR rows is
+    one fusion of 0.07 ms in a 59.6 ms ResNet-18 step, and the step
+    holds no other operation over the set.
     """
     import jax.numpy as jnp
 
     def step(state: TrainState, x_all, y_all, idx):
         step_rng = (jax.random.fold_in(state.rng, state.step)
                     if state.rng is not None else None)
-        x = jnp.take(x_all, idx, axis=0)
+        x = gather_rows(x_all, idx, row_shape)
         y = jnp.take(y_all, idx, axis=0) if y_all is not None else None
         if not dequantize and compute_dtype is not None:
             x = x.astype(compute_dtype)
@@ -290,7 +294,7 @@ def make_device_train_step(model, optimizer, loss_fn: Callable,
 def make_device_epoch_fn(model, optimizer, loss_fn: Callable,
                          mesh: Optional[Mesh] = None,
                          augment=None, dequantize: bool = False,
-                         compute_dtype=None):
+                         compute_dtype=None, row_shape=None):
     """One WHOLE training epoch as a single XLA computation:
     ``lax.scan`` over a [steps, batch] index permutation with the
     device-resident dataset. One dispatch per epoch removes per-step
@@ -302,7 +306,8 @@ def make_device_epoch_fn(model, optimizer, loss_fn: Callable,
 
     inner = make_device_train_step(
         model, optimizer, loss_fn, mesh=None, augment=augment,
-        dequantize=dequantize, compute_dtype=compute_dtype)
+        dequantize=dequantize, compute_dtype=compute_dtype,
+        row_shape=row_shape)
     # unwrap the jit — scan bodies must be plain traceable fns
     inner = inner.__wrapped__
 
@@ -327,14 +332,14 @@ def make_device_epoch_fn(model, optimizer, loss_fn: Callable,
 
 def make_device_eval_step(model, loss_fn: Callable,
                           mesh: Optional[Mesh] = None,
-                          dequantize: bool = False):
+                          dequantize: bool = False, row_shape=None):
     """Eval against the device-resident dataset: ships a [B] index
     vector + [B] weight vector per batch instead of the batch itself
     (the weights zero out tail padding so aggregates stay exact)."""
     import jax.numpy as jnp
 
     def step(state: TrainState, x_all, y_all, idx, w):
-        x = jnp.take(x_all, idx, axis=0)
+        x = gather_rows(x_all, idx, row_shape)
         y = jnp.take(y_all, idx, axis=0)
         if dequantize:
             x = x.astype(jnp.float32) / 255.0

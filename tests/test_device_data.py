@@ -176,6 +176,175 @@ class TestIndexedSteps:
             float(m_q['loss']), rel=1e-4)
 
 
+def _echo_setup(mesh):
+    """(model, optimizer, state) of a model that hands its input back
+    as the logits; with ``_echo_loss``, which hands them on as a
+    metric, what a step returns is the very batch it built from the
+    resident set."""
+    import flax.linen as nn
+    import jax
+    from mlcomp_tpu.train import create_train_state, make_optimizer
+
+    class Echo(nn.Module):
+        @nn.compact
+        def __call__(self, x, train: bool = False):
+            self.param('w', nn.initializers.zeros, ())
+            return x
+
+    model = Echo()
+    opt, _ = make_optimizer({'name': 'sgd', 'lr': 0.1}, 10)
+    state = create_train_state(
+        model, opt, np.zeros((8, 2), np.float32),
+        jax.random.PRNGKey(0), mesh=mesh)
+    return model, opt, state
+
+
+def _echo_loss(logits, y, weights=None):
+    import jax.numpy as jnp
+    return jnp.zeros((), jnp.float32), {'batch': logits, 'labels': y}
+
+
+def _resident_rows(case):
+    """(rows as the dataset gives them, device augment spec)."""
+    rng = np.random.RandomState(7)
+    crop_flip = [('pad_crop', {'pad': 2}), ('hflip', {})]
+    if case == 'uint8_images':
+        return rng.randint(0, 256, (64, 8, 8, 3)).astype(np.uint8), \
+            crop_flip
+    if case == 'float_images_outside_01':
+        return (rng.randn(64, 8, 8, 3) * 10).astype(np.float32), \
+            crop_flip
+    return rng.randn(64, 24).astype(np.float32), []     # rank-1 rows
+
+
+class TestFlatResidentSet:
+    """`place_dataset` holds rows flat and the step makers reshape the
+    gathered batch only (device_data.gather_rows): the model must see
+    the rows it saw when the set was held in the dataset's own shape."""
+
+    N, B = 64, 16
+
+    def _mesh(self, devices):
+        import jax
+        from jax.sharding import Mesh
+        return Mesh(np.array(jax.devices()[:devices]), ('dp',))
+
+    def _run(self, kind, mesh, x_all, y_all, perm, **kw):
+        """The batches one maker's step builds for the rows ``perm``
+        ([steps, B]), stacked."""
+        import jax
+        from mlcomp_tpu.parallel.sharding import batch_sharding
+        from mlcomp_tpu.train.loop import (
+            make_device_epoch_fn, make_device_eval_step,
+            make_device_train_step,
+        )
+        model, opt, state = _echo_setup(mesh)
+        sh1 = batch_sharding(mesh, 1)
+        if kind == 'epoch_scan':
+            fn = make_device_epoch_fn(model, opt, _echo_loss, mesh=mesh,
+                                      **kw)
+            _, m = fn(state, x_all, y_all, jax.device_put(
+                perm, batch_sharding(mesh, 2, batch_dim=1)))
+            return np.asarray(m['batch']), np.asarray(m['labels'])
+        if kind == 'eval':
+            kw.pop('augment', None)
+            fn = make_device_eval_step(model, _echo_loss, mesh=mesh,
+                                       **kw)
+            w = jax.device_put(np.ones(self.B, np.float32), sh1)
+            out = [fn(state, x_all, y_all, jax.device_put(idx, sh1), w)
+                   for idx in perm]
+        else:
+            fn = make_device_train_step(model, opt, _echo_loss,
+                                        mesh=mesh, **kw)
+            out = []
+            for idx in perm:
+                state, m = fn(state, x_all, y_all,
+                              jax.device_put(idx, sh1))
+                out.append(m)
+        return (np.stack([np.asarray(m['batch']) for m in out]),
+                np.stack([np.asarray(m['labels']) for m in out]))
+
+    @pytest.mark.parametrize('devices', [1, 8])
+    @pytest.mark.parametrize('kind', ['train', 'epoch_scan', 'eval'])
+    @pytest.mark.parametrize('case', [
+        'uint8_images', 'float_images_outside_01', 'rank1_rows'])
+    def test_batch_is_the_original_rows(self, case, kind, devices):
+        import jax
+        from mlcomp_tpu.parallel.sharding import replicated
+        from mlcomp_tpu.train.device_data import (
+            make_device_augment, place_dataset, quantize_dataset,
+        )
+        x, augs = _resident_rows(case)
+        y = np.arange(self.N, dtype=np.int32)
+        mesh = self._mesh(devices)
+        perm = np.random.RandomState(3).permutation(self.N).astype(
+            np.int32).reshape(-1, self.B)
+        x_q, dequant = quantize_dataset(x)
+        assert x_q is x and dequant == (case == 'uint8_images')
+        x_all, y_all = place_dataset(x_q, y, mesh)
+        assert x_all.shape == (self.N, int(np.prod(x.shape[1:])))
+
+        # the gathered rows, nothing else done to them: bit for bit
+        # x[idx] of the array the dataset gave, in idx's order
+        got, labels = self._run(kind, mesh, x_all, y_all, perm,
+                                row_shape=x.shape[1:])
+        assert got.dtype == x.dtype
+        np.testing.assert_array_equal(got, x[perm])
+        np.testing.assert_array_equal(labels, perm)
+
+        # as the executor builds the step (augment, dequantize): the
+        # same bits as from the set held in its own shape, which is
+        # the step as it was before the set was held flat
+        kw = dict(dequantize=dequant)
+        if augs:
+            kw['augment'] = make_device_augment(augs, x.shape[1:])
+        held_as_given = jax.device_put(x, replicated(mesh))
+        want, _ = self._run(kind, mesh, held_as_given, y_all, perm,
+                            **kw)
+        got, _ = self._run(kind, mesh, x_all, y_all, perm,
+                           row_shape=x.shape[1:], **kw)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
+        if augs and kind != 'eval':
+            assert not np.array_equal(
+                got, x[perm] / (255.0 if dequant else 1.0))
+
+    def test_lowered_gather_reads_a_rank2_set(self):
+        """Structural guard: a resident set of rank 4 made the v5e
+        compiler copy ALL of it into another layout in every step
+        (PERF.md, PR 26). Nothing in the lowered train step may have
+        the set's leading dimension and a rank above 2."""
+        import re
+        import jax
+        from mlcomp_tpu.parallel.sharding import batch_sharding
+        from mlcomp_tpu.train.device_data import (
+            make_device_augment, place_dataset,
+        )
+        from mlcomp_tpu.train.loop import make_device_train_step
+        x, augs = _resident_rows('uint8_images')
+        mesh = self._mesh(1)
+        x_all, y_all = place_dataset(
+            x, np.zeros(self.N, np.int32), mesh)
+        model, opt, state = _echo_setup(mesh)
+        step = make_device_train_step(
+            model, opt, _echo_loss, mesh=mesh, dequantize=True,
+            augment=make_device_augment(augs, x.shape[1:]),
+            row_shape=x.shape[1:])
+        text = step.lower(
+            state, x_all, y_all, jax.ShapeDtypeStruct(
+                (self.B,), np.int32,
+                sharding=batch_sharding(mesh, 1))).as_text()
+        gathers = re.findall(
+            r'stablehlo\.gather"?\(.*?:\s*\(tensor<([0-9x]+)x\w+>',
+            text)
+        operands = [tuple(int(d) for d in g.split('x'))
+                    for g in gathers]
+        assert (self.N, 8 * 8 * 3) in operands, operands
+        whole_set = set(re.findall(
+            r'tensor<(%dx[0-9x]+)x\w+>' % self.N, text))
+        assert whole_set == {'%dx%d' % (self.N, 8 * 8 * 3)}, whole_set
+
+
 class TestExecutorSelection:
     def test_jax_train_device_path_with_augment_runs(self, tmp_path):
         """auto path + on-device augmentation runs end to end (the
