@@ -11,12 +11,30 @@ as the larger of FLOPs over the peak FLOP/s and bytes over the peak
 bytes/s, both from shapes (``flops.py``). Under ``remat`` the forward
 kernel runs twice a step and is required once: recomputation does not
 count, so it costs roofline share. Which of the two bounds it is
-printed on standard error."""
+printed on standard error.
+
+The attention's shape is the configuration's: its ``kernels`` block
+gives ``q_heads``, ``head_dim`` and ``attention_layers`` where the head
+size is not ``d_model / n_heads`` or only some layers hold attention;
+a key it leaves out is derived from the model's (``n_heads``,
+``d_model // n_heads``, ``n_layers``). K and V are counted with the
+query's heads, so with fewer key-value heads the bytes are an upper
+estimate — which matters only where the kernels are bandwidth-bound."""
+
+
+def attention_shape(kernels: dict, model: dict):
+    """(query heads, head size, layers that hold attention)."""
+    heads = int(kernels.get('q_heads') or model['n_heads'])
+    head_dim = int(kernels.get('head_dim')
+                   or int(model['d_model']) // int(model['n_heads']))
+    layers = int(kernels.get('attention_layers') or model['n_layers'])
+    return heads, head_dim, layers
 
 
 def read(run, metric):
     reduced = run.reduced()
-    names = (run.config.get('kernels') or {}).get('flash_attn')
+    kernels = run.config.get('kernels') or {}
+    names = kernels.get('flash_attn')
     if not reduced or not names or run.peaks is None:
         return None
     from benchmark.trace_reduce import op_base_name
@@ -29,9 +47,8 @@ def read(run, metric):
     from benchmark.steady import job_spec
     job = job_spec(run.cell, run.config, run.seed)
     model, data = job['model'], run.cell['data']
-    seq, heads = int(data['seq_len']), int(model['n_heads'])
-    head_dim = int(model['d_model']) // heads
-    batch, layers = job['batch_size'], int(model['n_layers'])
+    seq, batch = int(data['seq_len']), job['batch_size']
+    heads, head_dim, layers = attention_shape(kernels, model)
     train = run.steps_per_epoch * batch * layers
     valid = -(-int(data['valid_rows']) // batch) * batch * layers
     fwd = flops.causal_attention(seq, heads, head_dim)

@@ -95,3 +95,26 @@ class Manifest:
                 f'device kind {device_kind!r} is not in peaks.json '
                 f'(has {sorted(table)}): add it with its source')
         return table[device_kind]
+
+
+if __name__ == '__main__':
+    # ``python3 benchmark/manifest.py --check``: for every cell the
+    # metrics it reports and what it still lacks, by the checks the
+    # benchmark's tests make (``tests/benchmark/accepted.py``); exit 1
+    # where anything is lacking or an accepted file or entry changed.
+    import argparse
+    import sys
+    parser = argparse.ArgumentParser(
+        description='BENCHMARK.json of this checkout against its own '
+                    'rules and the record of what was accepted')
+    parser.add_argument('--check', action='store_true', required=True)
+    parser.parse_args()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)    # the references import ``benchmark``
+    spec = importlib.util.spec_from_file_location(
+        'accepted', os.path.join(root, 'tests', 'benchmark', 'accepted.py'))
+    accepted = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(accepted)
+    lines, problems = accepted.report(Manifest(root))
+    print('\n'.join(lines))
+    sys.exit(1 if problems else 0)

@@ -1,8 +1,13 @@
 """benchmark/flops.py and the families' sums against hand counts."""
 
+import copy
+import json
+import os
+
 import pytest
 
-from benchmark import flops
+from benchmark import flops, trace_reduce
+from benchmark.manifest import Manifest
 from benchmark.reference import resnet, transformer_lm
 
 
@@ -54,3 +59,58 @@ def test_one_decoder_layer_by_hand():
     model['n_layers'] = 8
     per_token = transformer_lm.train_flops_per_sample(model, data) / t
     assert per_token == pytest.approx(4.04e9, rel=0.01)
+
+
+# ----------------------------------------- flash_attn_roofline's shapes
+class TracedOlmo:
+    """What ``flash_attn_roofline`` needs of a traced ``olmo-1b.steady``
+    run, with the recorded ``small_trace.json`` in the place of an
+    epoch's trace: its three 0.1 ms ``custom-call`` events stand for the
+    kernels, so the share has no meaning here, only its digits."""
+    seed, steps_per_epoch = 7, 32
+
+    def __init__(self, kernels=None):
+        here = os.path.dirname(os.path.abspath(__file__))
+        manifest = Manifest(os.path.dirname(os.path.dirname(here)))
+        with open(os.path.join(here, 'data', 'small_trace.json')) as fh:
+            self._trace = json.load(fh)
+        self.cell = manifest.cell('olmo-1b.steady')
+        self.config = copy.deepcopy(manifest.config('olmo-1b'))
+        assert self.config['kernels'] == {'flash_attn': ['attn']}
+        self.config['kernels'] = dict(kernels or {},
+                                      flash_attn=['custom-call'])
+        self.peaks = manifest.peaks('TPU v5 lite')
+        self.read = manifest.reader('flash_attn_roofline')
+        self.notes = []
+
+    def reduced(self):
+        return trace_reduce.reduce(self._trace)
+
+    def note(self, text):
+        self.notes.append(text)
+
+
+def test_flash_attn_roofline_derives_the_shape_as_it_always_did():
+    # the value the reader gave on this fixture before it knew of
+    # ``q_heads``, ``head_dim`` and ``attention_layers``: digit for digit
+    run = TracedOlmo()
+    assert run.read(run, 'flash_attn_roofline') == 91205.37467971574
+    assert run.notes == [
+        'flash_attn_roofline: kernels 0.0003 s in the traced epoch; least '
+        '0.2736 s by FLOPs, 0.1285 s by bytes -> bound by compute']
+
+
+@pytest.mark.parametrize('kernels, times', [
+    # olmo-1b's own shape, stated: 16 heads of 128 in all 8 layers
+    ({'q_heads': 16, 'head_dim': 128, 'attention_layers': 8}, 1.0),
+    # attention in one layer of four; heads twice as wide as
+    # d_model / n_heads, and half as many; each key alone
+    ({'attention_layers': 2}, 0.25),
+    ({'q_heads': 8, 'head_dim': 256}, 1.0),
+    ({'head_dim': 256}, 2.0),
+    ({'q_heads': 4}, 0.25)])
+def test_flash_attn_roofline_takes_the_shape_the_configuration_gives(
+        kernels, times):
+    run = TracedOlmo(kernels)
+    assert run.read(run, 'flash_attn_roofline') == pytest.approx(
+        91205.37467971574 * times, rel=1e-12)
