@@ -17,6 +17,7 @@ from mlcomp_tpu.models.encoders import (
 from mlcomp_tpu.models.transformer import (
     TransformerConfig, TransformerLM,
 )
+from mlcomp_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
 from mlcomp_tpu.models.unet import UNet
 from mlcomp_tpu.models.vit import ViT
 
@@ -24,6 +25,7 @@ __all__ = [
     'create_model', 'model_names', 'param_count', 'register_model',
     'MLP', 'ResNet', 'BasicBlock', 'Bottleneck',
     'TransformerConfig', 'TransformerLM', 'UNet', 'ViT',
+    'Qwen3NextConfig', 'Qwen3NextLM',
     'ResNetEncoder', 'FPN', 'LinkNet', 'PSPNet', 'DeepLabV3',
     'PipelinedTransformerLM',
     'VGGEncoder', 'DenseNetEncoder', 'EfficientNetEncoder',

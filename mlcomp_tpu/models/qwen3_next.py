@@ -1,0 +1,454 @@
+"""Qwen3-Next decoder (``model_type: qwen3_next``): gated-DeltaNet
+linear attention and gated grouped-query attention in one stack, every
+layer followed by a sparse top-k mixture of experts with a shared
+expert.
+
+The stack is made from a period: layer ``i`` holds full attention where
+``(i + 1) % full_attention_interval == 0`` and a gated DeltaNet mixer
+otherwise, so depth is a number (whole periods are scanned where there
+is more than one; layers past the last whole period are linear). Every
+block is a flax module named for what it is — ``linear_attn``,
+``full_attn``, ``moe`` — which is also its ``jax.named_scope`` on the
+device trace; the kernels inside carry names of their own
+(``gated_delta_fwd`` / ``gated_delta_bwd_scan``, ``gqa_attn``,
+``expert_matmul``).
+
+**The share.** ``experts_held`` / ``expert_offset`` tell an expert layer
+which of the ``n_experts`` it holds: ``[offset, offset + held)``. The
+router keeps all ``n_experts`` outputs, the top-k and its
+renormalisation run over all of them, the layer computes the part of
+the sum that its own experts give for the tokens routed to them, and
+the shared expert whole. What the absent experts would add is left out:
+under expert parallelism their ranks add it. No expert has a capacity:
+the (token, expert) pairs that land here are sorted by expert and go
+through grouped matrix products (``jax.lax.ragged_dot``, or the
+megablox Pallas kernel on a TPU) whatever the split between experts.
+``moe_buffer_factor`` bounds the rows of that sorted buffer at a
+multiple of the even share (``tokens * top_k * held / n_experts``);
+``None`` sizes it for the worst case, so that nothing can ever be left
+out. The ``moe.dropped`` counter says how many pairs did not fit.
+
+Counters (sown under ``intermediates``, carried out of the step by
+``train/loop.py`` and emitted per epoch by ``JaxTrain``):
+``moe.local_assign_share``, ``moe.load_max_over_mean``, ``moe.dropped``,
+``gated_delta.chunks``.
+"""
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from flax.core import meta as flax_meta
+from jax.sharding import Mesh
+
+from mlcomp_tpu.models.base import register_model
+from mlcomp_tpu.models.transformer import (
+    MlpBlock, TransformerConfig, _dense,
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class Qwen3NextConfig:
+    # the keys of the published config.json, under the repo's names
+    vocab_size: int = 151936
+    d_model: int = 2048                 # hidden_size
+    n_layers: int = 48                  # num_hidden_layers
+    full_attention_interval: int = 4
+    n_heads: int = 16                   # num_attention_heads
+    n_kv_heads: int = 2                 # num_key_value_heads
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    linear_key_heads: int = 16          # linear_num_key_heads
+    linear_value_heads: int = 32        # linear_num_value_heads
+    linear_key_dim: int = 128           # linear_key_head_dim
+    linear_value_dim: int = 128         # linear_value_head_dim
+    linear_conv_kernel: int = 4         # linear_conv_kernel_dim
+    n_experts: int = 512                # num_experts (the router's width)
+    top_k: int = 10                     # num_experts_per_tok
+    d_expert: int = 512                 # moe_intermediate_size
+    d_shared: int = 512                 # shared_expert_intermediate_size
+    norm_topk_prob: bool = True
+    rms_eps: float = 1e-6               # rms_norm_eps
+    # the share of the experts this program holds (module docstring);
+    # None = all of them
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    moe_buffer_factor: Optional[float] = None
+    # how it runs
+    dtype: str = 'bfloat16'
+    remat: bool = False
+    scan_layers: Any = 'auto'           # scan over whole periods
+    attn_impl: str = 'auto'             # ops/flash_attention.py
+    delta_impl: str = 'auto'            # ops/gated_delta.py
+    delta_chunk: int = 64
+    moe_impl: str = 'auto'              # 'gmm' | 'interpret' | 'ragged'
+
+    @property
+    def held(self):
+        return self.n_experts if self.experts_held is None \
+            else int(self.experts_held)
+
+
+def _norm(cfg, name, axes=('norm',)):
+    return nn.RMSNorm(
+        epsilon=cfg.rms_eps, dtype=jnp.dtype(cfg.dtype), name=name,
+        param_dtype=jnp.float32,
+        scale_init=nn.with_logical_partitioning(
+            nn.initializers.ones, axes))
+
+
+def _per_device(mesh, fn, n_batched, *args, n_out=1):
+    """``fn`` on each device's rows of the first ``n_batched`` arguments
+    (the others whole; ``n_out`` batch-major results): the Pallas
+    kernels see local shards. One device or data-parallel only."""
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    for axis in ('sp', 'tp', 'ep', 'pp'):
+        if mesh.shape.get(axis, 1) > 1:
+            raise NotImplementedError(
+                f'qwen3_next runs on one device or data-parallel; the '
+                f'mesh has {axis}={mesh.shape[axis]}')
+    from jax.sharding import PartitionSpec as P
+    try:
+        from jax import shard_map
+    except ImportError:                                 # older jax
+        from jax.experimental.shard_map import shard_map
+    data = tuple(a for a in ('dp', 'fsdp') if a in mesh.axis_names)
+    specs = tuple(P(data) if i < n_batched else P()
+                  for i in range(len(args)))
+    out = P(data) if n_out == 1 else (P(data),) * n_out
+    return shard_map(fn, mesh=mesh, in_specs=specs, out_specs=out,
+                     check_vma=False)(*args)
+
+
+# ---------------------------------------------------------------- rotary
+def rotary(x, theta: float, rotary_dim: int):
+    """Rotary positions on the first ``rotary_dim`` of the head
+    dimension of x [B,T,H,D] (the rest passes), halves rotated against
+    each other as the published model does."""
+    t = x.shape[1]
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    turned = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+    return jnp.concatenate([turned, x[..., rotary_dim:]], -1)
+
+
+# ------------------------------------------------------------ the mixers
+class GatedAttention(nn.Module):
+    """Grouped-query causal attention with per-head q/k RMSNorm, partial
+    rotary positions and a sigmoid output gate."""
+    cfg: Qwen3NextConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        qg = _dense((h, 2 * d), ('embed', 'heads', 'kv'), dtype,
+                    'q_proj')(x)
+        q, gate = qg[..., :d], qg[..., d:]
+        k = _dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
+                   'k_proj')(x)
+        v = _dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
+                   'v_proj')(x)
+        q = _norm(cfg, 'q_norm', ('kv',))(q)
+        k = _norm(cfg, 'k_norm', ('kv',))(k)
+        rot = int(d * cfg.partial_rotary_factor)
+        q = rotary(q, cfg.rope_theta, rot)
+        k = rotary(k, cfg.rope_theta, rot)
+        q = nn.with_logical_constraint(q, ('batch', 'seq', 'heads', 'kv'))
+
+        from mlcomp_tpu.ops.flash_attention import fused_attention
+
+        def attend(q, k, v):
+            with jax.named_scope('gqa_attn'):
+                return fused_attention(q, k, v, causal=True,
+                                       impl=cfg.attn_impl)
+
+        out = _per_device(self.mesh, attend, 3, q, k, v)
+        out = out * jax.nn.sigmoid(gate)
+        out = _dense(cfg.d_model, ('heads', 'kv', 'embed'), dtype,
+                     'o_proj', axis=(-2, -1))(out)
+        return nn.with_logical_constraint(out, ('batch', 'seq', 'embed'))
+
+
+def causal_depthwise_conv(x, kernel):
+    """y[t] = sum_j kernel[j] * x[t - (K-1) + j] over x [B,T,C] with
+    kernel [K,C]: left padding K-1, no bias."""
+    k, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + t] * kernel[j] for j in range(k))
+
+
+class GatedDeltaNet(nn.Module):
+    """The gated-DeltaNet mixer (``ops/gated_delta.py``)."""
+    cfg: Qwen3NextConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        f32 = jnp.float32
+        hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+        dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+        b, t, _ = x.shape
+        key_w, val_w = hk * dk, hv * dv
+        qkvz = _dense(2 * key_w + 2 * val_w, ('embed', 'mlp'), dtype,
+                      'in_proj_qkvz')(x)
+        ba = _dense(2 * hv, ('embed', 'heads'), dtype, 'in_proj_ba')(x)
+        conv = self.param(
+            'conv', nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (None, 'mlp')),
+            (cfg.linear_conv_kernel, 2 * key_w + val_w), jnp.float32)
+        a_log = self.param(
+            'A_log', nn.with_logical_partitioning(
+                nn.initializers.zeros, ('heads',)), (hv,), f32)
+        dt_bias = self.param(
+            'dt_bias', nn.with_logical_partitioning(
+                nn.initializers.zeros, ('heads',)), (hv,), f32)
+
+        mixed, z = qkvz[..., :2 * key_w + val_w], qkvz[..., -val_w:]
+        mixed = nn.silu(causal_depthwise_conv(mixed, conv.astype(dtype)))
+        q = mixed[..., :key_w].reshape(b, t, hk, dk)
+        k = mixed[..., key_w:2 * key_w].reshape(b, t, hk, dk)
+        v = mixed[..., 2 * key_w:].reshape(b, t, hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            ba[..., hv:].astype(f32) + dt_bias)
+
+        def unit(y):            # L2 norm over the head dimension
+            y = y.astype(f32)
+            return y * jax.lax.rsqrt(
+                jnp.sum(y * y, -1, keepdims=True) + 1e-6)
+
+        # each key head serves hv / hk value heads
+        q = jnp.repeat((unit(q) * dk ** -0.5).astype(dtype), hv // hk, 2)
+        k = jnp.repeat(unit(k).astype(dtype), hv // hk, 2)
+
+        from mlcomp_tpu.ops.gated_delta import chunk_count, \
+            gated_delta_rule
+        o = _per_device(
+            self.mesh,
+            lambda *a: gated_delta_rule(*a, chunk=cfg.delta_chunk,
+                                        impl=cfg.delta_impl),
+            5, q, k, v, g, beta)
+        self.sow('intermediates', 'gated_delta.chunks',
+                 jnp.float32(chunk_count(b, t, hv, cfg.delta_chunk)))
+        o = _norm(cfg, 'norm', ('kv',))(o)
+        o = o * nn.silu(z.reshape(b, t, hv, dv))
+        out = _dense(cfg.d_model, ('mlp', 'embed'), dtype, 'out_proj')(o.reshape(b, t, val_w))
+        return nn.with_logical_constraint(out, ('batch', 'seq', 'embed'))
+
+
+# ------------------------------------------------------------ the experts
+def grouped_matmul(lhs, rhs, group_sizes, impl: str):
+    """[M,K] x [G,K,N] -> [M,N], rows grouped by ``group_sizes``; rows
+    past their sum come back undefined (the caller masks them)."""
+    if impl == 'auto':
+        impl = 'gmm' if jax.default_backend() == 'tpu' else 'ragged'
+    with jax.named_scope('expert_matmul'):
+        if impl == 'ragged':
+            return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        tile = lambda n: min(512, n)  # noqa: E731
+        return gmm(lhs, rhs, group_sizes, lhs.dtype,
+                   (min(128, lhs.shape[0]), tile(lhs.shape[1]),
+                    tile(rhs.shape[2])), interpret=impl == 'interpret')
+
+
+def buffer_rows(cfg: Qwen3NextConfig, tokens: int) -> int:
+    """Rows of the sorted (token, expert) buffer: the worst case, or
+    ``moe_buffer_factor`` times the even share; whole row tiles of the
+    grouped product."""
+    rows = tokens * min(cfg.top_k, cfg.held)
+    if cfg.moe_buffer_factor is not None:
+        even = tokens * cfg.top_k * cfg.held / cfg.n_experts
+        rows = min(rows, int(cfg.moe_buffer_factor * even))
+    tile = 128 if tokens >= 128 else 8
+    return max(1, -(-rows // tile)) * tile
+
+
+class SparseMoe(nn.Module):
+    """Top-k routed experts on a share of the experts, with a gated
+    shared expert (module docstring)."""
+    cfg: Qwen3NextConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        f32 = jnp.float32
+        held, m, f = cfg.held, cfg.d_model, cfg.d_expert
+        if not 0 <= cfg.expert_offset <= cfg.n_experts - held:
+            raise ValueError(
+                f'experts [{cfg.expert_offset}, {cfg.expert_offset + held})'
+                f' are not among {cfg.n_experts}')
+        router = self.param(
+            'router', nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.02), ('embed', None)),
+            (m, cfg.n_experts), f32)
+
+        def experts(name, shape, axes):
+            return self.param(name, nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), axes), shape, jnp.float32)
+
+        wi_gate = experts('wi_gate', (held, m, f),
+                          ('expert', 'embed', 'mlp'))
+        wi_up = experts('wi_up', (held, m, f), ('expert', 'embed', 'mlp'))
+        wo = experts('wo', (held, f, m), ('expert', 'mlp', 'embed'))
+
+        def routed(x, router, wi_gate, wi_up, wo):
+            b, t, _ = x.shape
+            n = b * t
+            flat = x.reshape(n, m)
+            # the router over ALL experts, in float32
+            probs = jax.nn.softmax(jnp.dot(
+                flat.astype(f32), router,
+                precision=jax.lax.Precision.HIGHEST), -1)
+            top_w, top_i = jax.lax.top_k(probs, cfg.top_k)
+            if cfg.norm_topk_prob:
+                top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+            # pairs that land on a held expert, sorted by expert; the
+            # others sort behind them under the id `held`
+            local = top_i - cfg.expert_offset
+            local = jnp.where((local >= 0) & (local < held), local,
+                              held).reshape(-1)
+            rows = buffer_rows(cfg, n)
+            order = jnp.argsort(local, stable=True)[:rows]
+            token = order // cfg.top_k
+            sizes = jnp.bincount(local, length=held + 1)[:held]
+            landed = jnp.sum(sizes)
+            # groups cut to the buffer (nothing is cut at the default)
+            ends = jnp.minimum(jnp.cumsum(sizes), rows)
+            fitted = jnp.diff(ends, prepend=0).astype(jnp.int32)
+            valid = (jnp.arange(rows) < ends[-1])[:, None]
+            xs = jnp.where(valid, flat[token], 0).astype(dtype)
+            gm = lambda a, w: grouped_matmul(  # noqa: E731
+                a, w.astype(dtype), fitted, cfg.moe_impl)
+            hidden = nn.silu(gm(xs, wi_gate)) * gm(xs, wi_up)
+            # rows past the pairs that landed are undefined, in both
+            # passes: masked before anything multiplies them
+            ys = jnp.where(valid, gm(hidden, wo).astype(f32), 0) \
+                * top_w.reshape(-1)[order][:, None]
+            out = jnp.zeros((n, m), f32).at[token].add(ys)
+            mean = jnp.maximum(landed / held, 1e-9)
+            counters = jnp.stack([
+                landed / (n * cfg.top_k), jnp.max(sizes) / mean,
+                (landed - ends[-1]).astype(f32)]).astype(f32)
+            return out.astype(dtype).reshape(b, t, m), counters[None]
+
+        y, counters = _per_device(
+            self.mesh, routed, 1, x, router, wi_gate, wi_up, wo, n_out=2)
+        for i, name in enumerate(('moe.local_assign_share',
+                                  'moe.load_max_over_mean',
+                                  'moe.dropped')):
+            self.sow('intermediates', name, jnp.mean(counters[:, i]))
+
+        shared_cfg = TransformerConfig(
+            d_model=m, d_ff=cfg.d_shared, dtype=cfg.dtype)
+        shared = MlpBlock(shared_cfg, name='shared')(x)
+        gate = _dense(1, ('embed', None), dtype, 'shared_gate')(x)
+        y = y + jax.nn.sigmoid(gate) * shared
+        return nn.with_logical_constraint(y, ('batch', 'seq', 'embed'))
+
+
+# -------------------------------------------------------------- the stack
+class Qwen3NextLayer(nn.Module):
+    cfg: Qwen3NextConfig
+    full: bool
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        y = _norm(cfg, 'norm_mixer')(x)
+        if self.full:
+            x = x + GatedAttention(cfg, self.mesh, name='full_attn')(y)
+        else:
+            x = x + GatedDeltaNet(cfg, self.mesh, name='linear_attn')(y)
+        y = _norm(cfg, 'norm_moe')(x)
+        x = x + SparseMoe(cfg, self.mesh, name='moe')(y)
+        return nn.with_logical_constraint(x, ('batch', 'seq', 'embed'))
+
+
+class Qwen3NextPeriod(nn.Module):
+    """One period of the layer pattern: ``interval - 1`` linear layers
+    and a full one; the body of the scan over periods."""
+    cfg: Qwen3NextConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x, _=None):
+        cfg = self.cfg
+        layer = nn.remat(Qwen3NextLayer, prevent_cse=False) \
+            if cfg.remat else Qwen3NextLayer
+        for i in range(cfg.full_attention_interval):
+            full = i == cfg.full_attention_interval - 1
+            x = layer(cfg, full, self.mesh, name=f'layer_{i}')(x)
+        return x, None
+
+
+class Qwen3NextLM(nn.Module):
+    cfg: Qwen3NextConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        table = self.param(
+            'embed', nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.02), ('vocab', 'embed')),
+            (cfg.vocab_size, cfg.d_model), jnp.float32)
+        x = jnp.take(table, tokens, axis=0).astype(dtype)
+        x = nn.with_logical_constraint(x, ('batch', 'seq', 'embed'))
+
+        interval = cfg.full_attention_interval
+        periods = cfg.n_layers // interval
+        use_scan = periods > 1 if cfg.scan_layers == 'auto' \
+            else bool(cfg.scan_layers) and periods > 0
+        first = 0
+        if use_scan:
+            scanned = nn.scan(
+                Qwen3NextPeriod,
+                variable_axes={'params': 0, 'intermediates': 0},
+                split_rngs={'params': True}, in_axes=nn.broadcast,
+                length=periods,
+                metadata_params={flax_meta.PARTITION_NAME: 'layers'})
+            x, _ = scanned(cfg, self.mesh, name='periods')(x, None)
+            first = periods * interval
+        layer = nn.remat(Qwen3NextLayer) if cfg.remat else Qwen3NextLayer
+        for i in range(first, cfg.n_layers):
+            # preflight: disable=jax-layer-loop
+            full = (i + 1) % interval == 0
+            x = layer(cfg, full, self.mesh, name=f'layer_{i}')(x)
+
+        x = _norm(cfg, 'norm_final')(x)
+        logits = _dense(cfg.vocab_size, ('embed', 'vocab'), dtype,
+                        'lm_head')(x)
+        return nn.with_logical_constraint(
+            logits, ('batch', 'seq', 'vocab'))
+
+
+@register_model('qwen3_next')
+def _qwen3_next(mesh=None, **kwargs):
+    fields = {f.name for f in dataclasses.fields(Qwen3NextConfig)}
+    cfg = Qwen3NextConfig(
+        **{k: v for k, v in kwargs.items() if k in fields})
+    return Qwen3NextLM(cfg, mesh=mesh)
+
+
+__all__ = ['Qwen3NextConfig', 'Qwen3NextLM', 'rotary',
+           'causal_depthwise_conv', 'grouped_matmul']

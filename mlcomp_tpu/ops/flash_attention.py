@@ -62,6 +62,9 @@ def reference_attention(q, k, v, causal: bool = True,
     """Dense softmax attention over [B, T, H, D] — the numerics the
     kernel must reproduce, and the fallback/backward path."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
+    group = q.shape[2] // k.shape[2]
+    if group > 1:           # each key-value head serves `group` heads
+        k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
     s = jnp.einsum('bqhd,bkhd->bhqk', q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -159,7 +162,15 @@ def _block_live(i_q, i_k, block_q: int, block_k: int, causal: bool):
         if causal else True
 
 
-def _causal_kv_ix(block_q: int, block_k: int, causal: bool):
+def _kv_head(bh, group: int):
+    """The key-value head (folded with the batch) that query head ``bh``
+    reads: with grouped-query attention ``group`` query heads share
+    one. Equal head counts leave the index as it is."""
+    return bh if group == 1 else bh // group
+
+
+def _causal_kv_ix(block_q: int, block_k: int, causal: bool,
+                  group: int = 1):
     """Index map for operands streamed over k-blocks (grid order
     (bh, iq, ik)). ``pl.when`` skips a masked block's COMPUTE but
     Pallas still copies the tiles the index map names — half the K/V
@@ -169,26 +180,54 @@ def _causal_kv_ix(block_q: int, block_k: int, causal: bool):
     unchanged. Kernels read the TRUE ik from program_id, so masking
     and skip logic are unaffected."""
     if not causal:
-        return lambda bh, iq, ik: (bh, ik, 0)
+        return lambda bh, iq, ik: (_kv_head(bh, group), ik, 0)
 
     def ix(bh, iq, ik):
-        return (bh, jnp.minimum(ik, _last_live_k(iq, block_q, block_k)),
-                0)
+        return (_kv_head(bh, group),
+                jnp.minimum(ik, _last_live_k(iq, block_q, block_k)), 0)
     return ix
 
 
-def _causal_q_ix(block_q: int, block_k: int, causal: bool):
+def _causal_q_ix(block_q: int, block_k: int, causal: bool,
+                 group: int = 1, n_q: int = 0):
     """Dual of ``_causal_kv_ix`` for operands streamed over q-blocks
     (grid order (bh, ik, iq)): the dead steps sit BELOW the diagonal
     start, so clamp iq from below to this k-block's first live
-    q-block."""
-    if not causal:
-        return lambda bh, ik, iq: (bh, iq, 0)
+    q-block. With grouped-query attention the grid runs over key-value
+    heads and its last axis over (query head of the group, q-block):
+    step ``j`` reads q-block ``j % n_q`` of query head
+    ``bh * group + j // n_q``."""
+    if group == 1:
+        if not causal:
+            return lambda bh, ik, iq: (bh, iq, 0)
 
-    def ix(bh, ik, iq):
-        return (bh, jnp.maximum(iq, _first_live_q(ik, block_q, block_k)),
-                0)
-    return ix
+        def ix(bh, ik, iq):
+            return (bh,
+                    jnp.maximum(iq, _first_live_q(ik, block_q, block_k)),
+                    0)
+        return ix
+
+    def grouped(bh, ik, j):
+        iq = j % n_q
+        if causal:
+            iq = jnp.maximum(iq, _first_live_q(ik, block_q, block_k))
+        return (bh * group + j // n_q, iq, 0)
+    return grouped
+
+
+def _kv_group(q, k) -> int:
+    """Query heads a key-value head serves (1 = equal head counts)."""
+    h, h_kv = q.shape[2], k.shape[2]
+    if h % h_kv:
+        raise ValueError(f'{h} query heads over {h_kv} key-value heads')
+    return h // h_kv
+
+
+def _head_block(d: int, want: int) -> int:
+    """Heads wider than 128 take tiles of at most 512: at 1024 x 1024
+    the backward kernels' score-sized temporaries and the doubled q/k/v
+    tiles pass the scoped VMEM limit."""
+    return want if d <= 128 else min(want, 512)
 
 
 def _fit_block(t: int, want: int) -> int:
@@ -207,18 +246,22 @@ def flash_attention_forward(q, k, v, causal: bool = True,
                             block_q: int = 1024, block_k: int = 1024,
                             interpret: bool = False,
                             with_lse: bool = False):
-    """Pallas forward over [B, T, H, D]. T must divide by both block
+    """Pallas forward over q [B, T, H, D] and k, v [B, T, Hkv, D]
+    (grouped-query attention where Hkv < H: query head ``i`` reads
+    key-value head ``i // (H / Hkv)``). T must divide by both block
     sizes (caller falls back to dense otherwise). ``with_lse`` also
     returns the per-row logsumexp [B, H, T] the fused backward needs."""
     b, t, h, d = q.shape
+    group = _kv_group(q, k)
     scale = scale if scale is not None else d ** -0.5
-    block_q = _fit_block(t, block_q)
-    block_k = _fit_block(t, block_k)
+    block_q = _fit_block(t, _head_block(d, block_q))
+    block_k = _fit_block(t, _head_block(d, block_k))
     n_q, n_k = t // block_q, t // block_k
 
     # [B, T, H, D] -> [B*H, T, D]: contiguous (seq, head_dim) tiles
     def fold(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            b * x.shape[2], t, d)
 
     qf, kf, vf = fold(q), fold(k), fold(v)
 
@@ -228,7 +271,7 @@ def flash_attention_forward(q, k, v, causal: bool = True,
 
     # causal dead-tile DMA elision for the streamed k/v operands (see
     # _causal_kv_ix)
-    kv_ix = _causal_kv_ix(block_q, block_k, causal)
+    kv_ix = _causal_kv_ix(block_q, block_k, causal, group)
 
     out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d),
@@ -329,11 +372,14 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                       block_q, block_k, n_q):
+                       block_q, block_k, n_q, group=1):
     i_k = pl.program_id(1)
-    i_q = pl.program_id(2)
+    # the last grid axis: q-blocks, and with grouped-query attention
+    # the group's query heads one after the other (see _causal_q_ix)
+    step = pl.program_id(2)
+    i_q = step if group == 1 else step % n_q
 
-    @pl.when(i_q == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -353,7 +399,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    @pl.when(i_q == n_q - 1)
+    @pl.when(step == group * n_q - 1)
     def _finalise():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -369,13 +415,16 @@ def flash_attention_backward(q, k, v, out, lse, do,
     forward computed them. Two kernels: dq accumulates over k-blocks,
     dk/dv accumulate over q-blocks."""
     b, t, h, d = q.shape
+    group = _kv_group(q, k)
+    h_kv = h // group
     scale = scale if scale is not None else d ** -0.5
-    block_q = _fit_block(t, block_q)
-    block_k = _fit_block(t, block_k)
+    block_q = _fit_block(t, _head_block(d, block_q))
+    block_k = _fit_block(t, _head_block(d, block_k))
     n_q, n_k = t // block_q, t // block_k
 
     def fold(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            b * x.shape[2], t, d)
 
     qf, kf, vf, of, dof = fold(q), fold(k), fold(v), fold(out), fold(do)
     # row statistics live lane-tiled ([bh, t, 128]) so their blocks meet
@@ -392,7 +441,7 @@ def flash_attention_backward(q, k, v, out, lse, do,
                             lambda bh, iq, ik: (bh, iq, 0))
 
     # dead-tile DMA elision, same as the forward: dq streams k/v
-    kv_ix = _causal_kv_ix(block_q, block_k, causal)
+    kv_ix = _causal_kv_ix(block_q, block_k, causal, group)
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
@@ -413,17 +462,17 @@ def flash_attention_backward(q, k, v, out, lse, do,
     )(qf, kf, vf, dof, lsef, delta)
 
     # dk/dv streams q/do/lse/delta with iq innermost (see _causal_q_ix)
-    q_ix = _causal_q_ix(block_q, block_k, causal)
-
+    q_ix = _causal_q_ix(block_q, block_k, causal, group, n_q)
     k_spec = pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q),
+                          block_q=block_q, block_k=block_k, n_q=n_q,
+                          group=group),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype),
         ],
-        grid=(b * h, n_k, n_q),
+        grid=(b * h_kv, n_k, group * n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_ix),
             k_spec, k_spec,
@@ -440,7 +489,7 @@ def flash_attention_backward(q, k, v, out, lse, do,
     )(qf, kf, vf, dof, lsef, delta)
 
     def unfold(x):
-        return jnp.transpose(x.reshape(b, h, t, d), (0, 2, 1, 3))
+        return jnp.transpose(x.reshape(b, -1, t, d), (0, 2, 1, 3))
 
     return unfold(dq), unfold(dk), unfold(dv)
 

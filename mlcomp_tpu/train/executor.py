@@ -50,7 +50,8 @@ from mlcomp_tpu.train.data import (
     create_dataset, iterate_batches, place_batch, prefetch_batches,
 )
 from mlcomp_tpu.train.loop import (
-    aggregate_metrics, create_train_state, loss_for_task, make_eval_step,
+    STEP_COUNTERS, aggregate_metrics, create_train_state, loss_for_task,
+    make_eval_step,
     make_train_step,
 )
 from mlcomp_tpu.train.optim import make_optimizer
@@ -1067,6 +1068,10 @@ class JaxTrain(Executor):
 
                 self._next_span('train.epoch.report')
                 n_train = steps_per_epoch * self.batch_size
+                # the model's counters (train/loop.py STEP_COUNTERS):
+                # the epoch's mean over its steps, a series each
+                counters = {k: train_agg.pop(k) for k in STEP_COUNTERS
+                            if k in train_agg}
                 for k, v in train_agg.items():
                     self._report_series(k, v, global_epoch, 'train',
                                         stage_name)
@@ -1084,6 +1089,8 @@ class JaxTrain(Executor):
                         base = global_epoch * steps_per_epoch
                         for k, v in metric_arrays.items():
                             tel.series_array(k, np.asarray(v), base)
+                    for k, v in counters.items():
+                        tel.series(k, v, step=global_epoch)
                     tel.gauge('epoch_time_s', train_dt)
                     tel.gauge('epoch_throughput', n_train / train_dt)
                     if self._step_flops:
@@ -1311,7 +1318,7 @@ class JaxTrain(Executor):
             @jax.jit
             def forward(s, x):
                 with mesh, nn.logical_axis_rules(rules):
-                    logits, _, _ = _apply(model, s, x, train=False)
+                    logits = _apply(model, s, x, train=False)[0]
                     return jax.nn.softmax(
                         jnp.asarray(logits, jnp.float32))
 

@@ -140,10 +140,21 @@ def loss_for_task(task) -> Callable:
 #: weight of the MoE load-balance auxiliary loss (Switch's default)
 MOE_AUX_COEF = 0.01
 
+#: counters a model may sow under ``intermediates``, and how the values
+#: of its layers become one number a step. They leave the step beside
+#: the loss's metrics; ``JaxTrain`` emits each as a series per epoch
+STEP_COUNTERS = {
+    'moe.local_assign_share': jnp.mean,
+    'moe.load_max_over_mean': jnp.mean,
+    'moe.dropped': jnp.sum,
+    'gated_delta.chunks': jnp.sum,
+}
+
 
 def _apply(model, state: TrainState, x, train: bool, rng=None):
-    """Returns (logits, new_batch_stats, aux_loss) — aux_loss is the
-    summed sown ``moe_aux_loss`` (None when the model sows nothing)."""
+    """Returns (logits, new_batch_stats, aux_loss, counters) — aux_loss
+    is the summed sown ``moe_aux_loss`` (None when the model sows
+    none), counters the sown ``STEP_COUNTERS`` ({} likewise)."""
     variables = {'params': state.params}
     mutable = []
     if state.batch_stats is not None:
@@ -157,23 +168,38 @@ def _apply(model, state: TrainState, x, train: bool, rng=None):
                       rngs=rngs)
     if mutable:
         logits, updates = out
-        # pick out ONLY moe_aux_loss sows — other sown diagnostics must
-        # not leak into the loss
-        aux_leaves = []
+        # pick out ONLY moe_aux_loss and the counters — other sown
+        # diagnostics must not leak into the loss or the metrics
+        sown = {}
 
         def collect(tree):
             if isinstance(tree, dict):
                 for key, value in tree.items():
-                    if key == 'moe_aux_loss':
-                        aux_leaves.extend(jax.tree.leaves(value))
+                    if key == 'moe_aux_loss' or key in STEP_COUNTERS:
+                        sown.setdefault(key, []).extend(
+                            jnp.ravel(jnp.asarray(a, jnp.float32))
+                            for a in jax.tree.leaves(value))
                     else:
                         collect(value)
 
         collect(updates.get('intermediates', {}))
-        aux = sum(jnp.asarray(a, jnp.float32).sum()
-                  for a in aux_leaves) if aux_leaves else None
-        return logits, updates.get('batch_stats'), aux
-    return (out[0] if isinstance(out, tuple) else out), None, None
+        aux_leaves = sown.pop('moe_aux_loss', None)
+        aux = sum(a.sum() for a in aux_leaves) if aux_leaves else None
+        counters = {key: STEP_COUNTERS[key](jnp.concatenate(leaves))
+                    for key, leaves in sown.items()}
+        return logits, updates.get('batch_stats'), aux, counters
+    return (out[0] if isinstance(out, tuple) else out), None, None, {}
+
+
+def _with_sown(loss, metrics, aux, counters):
+    """The loss with the auxiliary loss added, the metrics with it and
+    the counters beside them."""
+    if aux is not None:
+        loss = loss + MOE_AUX_COEF * aux
+        metrics = dict(metrics, moe_aux=aux)
+    if counters:
+        metrics = dict(metrics, **jax.lax.stop_gradient(counters))
+    return loss, metrics
 
 
 def make_train_step(model, optimizer, loss_fn: Callable,
@@ -190,14 +216,12 @@ def make_train_step(model, optimizer, loss_fn: Callable,
                     if state.rng is not None else None)
 
         def loss_wrapped(params):
-            logits, new_stats, aux = _apply(
+            logits, new_stats, aux, counters = _apply(
                 model, state.replace(params=params), x, train=True,
                 rng=step_rng)
             target = x if self_supervised else y
-            loss, metrics = loss_fn(logits, target)
-            if aux is not None:
-                loss = loss + MOE_AUX_COEF * aux
-                metrics = dict(metrics, moe_aux=aux)
+            loss, metrics = _with_sown(*loss_fn(logits, target), aux,
+                                       counters)
             return loss, (metrics, new_stats)
 
         grads, (metrics, new_stats) = jax.grad(
@@ -259,13 +283,10 @@ def make_device_train_step(model, optimizer, loss_fn: Callable,
             x = x.astype(compute_dtype or jnp.float32) / 255.0
 
         def loss_wrapped(params):
-            logits, new_stats, aux = _apply(
+            logits, new_stats, aux, counters = _apply(
                 model, state.replace(params=params), x, train=True,
                 rng=step_rng)
-            loss, metrics = loss_fn(logits, y)
-            if aux is not None:
-                loss = loss + MOE_AUX_COEF * aux
-                metrics = dict(metrics, moe_aux=aux)
+            loss, metrics = _with_sown(*loss_fn(logits, y), aux, counters)
             return loss, (metrics, new_stats)
 
         grads, (metrics, new_stats) = jax.grad(
@@ -343,7 +364,7 @@ def make_device_eval_step(model, loss_fn: Callable,
         y = jnp.take(y_all, idx, axis=0)
         if dequantize:
             x = x.astype(jnp.float32) / 255.0
-        logits, _, _ = _apply(model, state, x, train=False)
+        logits = _apply(model, state, x, train=False)[0]
         _, metrics = loss_fn(logits, y, weights=w)
         return metrics
 
@@ -363,7 +384,7 @@ def make_eval_step(model, loss_fn: Callable,
                    mesh: Optional[Mesh] = None,
                    self_supervised: bool = False):
     def step(state: TrainState, x, y, w=None):
-        logits, _, _ = _apply(model, state, x, train=False)
+        logits = _apply(model, state, x, train=False)[0]
         target = x if self_supervised else y
         _, metrics = loss_fn(logits, target, weights=w)
         return metrics

@@ -128,3 +128,50 @@ def test_fused_ce(one_chip):
     assert _compile(jax.grad(loss), one_chip,
                     ((8192, 32768), jnp.bfloat16),
                     ((8192,), jnp.int32)) >= 2
+
+
+# ---- the kernels of qwen3_next at the published widths (PR 28)
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+def test_grouped_query_flash_attention(one_chip, grad):
+    """16 query heads over 2 key-value heads of 256 at 8,192 tokens:
+    the 512-tiles have to fit the scoped VMEM, dq and dk/dv included."""
+    shapes = [((2, 8192, 16, 256), jnp.bfloat16),
+              ((2, 8192, 2, 256), jnp.bfloat16),
+              ((2, 8192, 2, 256), jnp.bfloat16)]
+    assert _compile(_flash(grad), one_chip, *shapes) >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+def test_gated_delta_rule(one_chip, grad):
+    """One block of 8 value heads of 128 x 128 at 8,192 tokens."""
+    from mlcomp_tpu.ops.gated_delta import gated_delta_rule
+
+    def fwd(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta, impl='pallas')
+
+    fn = fwd if not grad else jax.grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))
+    wide = ((1, 8192, 8, 128), jnp.bfloat16)
+    gate = ((1, 8192, 8), jnp.float32)
+    assert _compile(fn, one_chip, wide, wide, wide, gate, gate) \
+        == (2 if grad else 1)
+
+
+def test_expert_grouped_matmul(one_chip):
+    """The held experts' gate, up and down products and their backward
+    (megablox gmm / tgmm) over a buffer of 40,960 routed rows."""
+    from mlcomp_tpu.models.qwen3_next import grouped_matmul
+
+    def fwd(x, gate, up, down, sizes):
+        hidden = jax.nn.silu(grouped_matmul(x, gate, sizes, 'gmm')) \
+            * grouped_matmul(x, up, sizes, 'gmm')
+        return grouped_matmul(hidden, down, sizes, 'gmm')
+
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                  argnums=(0, 1, 2, 3))
+    assert _compile(fn, one_chip, ((40960, 2048), jnp.bfloat16),
+                    ((32, 2048, 512), jnp.bfloat16),
+                    ((32, 2048, 512), jnp.bfloat16),
+                    ((32, 512, 2048), jnp.bfloat16),
+                    ((32,), jnp.int32)) >= 8
