@@ -10,8 +10,8 @@ is more than one; layers past the last whole period are linear). Every
 block is a flax module named for what it is — ``linear_attn``,
 ``full_attn``, ``moe`` — which is also its ``jax.named_scope`` on the
 device trace; the kernels inside carry names of their own
-(``gated_delta_fwd`` / ``gated_delta_bwd_scan``, ``gqa_attn``,
-``expert_matmul``).
+(``gated_delta_prepare`` / ``gated_delta_fwd`` / ``gated_delta_bwd_scan``
+/ ``gated_delta_prepare_bwd``, ``gqa_attn``, ``expert_matmul``).
 
 **The share.** ``experts_held`` / ``expert_offset`` tell an expert layer
 which of the ``n_experts`` it holds: ``[offset, offset + held)``. The

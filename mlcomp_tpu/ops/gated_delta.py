@@ -22,14 +22,23 @@ chunk and ``S`` the state the chunk starts from::
     O     = (q exp(gamma)) S + lower(exp(gamma_t - gamma_i) q_t.k_i) D
     S'    = exp(gamma_C) S + (k exp(gamma_C - gamma))^T D
 
-Everything up to ``U, W`` and the masked ``q k^T`` is local to a chunk:
-batched matrix products that XLA lays onto the MXU and differentiates
-itself (``_prepare``). What is sequential — ``D``, ``O`` and ``S'``,
-chunk after chunk — is the kernel: ``gated_delta_fwd`` keeps the state
-in VMEM in float32 and saves the state each chunk starts from;
-``gated_delta_bwd_scan`` walks the chunks backwards, recomputes ``D``
-within the chunk from the saved state, and carries ``dS``. Off the TPU
-the same recurrence is a checkpointed ``lax.scan`` (``impl='xla'``).
+Everything up to ``U, W`` and the masked ``q k^T`` is local to a chunk.
+What is sequential — ``D``, ``O`` and ``S'``, chunk after chunk — is the
+scan. On the TPU (``impl='pallas'``) the op is four kernels under one
+``custom_vjp``: ``gated_delta_prepare`` makes the operands of a pair of
+chunks in VMEM (the running sum, ``A``, the float32 inverse by block
+joins, ``U``, ``W``, the masked ``q k^T``) and writes them where the
+scan reads them;
+``gated_delta_fwd`` keeps the state in VMEM in float32 and saves the
+state each group of chunks starts from; ``gated_delta_bwd_scan`` walks
+the chunks backwards, recomputes ``D`` within the chunk from the saved
+state, and carries ``dS``; ``gated_delta_prepare_bwd`` pulls the
+operands' cotangents back to the inputs in closed form
+(``dA = -strict_lower(T^T dT T^T)``), with the ``T`` that the
+backward's own ``gated_delta_prepare`` hands it. Off the TPU
+(``impl='xla'``) the chunk-local part is batched XLA products that XLA
+differentiates itself (``_prepare``, the oracle the kernels are tested
+against) and the recurrence a checkpointed ``lax.scan``.
 
 The state, the decays and every accumulation are float32; the matrix
 products take their operands in the input dtype (bfloat16 in a bfloat16
@@ -285,6 +294,323 @@ def _scan_pallas_bwd(qg, kd, w, u, p, a, states, do, group, interpret):
     return tuple(out[:5]) + (out[5][:, :, 0, 0],)
 
 
+# ---------------------------------------------- within a chunk, the kernel
+# ``_prepare`` again in VMEM, two consecutive chunks at a time: the same
+# mathematics with the same casts, the inverse in float32 at full
+# precision. A pair's [C,C] matrices lie side by side on the lanes,
+# ``wide`` [C,2C] = [X1 | X2]; a product takes its right-hand side as
+# ``diag(Y1, Y2)`` [2C,2C], so that ``[X1 | X2] diag(Y1, Y2)`` =
+# ``[X1 Y1 | X2 Y2]`` fills a 128 x 128 tile where C is 64. Rows of q, k,
+# v stay stacked [2C,d] as the tokens lie, and ``stacked stacked^T`` is
+# ``diag`` with cross terms that ``_wide`` leaves out. The gates come as
+# rows [1,2C]; a column is a masked sum along the lanes, a running sum
+# one along the sublanes, so nothing is transposed.
+def _dot32(x, y, dims=_NN):
+    return lax.dot_general(x, y, (dims, ((), ())), precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _diag(x, left):
+    """wide [C,2C] -> [2C,2C] with the halves on the diagonal."""
+    zero = jnp.zeros_like(x)
+    return jnp.concatenate(
+        [jnp.where(left, x, zero), jnp.where(left, zero, x)], 0)
+
+
+def _wide(x, left):
+    """[2C,2C] -> its diagonal blocks side by side, [C,2C]."""
+    half = x.shape[0] // 2
+    return jnp.where(left, x[:half], x[half:])
+
+
+def _inv_unit_lower_pairs(a_mats, row, col, left):
+    """``(I + A)^-1`` of strictly lower float32 ``A``, wide, C a power
+    of two, by ``inv_unit_lower``'s joins alone, each on the whole
+    block: ``I - A`` inverts the 2 x 2 diagonal blocks exactly, and
+    blocks of 2, 4, ... are joined to twice their size by ``X - X L X``
+    with ``L`` the part of ``A`` the join brings in. Ten products
+    whatever the base, so the base is the smallest: no power of ``A``
+    is ever formed. (``i // s == j // s`` is ``i ^ j < s``.) A list of
+    pairs goes through level by level: a pair's products depend on each
+    other, those of different pairs fill the MXU's pipeline."""
+    apart = jnp.bitwise_xor(row, col)
+    outs = [jnp.where(row == col, 1.0, jnp.where(apart < 2, -a, 0.0))
+            for a in a_mats]
+    size = 2
+    while size < row.shape[0]:
+        join = (apart >= size) & (apart < 2 * size)
+        steps = [_dot32(out, _diag(jnp.where(join, a, 0.0), left))
+                 for out, a in zip(outs, a_mats)]
+        outs = [out - _dot32(step, _diag(out, left))
+                for out, step in zip(outs, steps)]
+        size *= 2
+    return outs
+
+
+def _pair_local(q, k, v, g_row, beta_row):
+    """A pair's q, k [2C,dk], v [2C,dv] and gates [1,2C] -> what
+    ``_prepare`` makes of them up to ``A``, by name: [C,C] matrices
+    wide, per-token factors as columns [2C,1] (``*_col``) or rows."""
+    f32 = jnp.float32
+    dtype = q.dtype
+    chunk = q.shape[0] // 2
+    row = lax.broadcasted_iota(jnp.int32, (chunk, 2 * chunk), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, 2 * chunk), 1)
+    col = lane & (chunk - 1)
+    left = lane < chunk
+
+    def columns(x, keep):       # [1,2C] -> each half's [C,1]
+        x = jnp.where(keep, x, 0.0)
+        return (jnp.sum(jnp.where(left, x, 0.0), 1, keepdims=True),
+                jnp.sum(jnp.where(left, 0.0, x), 1, keepdims=True))
+
+    beta_cols = columns(beta_row, row == col)
+    gamma_cols = columns(g_row, col <= row)
+    gamma = jnp.where(left, *gamma_cols)
+    gamma_row = jnp.sum(jnp.where(row == col, gamma, 0.0), 0,
+                        keepdims=True)
+    lasts = [jnp.sum(jnp.where(row[:, :1] == chunk - 1, x, 0.0), 0,
+                     keepdims=True) for x in gamma_cols]        # [1,1]
+    beta_col = jnp.concatenate(beta_cols, 0)
+    gamma_col = jnp.concatenate(gamma_cols, 0)
+    last_col = jnp.concatenate(
+        [jnp.broadcast_to(x, (chunk, 1)) for x in lasts], 0)
+    lower = jnp.exp(jnp.where(col <= row, gamma - gamma_row, -jnp.inf))
+    kk = _wide(_dot(k, k, _NT), left)
+    decay_col = jnp.exp(gamma_col)
+    q32, k32, v32 = q.astype(f32), k.astype(f32), v.astype(f32)
+    return dict(
+        row=row, col=col, left=left, beta_row=beta_row, beta_col=beta_col,
+        gamma=gamma, gamma_row=gamma_row, lower=lower, kk=kk,
+        a_mat=jnp.where(
+            col < row, jnp.where(left, *beta_cols) * kk * lower, 0.0),
+        decay_col=decay_col, tail_col=jnp.exp(last_col - gamma_col),
+        q32=q32, k32=k32, v32=v32, qk=_wide(_dot(q, k, _NT), left),
+        bv=(v32 * beta_col).astype(dtype),
+        bk=(k32 * (beta_col * decay_col)).astype(dtype),
+        a=[jnp.exp(x) for x in lasts])
+
+
+def _pairs_local(q_ref, k_ref, v_ref, g_ref, beta_ref, group, chunk):
+    """-> per pair of the group: its rows of q, k, v, ``_pair_local``."""
+    out = []
+    for pair in range(group // 2):
+        tokens = slice(2 * pair * chunk, 2 * (pair + 1) * chunk)
+        out.append((tokens, _pair_local(
+            q_ref[0, tokens], k_ref[0, tokens], v_ref[0, tokens],
+            g_ref[0, pair], beta_ref[0, pair])))
+    return out
+
+
+def _halves(ref, pair, value):
+    """Store a stacked [2C,d] value as the pair's two chunks."""
+    chunk = value.shape[0] // 2
+    ref[0, 2 * pair] = value[:chunk].astype(ref.dtype)
+    ref[0, 2 * pair + 1] = value[chunk:].astype(ref.dtype)
+
+
+def _prepare_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    qg_ref, kd_ref, w_ref, u_ref, p_ref, a_ref, *t_ref,
+                    group):
+    chunk = p_ref.shape[-1]
+    dtype = q_ref.dtype
+    local = _pairs_local(q_ref, k_ref, v_ref, g_ref, beta_ref, group,
+                         chunk)
+    _, x = local[0]
+    inverses = _inv_unit_lower_pairs(
+        [x['a_mat'] for _, x in local], x['row'], x['col'], x['left'])
+    for pair, ((_, x), t32) in enumerate(zip(local, inverses)):
+        t = _diag(t32.astype(dtype), x['left'])
+        _halves(u_ref, pair, _dot(t, x['bv'], _NN))
+        _halves(w_ref, pair, _dot(t, x['bk'], _NN))
+        _halves(qg_ref, pair, x['q32'] * x['decay_col'])
+        _halves(kd_ref, pair, x['k32'] * x['tail_col'])
+        p = (x['qk'] * x['lower']).astype(dtype)
+        p_ref[0, 2 * pair] = p[:, :chunk]
+        p_ref[0, 2 * pair + 1] = p[:, chunk:]
+        for c, a in enumerate(x['a']):
+            a_ref[0, 2 * pair + c] = jnp.broadcast_to(a, a_ref.shape[2:])
+        for ref in t_ref:       # the backward's call asks for T
+            ref[0, pair] = t32
+
+
+def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref,
+                        dqg_ref, dkd_ref, dw_ref, du_ref, dp_ref, da_ref,
+                        dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *,
+                        group):
+    """The pullback of ``_prepare_kernel`` in closed form: with
+    ``dT = du (beta v)^T + dw (beta e^gamma k)^T`` the inverse gives
+    ``dA = -strict_lower(T^T dT T^T)``; the rest is element-wise, row
+    sums minus column sums for ``gamma`` and a reverse running sum back
+    to ``g``. The inverse's side is worked on transposed (``dT^T``,
+    ``dA^T = -strict_upper(T dT^T T)``, ``A^T``), because a wide
+    left-hand side cannot be: every transposed factor is at hand, as
+    ``k k^T`` is symmetric. ``T`` comes from the forward kernel."""
+    f32 = jnp.float32
+    chunk = dp_ref.shape[-1]
+    dtype = q_ref.dtype
+
+    def stacked(ref, pair):
+        return jnp.concatenate([ref[0, 2 * pair], ref[0, 2 * pair + 1]], 0)
+
+    def rows_sum(x):
+        return jnp.sum(x, 1, keepdims=True)
+
+    local = _pairs_local(q_ref, k_ref, v_ref, g_ref, beta_ref, group,
+                         chunk)
+    _, x = local[0]
+    row, col, left = x['row'], x['col'], x['left']
+    # the inverse, transposed; level by level as in the forward
+    inverses = [t_ref[0, pair] for pair in range(len(local))]
+    seeds = [(stacked(du_ref, pair), stacked(dw_ref, pair))
+             for pair in range(len(local))]
+    steps = [_dot32(t32, _diag(_wide(
+        _dot(x['bv'], du, _NT) + _dot(x['bk'], dw, _NT), left), left))
+        for (_, x), t32, (du, dw) in zip(local, inverses, seeds)]
+    d_ats = [jnp.where(row < col, -_dot32(step, _diag(t32, left)), 0.0)
+             for step, t32 in zip(steps, inverses)]
+    ts = [_diag(t32.astype(dtype), left) for t32 in inverses]
+    d_bvs = [_dot(t, du, _TN) for t, (du, _) in zip(ts, seeds)]
+    d_bks = [_dot(t, dw, _TN) for t, (_, dw) in zip(ts, seeds)]
+    for pair, (tokens, x) in enumerate(local):
+        q, k = q_ref[0, tokens], k_ref[0, tokens]
+        beta_col, decay_col = x['beta_col'], x['decay_col']
+        k32, tail_col = x['k32'], x['tail_col']
+        d_bv, d_bk = d_bvs[pair], d_bks[pair]
+        lower_t = jnp.exp(jnp.where(
+            row <= col, x['gamma_row'] - x['gamma'], -jnp.inf))
+        d_at_kk = d_ats[pair] * x['kk'] * lower_t
+        d_kkt = _diag(
+            (d_ats[pair] * x['beta_row'] * lower_t).astype(dtype), left)
+        # the masked q k^T
+        d_p = jnp.concatenate(
+            [dp_ref[0, 2 * pair], dp_ref[0, 2 * pair + 1]], 1).astype(f32)
+        d_qk = _diag((d_p * x['lower']).astype(dtype), left)
+        # d loss / d (gamma_i - gamma_j), through p's decays and A's
+        pairs = d_p * x['qk'] * x['lower'] - d_at_kk * x['beta_row']
+        d_qg = stacked(dqg_ref, pair).astype(f32)
+        d_kd = stacked(dkd_ref, pair).astype(f32)
+        by_bk = rows_sum(d_bk * k32)
+        by_kd = rows_sum(d_kd * k32) * tail_col
+        d_gamma_col = (by_bk * beta_col * decay_col
+                       + rows_sum(d_qg * x['q32']) * decay_col - by_kd)
+        d_beta_col = rows_sum(d_bv * x['v32']) + by_bk * decay_col
+        across = jnp.sum(pairs, 0, keepdims=True)       # column sums
+        d_gammas = []
+        for c, keep in enumerate((left, ~left)):
+            half = slice(c * chunk, (c + 1) * chunk)
+            d_last = jnp.sum(by_kd[half], 0, keepdims=True) \
+                + da_ref[0, 2 * pair + c][:, :1] * x['a'][c]
+            d_gammas.append(
+                d_gamma_col[half] + rows_sum(jnp.where(keep, pairs, 0.0))
+                - rows_sum(jnp.where(keep & (row == col), across, 0.0))
+                + jnp.where(row[:, :1] == chunk - 1, d_last, 0.0))
+        dq_ref[0, tokens] = (_dot(d_qk, k, _NN)
+                             + d_qg * decay_col).astype(dtype)
+        dk_ref[0, tokens] = (
+            _dot(d_qk, q, _TN) + _dot(d_kkt, k, _NN) + _dot(d_kkt, k, _TN)
+            + d_bk * (beta_col * decay_col) + d_kd * tail_col).astype(dtype)
+        dv_ref[0, tokens] = (d_bv * beta_col).astype(dtype)
+        # gamma is g's running sum: g_t gets every gamma_i with i >= t
+        dg_ref[0, pair] = jnp.sum(jnp.where(
+            row >= col, jnp.where(left, *d_gammas), 0.0), 0, keepdims=True)
+        dbeta_ref[0, pair] = jnp.sum(d_at_kk, 0, keepdims=True) + jnp.sum(
+            jnp.where(row == col, jnp.where(
+                left, d_beta_col[:chunk], d_beta_col[chunk:]), 0.0), 0,
+            keepdims=True)
+
+
+def _prepare_call(kernel, name, inputs, given, out_like, chunk, group,
+                  interpret):
+    """A grid of (sequence x head, group of chunks) over q, k, v where
+    they lie, [B,T,H,*] read as [B,T,H*d] in blocks of one head's
+    width, and over the gates as rows of a pair [B*H,N/2,1,2C].
+    ``given`` (kind, array) and the outputs named by ``out_like``
+    follow: 'q', 'v', 'g' as the inputs; 'k', 'v_folded', 'p', 'a' as
+    ``_specs`` has them; 't' a pair's wide float32 ``T``."""
+    q, k, v, g, beta = inputs
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    n = t // chunk
+    f32 = jnp.float32
+    if chunk & (chunk - 1) or group % 2:
+        raise ValueError(
+            f'the kernels work on pairs of chunks and join blocks of 2, '
+            f'4, ...: chunk {chunk} has to be a power of two, the group '
+            f'{group} even')
+
+    def wide(d):
+        return pl.BlockSpec((1, group * chunk, d),
+                            lambda i, j: (i // h, j, i % h))
+
+    def index(i, j):
+        return (i, j, 0, 0)
+
+    folded = _specs(group, chunk, dk, dv, index)
+    kinds = {
+        'q': (wide(dk), (b, t, h * dk), q.dtype),
+        'v': (wide(dv), (b, t, h * dv), q.dtype),
+        'g': (pl.BlockSpec((1, group // 2, 1, 2 * chunk), index),
+              (b * h, n // 2, 1, 2 * chunk), f32),
+        't': (pl.BlockSpec((1, group // 2, chunk, 2 * chunk), index),
+              (b * h, n // 2, chunk, 2 * chunk), f32),
+        'k': (folded['k'], (b * h, n, chunk, dk), q.dtype),
+        'v_folded': (folded['v'], (b * h, n, chunk, dv), q.dtype),
+        'p': (folded['p'], (b * h, n, chunk, chunk), q.dtype),
+        'a': (folded['a'], (b * h, n, 1, 128), f32),
+    }
+
+    def gates(x):           # [B,T,H] -> [B*H, N/2, 1, 2C]
+        return jnp.moveaxis(x, 2, 1).reshape(kinds['g'][1])
+
+    return pl.pallas_call(
+        functools.partial(kernel, group=group),
+        out_shape=[jax.ShapeDtypeStruct(*kinds[kind][1:])
+                   for kind in out_like],
+        grid=(b * h, n // group),
+        in_specs=[wide(dk), wide(dk), wide(dv), kinds['g'][0],
+                  kinds['g'][0]] + [kinds[kind][0] for kind, _ in given],
+        out_specs=[kinds[kind][0] for kind in out_like],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel')),
+        interpret=interpret,
+        name=name,
+    )(q.reshape(b, t, h * dk), k.reshape(b, t, h * dk),
+      v.reshape(b, t, h * dv), gates(g), gates(beta),
+      *(x for _, x in given))
+
+
+def _prepare_pallas(q, k, v, g, beta, chunk, group, interpret,
+                    keep_t=False):
+    """``_prepare`` as the kernel ``gated_delta_prepare``; with
+    ``keep_t`` the float32 ``T`` follows, for ``_prepare_pallas_bwd``."""
+    out = list(_prepare_call(
+        _prepare_kernel, 'gated_delta_prepare', (q, k, v, g, beta), [],
+        ['k', 'k', 'k', 'v_folded', 'p', 'a'] + ['t'] * keep_t, chunk,
+        group, interpret))
+    out[5] = out[5][:, :, 0, 0]
+    return tuple(out)
+
+
+def _prepare_pallas_bwd(q, k, v, g, beta, t32, cotangents, chunk, group,
+                        interpret):
+    """The cotangents of ``_prepare``'s outputs -> those of its inputs
+    (the kernel ``gated_delta_prepare_bwd``)."""
+    b, t, h, _ = q.shape
+    dqg, dkd, dw, du, dp, da = cotangents
+    dq, dk, dv, dg, dbeta = _prepare_call(
+        _prepare_bwd_kernel, 'gated_delta_prepare_bwd', (q, k, v, g, beta),
+        [('t', t32), ('k', dqg), ('k', dkd), ('k', dw), ('v_folded', du),
+         ('p', dp), ('a', _lane(da))],
+        ['q', 'q', 'v', 'g', 'g'], chunk, group, interpret)
+
+    def gates(x):           # [B*H, N/2, 1, 2C] -> [B,T,H]
+        return jnp.moveaxis(x.reshape(b, h, t), 1, 2)
+
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            gates(dg), gates(dbeta))
+
+
 # The kernels' rule covers the whole op: what is saved for the backward
 # pass is the inputs and the group states alone, and the chunk-local
 # operands are made again there. Both passes go through the heads a
@@ -303,11 +629,11 @@ def _delta_pallas(q, k, v, g, beta, static):
 
 
 def _delta_fwd_rule(q, k, v, g, beta, static):
-    chunk, group, solve_base, heads, interpret = static
+    chunk, group, heads, interpret = static
     b, t, h, _ = q.shape
 
     def one(block):
-        operands = _prepare(*block, chunk, solve_base)
+        operands = _prepare_pallas(*block, chunk, group, interpret)
         return _scan_pallas_fwd(*operands, group, interpret)
 
     inputs = (q, k, v, g, beta)
@@ -321,20 +647,20 @@ def _delta_fwd_rule(q, k, v, g, beta, static):
 
 
 def _delta_bwd_rule(static, residuals, do):
-    chunk, group, solve_base, heads, interpret = static
+    chunk, group, heads, interpret = static
     inputs, states = residuals
     b, t, h, dv = do.shape
 
     def one(args):
         block, states, do = args
-        operands, pullback = jax.vjp(
-            lambda *x: _prepare(*x, chunk, solve_base), *block)
-        # [B,T,heads,dv] -> [B*heads, N, C, dv], as _prepare folds
+        *operands, t32 = _prepare_pallas(*block, chunk, group, interpret,
+                                         keep_t=True)
+        # [B,T,heads,dv] -> [B*heads, N, C, dv], as the operands lie
         do = jnp.moveaxis(do, 2, 1).reshape(
             b * heads, t // chunk, chunk, dv)
-        return pullback(_scan_pallas_bwd(
+        return _prepare_pallas_bwd(*block, t32, _scan_pallas_bwd(
             *operands, states, do.astype(operands[3].dtype), group,
-            interpret))
+            interpret), chunk, group, interpret)
 
     grads = lax.map(one, (
         tuple(_head_blocks(x, heads) for x in inputs), states,
@@ -366,13 +692,15 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     checkpointed ``lax.scan`` over chunks), ``auto`` (the kernels on a
     TPU, the scan elsewhere). ``group``: chunks a grid step of the
     kernels works through; ``head_block``: heads whose chunk-local
-    operands are live at once; ``solve_base``: see ``inv_unit_lower``."""
+    operands are live at once; ``solve_base``: see ``inv_unit_lower``
+    (the ``xla`` path's; the kernel inverts by joins alone)."""
     if impl == 'auto':
         impl = 'pallas' if jax.default_backend() == 'tpu' else 'xla'
     if impl not in ('pallas', 'interpret', 'xla'):
         raise ValueError(f'unknown gated_delta_rule impl {impl!r}')
     b, t, h, _ = q.shape
-    pad = -t % chunk
+    # the kernels work on pairs of chunks
+    pad = -t % (chunk if impl == 'xla' else 2 * chunk)
     if pad:
         # padded tokens write nothing (beta 0) and decay nothing (g 0)
         widths = [(0, 0), (0, pad)]
@@ -388,8 +716,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
         out = jnp.moveaxis(
             out.reshape(b, h, t + pad, v.shape[-1]), 1, 2)
     else:
-        static = (chunk, _fit((t + pad) // chunk, group), solve_base,
-                  _fit(h, head_block), impl == 'interpret')
+        pairs = _fit((t + pad) // (2 * chunk), max(group // 2, 1))
+        static = (chunk, 2 * pairs, _fit(h, head_block),
+                  impl == 'interpret')
         out = _delta_pallas(q, k, v, g, beta, static)
     return out[:, :t]
 

@@ -142,8 +142,11 @@ def test_grouped_query_flash_attention(one_chip, grad):
 
 
 @pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
-def test_gated_delta_rule(one_chip, grad):
-    """One block of 8 value heads of 128 x 128 at 8,192 tokens."""
+@pytest.mark.parametrize('sequences', [1, 2], ids=['one', 'the_cell'])
+def test_gated_delta_rule(one_chip, sequences, grad):
+    """One block of 8 value heads of 128 x 128 at 8,192 tokens; with 2
+    sequences it is what a step of ``qwen3-next-80b-a3b.steady`` hands
+    the kernels for each of its 4 head blocks."""
     from mlcomp_tpu.ops.gated_delta import gated_delta_rule
 
     def fwd(q, k, v, g, beta):
@@ -152,10 +155,13 @@ def test_gated_delta_rule(one_chip, grad):
     fn = fwd if not grad else jax.grad(
         lambda *a: fwd(*a).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4))
-    wide = ((1, 8192, 8, 128), jnp.bfloat16)
-    gate = ((1, 8192, 8), jnp.float32)
+    wide = ((sequences, 8192, 8, 128), jnp.bfloat16)
+    gate = ((sequences, 8192, 8), jnp.float32)
+    # forward: gated_delta_prepare, gated_delta_fwd; the backward makes
+    # the operands again (prepare), then gated_delta_bwd_scan and
+    # gated_delta_prepare_bwd
     assert _compile(fn, one_chip, wide, wide, wide, gate, gate) \
-        == (2 if grad else 1)
+        == (5 if grad else 2)
 
 
 def test_expert_grouped_matmul(one_chip):

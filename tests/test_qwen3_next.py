@@ -31,6 +31,7 @@ from mlcomp_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention_backward, flash_attention_forward, fused_attention,
     reference_attention,
 )
+from mlcomp_tpu.ops import gated_delta  # noqa: E402
 from mlcomp_tpu.ops.gated_delta import (  # noqa: E402
     gated_delta_rule, inv_unit_lower, reference_gated_delta,
 )
@@ -155,9 +156,10 @@ def delta_inputs(b=2, t=96, h=3, dk=16, dv=32):
 
 @pytest.mark.parametrize('impl,chunk', [
     ('xla', 16), ('xla', 32), ('xla', 64), ('interpret', 16),
-    ('interpret', 32)])
+    ('interpret', 32), ('interpret', 64)])
 def test_chunked_delta_rule_against_the_recurrence(impl, chunk):
-    """T = 96: six, three and one and a half chunks (the last padded)."""
+    """T = 96: six, three and one and a half chunks (the last padded).
+    ``interpret`` goes through the four kernels of the Pallas path."""
     args = delta_inputs()
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
 
@@ -182,6 +184,85 @@ def test_inverse_of_unit_lower_triangular(n, base):
     mat = a * 0.3 + jnp.eye(n)
     got = inv_unit_lower(mat, base)
     assert float(jnp.abs(got @ mat - jnp.eye(n)).max()) < 1e-4
+
+
+# ------------------------ the chunk-local kernels against ``_prepare``
+OPERANDS = 'qg kd w u p a'.split()
+INPUTS = 'q k v g beta'.split()
+# float32: the same products in another order; bfloat16: the kernels
+# cast a cotangent where XLA's transposed chain multiplies it in float32
+CLOSE = {'float32': (1e-5, 2e-5), 'bfloat16': (2e-3, 1e-2)}
+
+
+@pytest.fixture(scope='module', params=['float32', 'bfloat16'])
+def prepared(request):
+    """At the cell's widths (C = 64, dk = dv = 128), 2 x 2 heads of 4
+    chunks, one pair a grid step: ``_prepare``'s outputs and the
+    pullback of random cotangents, from XLA and from the kernels."""
+    dtype = request.param
+    with jax.default_matmul_precision('highest'):
+        q, k, v, g, beta = delta_inputs(t=256, h=2, dk=128, dv=128)
+        args = tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta)
+        want, pullback = jax.vjp(
+            lambda *a: gated_delta._prepare(*a, 64, 16), *args)
+        *got, t32 = gated_delta._prepare_pallas(
+            *args, 64, 2, True, keep_t=True)
+        keys = jax.random.split(jax.random.PRNGKey(11), len(want))
+        cotangents = tuple(jax.random.normal(key, x.shape).astype(x.dtype)
+                           for key, x in zip(keys, want))
+        back = gated_delta._prepare_pallas_bwd(
+            *args, t32, cotangents, 64, 2, True)
+        return dtype, want, got, pullback(cotangents), back
+
+
+def close(got, want, limit):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert rel(got.astype(jnp.float32), want.astype(jnp.float32)) < limit
+
+
+@pytest.mark.parametrize('name', OPERANDS)
+def test_prepare_kernel_against_xla(prepared, name):
+    dtype, want, got, _, _ = prepared
+    i = OPERANDS.index(name)
+    close(got[i], want[i], CLOSE[dtype][0])
+
+
+@pytest.mark.parametrize('name', INPUTS)
+def test_prepare_backward_in_closed_form(prepared, name):
+    dtype, _, _, want, got = prepared
+    i = INPUTS.index(name)
+    close(got[i], want[i], CLOSE[dtype][1])
+
+
+def test_the_kernels_solve_on_ill_conditioned_chunks():
+    """One key repeated, beta 0.99, hardly any decay: ``A`` is 0.99
+    below the diagonal, its powers hold binomials (a Neumann product
+    over all 64 rows would cancel 1e17 down to 1e-2). Against the answer
+    in float64 the kernel's ``u = T (beta v)``, from joins alone, is no
+    worse than ``inv_unit_lower(..., 16)``'s."""
+    c, d = 64, 128
+    key = jax.random.normal(jax.random.PRNGKey(2), (d,))
+    k = jnp.broadcast_to(key / jnp.linalg.norm(key), (1, 2 * c, 1, d))
+    q = k * d ** -0.5
+    v = jax.random.normal(jax.random.PRNGKey(4), (1, 2 * c, 1, d))
+    g = jnp.full((1, 2 * c, 1), -1e-3)
+    beta = jnp.full((1, 2 * c, 1), 0.99)
+    k64, v64 = (np.asarray(x[0, :, 0], np.float64) for x in (k, v))
+    gamma = -1e-3 * np.arange(1, c + 1)
+    a_mat = np.tril(0.99 * (k64[:c] @ k64[:c].T)
+                    * np.exp(gamma[:, None] - gamma[None]), -1)
+    exact = np.linalg.solve(
+        np.eye(c) + a_mat, 0.99 * v64.reshape(2, c, d))       # [2,C,d]
+
+    def gap(operands):
+        return np.abs(np.asarray(operands[3][0], np.float64)
+                      - exact).max() / np.abs(exact).max()
+
+    xla = gap(gated_delta._prepare(q, k, v, g, beta, c, 16))
+    kernel = gap(gated_delta._prepare_pallas(q, k, v, g, beta, c, 2, True))
+    whole = gap(gated_delta._prepare(q, k, v, g, beta, c, 64))
+    assert kernel < xla + 1e-6 and kernel < 1e-5
+    assert whole > 1e6 * kernel        # why no whole-block Neumann
 
 
 # --------------------------------------------------- grouped-query flash
