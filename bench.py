@@ -16,8 +16,9 @@ transfer on the critical path; the device path removes it from the
 loop entirely, and the pad-crop is formulated as one-hot MATMULS because the natural gather
 lowers slowly on TPU). Reference numbers on the v5e chip: 34.3k img/s
 epoch throughput (best of 3 epochs, full 50k-sample CIFAR epoch),
-0.51 MFU, epoch loop ~1.1x the compute-only loop (lax.scan removes
-per-step dispatch).
+0.51 MFU, epoch loop ~1.1x the compute-only loop (taken with the whole
+epoch as one lax.scan dispatch; the epoch leg now dispatches per step,
+as JaxTrain does).
 A compute-only loop is also measured so pipeline efficiency is visible,
 and MFU is computed from XLA's own cost analysis of the compiled step.
 
@@ -1678,13 +1679,11 @@ def main():
     from mlcomp_tpu.train.device_data import (
         make_device_augment, place_dataset, quantize_dataset,
     )
-    from mlcomp_tpu.train.loop import (
-        make_device_epoch_fn, make_device_train_step,
-    )
+    from mlcomp_tpu.train.loop import make_device_train_step
 
     batch_size = int(os.environ.get('BENCH_BATCH', '512'))
     # real CIFAR-10 epoch size — short epochs under-amortize the
-    # per-epoch permutation transfer + scan dispatch (~5% at 20k)
+    # per-epoch permutation and the first dispatch (~5% at 20k)
     n_train = int(os.environ.get('BENCH_SAMPLES', '50000'))
     compute_steps = int(os.environ.get('BENCH_STEPS', '60'))
     peak_tflops = PEAK_BF16_TFLOPS[jax.devices()[0].device_kind]
@@ -1715,9 +1714,8 @@ def main():
 
     # ONE dispatch for the whole compute loop (lax.scan over steps):
     # per-step python dispatch would put host overhead into a loop that
-    # exists to bound step compute, and the scanned epoch it is held
-    # against pays none (pipeline_efficiency > 1, nonsense). Same-batch
-    # repetition is fine — the loop exists to bound step compute.
+    # exists to bound step compute. Same-batch repetition is fine for
+    # the same reason.
     import jax as _jax
 
     def _compute_scan(state, xb, yb):
@@ -1747,9 +1745,6 @@ def main():
     x_all, y_all = place_dataset(x_q, y_train, mesh)
     augment = make_device_augment(
         [('pad_crop', {'pad': 4}), ('hflip', {})], x_train.shape[1:])
-    # lax.scan whole-epoch dispatch (no per-step dispatch);
-    # BENCH_EPOCH_SCAN=0 times the per-step device path instead
-    use_scan = os.environ.get('BENCH_EPOCH_SCAN', '1') == '1'
     steps_per_epoch = len(x_train) // batch_size
 
     def epoch_perm(seed):
@@ -1757,29 +1752,17 @@ def main():
             len(x_train))[:steps_per_epoch * batch_size]
         return perm.astype(np.int32).reshape(steps_per_epoch, batch_size)
 
-    if use_scan:
-        epoch_fn = make_device_epoch_fn(
-            model, optimizer, loss_fn, mesh=mesh, augment=augment,
-            dequantize=dequant, row_shape=x_train.shape[1:])
+    dev_step = make_device_train_step(
+        model, optimizer, loss_fn, mesh=mesh, augment=augment,
+        dequantize=dequant, row_shape=x_train.shape[1:])
 
-        def run_epoch(state, seed):
-            perm_dev = jax.device_put(
-                epoch_perm(seed), batch_sharding(mesh, 2, batch_dim=1))
-            state, metrics = epoch_fn(state, x_all, y_all, perm_dev)
-            float(np.asarray(metrics['loss'])[-1])
-            return state
-    else:
-        dev_step = make_device_train_step(
-            model, optimizer, loss_fn, mesh=mesh, augment=augment,
-            dequantize=dequant, row_shape=x_train.shape[1:])
-
-        def run_epoch(state, seed):
-            perm = epoch_perm(seed)
-            for s in range(steps_per_epoch):
-                idx = jax.device_put(perm[s], batch_sharding(mesh, 1))
-                state, metrics = dev_step(state, x_all, y_all, idx)
-            float(metrics['loss'])
-            return state
+    def run_epoch(state, seed):
+        perm = epoch_perm(seed)
+        for s in range(steps_per_epoch):
+            idx = jax.device_put(perm[s], batch_sharding(mesh, 1))
+            state, metrics = dev_step(state, x_all, y_all, idx)
+        float(metrics['loss'])
+        return state
 
     state = run_epoch(state, 99)    # warmup (compiles the device step)
     # best of 3 epochs: peak sustained throughput (no dispersion is

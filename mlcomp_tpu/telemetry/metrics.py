@@ -215,15 +215,6 @@ class MetricRecorder:
             return {name: h.summary()
                     for name, h in self._histograms.items()}
 
-    def series_array(self, name: str, values, start_step: int = 0):
-        """Bulk append — e.g. the [steps] metric arrays a whole-epoch
-        ``lax.scan`` returns (one host pull for the whole epoch)."""
-        arr = np.asarray(values).reshape(-1)
-        with self._mutate_lock:
-            for i, v in enumerate(arr):
-                self._pending.append((name, 'series', start_step + i,
-                                      float(v)))
-
     # ----------------------------------------------------------- flush path
     def _materialize(self):
         """Swap out pending samples + aggregate snapshots, converting

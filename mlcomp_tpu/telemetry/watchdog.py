@@ -78,9 +78,9 @@ class WatchdogConfig:
 
     #: seconds without heartbeat/metric progress before a task stalls.
     #: The deadline must exceed the longest LEGITIMATE quiet period —
-    #: first jit compile of a big model, a checkpoint restore, an
-    #: epoch_scan epoch, a task running with telemetry disabled (whose
-    #: only life signal is status-transition last_activity) — which is
+    #: first jit compile of a big model, a checkpoint restore, a task
+    #: running with telemetry disabled (whose only life signal is
+    #: status-transition last_activity) — which is
     #: why the default is conservative. The metric-flush heartbeat
     #: (MetricRecorder.flush touches task.last_activity) keeps
     #: instrumented tasks far inside it.

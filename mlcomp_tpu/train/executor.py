@@ -31,10 +31,15 @@ Example spec::
       model_name: my_model      # optional Model-registry entry
 """
 
+import contextlib
+import dataclasses
+import functools
 import itertools
 import json
+import math
 import os
 import time
+from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
@@ -58,6 +63,67 @@ from mlcomp_tpu.train.optim import make_optimizer
 from mlcomp_tpu.worker.executors import Executor
 
 
+def _phase(name):
+    """The decorated method of ``JaxTrain`` is the phase ``name``: it
+    runs inside the span of that name (``JaxTrain._span``)."""
+    def wrap(method):
+        @functools.wraps(method)
+        def inside(self, *args, **kwargs):
+            with self._span(name):
+                return method(self, *args, **kwargs)
+        return inside
+    return wrap
+
+
+@dataclasses.dataclass
+class _Inputs:
+    """What ``train.setup.data`` leaves: the data set on the host and,
+    on the device-data path, resident on the mesh with the on-device
+    augmentation; on the host path the host ``transform``."""
+    x_train: Any
+    y_train: Any
+    x_valid: Any
+    y_valid: Any
+    seq_dim: Optional[int]
+    use_device_data: bool
+    x_all: Any = None
+    y_all: Any = None
+    xv_all: Any = None
+    yv_all: Any = None
+    dequant: bool = False
+    dequant_v: bool = False
+    dev_augment: Optional[Callable] = None
+    transform: Any = None
+
+
+@dataclasses.dataclass
+class _Run:
+    """What the phases of one job share, and what they move forward:
+    the train state, the best score, the count of epochs."""
+    mesh: Any
+    ck_dir: str
+    loss_fn: Callable
+    self_supervised: bool
+    steps_per_epoch: int
+    model: Any = None
+    state: Any = None
+    n_params: int = 0
+    meta: Optional[dict] = None         # of the checkpoint restored
+    best: Optional[float] = None
+    first_global_epoch: int = 0         # where this dispatch resumed
+    global_epoch: int = 0
+    images_seen: int = 0
+
+
+@dataclasses.dataclass
+class _Stage:
+    name: str
+    epochs: int
+    optimizer: Any
+    train_step: Callable    # (state, *feed) -> (state, metrics)
+    evaluate: Callable      # (state, rows, weights) -> metrics
+
+
 @Executor.register
 class JaxTrain(Executor):
     def __init__(self, model=None, dataset=None, loss='softmax_ce',
@@ -65,9 +131,8 @@ class JaxTrain(Executor):
                  stages=None, epochs=1, optimizer=None,
                  main_metric='accuracy', minimize=False,
                  model_name=None, seed=0, checkpoint_dir=None,
-                 stage_per_dispatch=False, log_every=50,
-                 report_imgs=None, augment=None, prefetch=2,
-                 device_data='auto', epoch_scan=False,
+                 stage_per_dispatch=False, report_imgs=None,
+                 augment=None, prefetch=2, device_data='auto',
                  checkpoint_every=1, infer_valid=None, profile=None,
                  async_checkpoint=True, telemetry=True, **kwargs):
         self.model_spec = dict(model or {'name': 'mlp'})
@@ -93,15 +158,10 @@ class JaxTrain(Executor):
         self.seed = int(seed)
         self.checkpoint_dir = checkpoint_dir
         self.stage_per_dispatch = bool(stage_per_dispatch)
-        self.log_every = int(log_every)
         self.report_imgs = dict(report_imgs) if report_imgs else None
         self.augment = list(augment) if augment else None
         self.prefetch = int(prefetch)
         self.device_data = device_data
-        # one-XLA-dispatch-per-epoch via lax.scan: measured ~equal to
-        # the per-step device path on TPU and pathologically slow to
-        # compile on XLA:CPU (scan-of-conv-graph), so opt-in
-        self.epoch_scan = bool(epoch_scan)
         self.checkpoint_every = int(checkpoint_every)
         if self.checkpoint_every == 0:
             wants_best = bool(infer_valid) and \
@@ -168,8 +228,6 @@ class JaxTrain(Executor):
         show before its numbers mean anything. Read from the arrays'
         shardings: touching ``addressable_shards`` would leave aliases
         of buffers the train step is about to donate."""
-        import math
-
         def local_frac(sharding, shape):
             return math.prod(sharding.shard_shape(tuple(shape))) \
                 / max(1, math.prod(shape))
@@ -203,11 +261,8 @@ class JaxTrain(Executor):
             # the supervisor did NOT pin the mesh: for a fanned-out
             # job a size mismatch is a placement bug that must stay a
             # loud normalize_mesh_spec error, not a silent prefix
-            import math as _math
-
-            import jax as _jax
-            product = _math.prod(int(v) for v in spec.values())
-            visible = _jax.devices()
+            product = math.prod(int(v) for v in spec.values())
+            visible = jax.devices()
             if 0 < product < len(visible):
                 devices = visible[:product]
         return mesh_from_spec(spec, devices=devices)
@@ -316,14 +371,17 @@ class JaxTrain(Executor):
         self._attribution = None
         self._tripwire = None
         self._compile_events = None
+        self._memory = None
+        self._step_flops = None
+        self._comm_probe_ms = None
+        self._introspected = False
         ok = False
         # the train loop's leg of the cross-process trace: a
         # `train.work` root (role='train') with the set-up and
-        # per-epoch phase spans below it (_open_span), joined to the
+        # per-epoch phase spans below it (_span), joined to the
         # supervisor dispatch and worker pipeline spans by the trace id
         # the task environment / additional_info carries
         # (telemetry/spans.py trace context)
-        self._open_spans = []
         self._spans_on = self.telemetry_spec is not None \
             and self.session is not None \
             and getattr(self, 'task', None) is not None
@@ -335,19 +393,14 @@ class JaxTrain(Executor):
             # of this process is also an event on the host line of any
             # jax.profiler trace (spans.py stays importable without jax)
             spans.set_annotation_factory(jax.profiler.TraceAnnotation)
-            self._open_span('train.work',
-                            model=self.model_spec.get('name'))
         try:
-            result = self._work()
+            with self._span('train.work',
+                            model=self.model_spec.get('name')):
+                result = self._work()
             ok = True
             return result
         finally:
-            if self._open_spans:
-                import sys as _sys
-                # an exception left the phase it was raised in (and
-                # its parents) open: they close here, as errors
-                while self._open_spans:
-                    self._close_span(_sys.exc_info())
+            if self._spans_on:
                 from mlcomp_tpu.telemetry import flush_spans
                 try:
                     flush_spans(self.session)
@@ -398,34 +451,17 @@ class JaxTrain(Executor):
                     if ok:
                         raise
 
-    # ---------------------------------------------------------------- spans
-    # The phases of _work are a flat sequence inside one long body, so
-    # they are opened and closed by statement rather than by `with`
-    # blocks (which would re-indent the whole epoch loop); work()'s
-    # `finally` closes whatever an exception left open.
-    def _open_span(self, name, **tags):
-        """Open ``name`` as a child of the innermost open span (the
-        DB row, and the profiler annotation: telemetry/spans.py). Does
-        nothing in a run without telemetry, which has no `train.work`
-        root to hang it on."""
+    def _span(self, name, **tags):
+        """``with self._span(name):`` is ``name`` as a child of the
+        innermost open span (the DB row, and the profiler annotation:
+        telemetry/spans.py); an exception that leaves the block closes
+        it, and then its parents, as errors. Nothing is opened in a run
+        without telemetry."""
         if not self._spans_on:
-            return
+            return contextlib.nullcontext()
         from mlcomp_tpu.telemetry import span
-        cm = span(name, task=self.task.id, role='train',
-                  trace_id=self._span_trace_id, tags=tags or None)
-        cm.__enter__()
-        self._open_spans.append(cm)
-
-    def _close_span(self, exc_info=(None, None, None)):
-        """Close the innermost open span; given the active exception
-        (work()'s `finally`) it is recorded as an error."""
-        if self._open_spans:
-            self._open_spans.pop().__exit__(*exc_info)
-
-    def _next_span(self, name):
-        """The open phase ends where the next one starts."""
-        self._close_span()
-        self._open_span(name)
+        return span(name, task=self.task.id, role='train',
+                    trace_id=self._span_trace_id, tags=tags or None)
 
     def _drain_ckpt_writer(self):
         if self._ckpt_writer is not None:
@@ -454,14 +490,81 @@ class JaxTrain(Executor):
             'ids': [d.id for d in devices],
             'visible_chips': os.environ.get('TPU_VISIBLE_CHIPS', 'all'),
             'mesh': {k: int(v) for k, v in dict(mesh.shape).items()}}))
-        loss_fn = loss_for_task(self.loss_spec)
-        self_supervised = self.loss_name == 'lm_ce'
 
-        # set-up spans (children of train.work, like the epochs below):
-        # `data` is the dataset's load plus its way onto the device,
-        # `state` the model and its train state (fresh, restored or
-        # pretrained), `introspect` the AOT compile of the step
-        self._open_span('train.setup.data')
+        # set-up, each phase a span (a child of train.work, like the
+        # epochs below); a third, `introspect`, is the AOT compile of
+        # the first stage's step
+        self_supervised = self.loss_name == 'lm_ce'
+        inputs = self._setup_data(mesh, self_supervised)
+        run = _Run(
+            mesh=mesh, ck_dir=self._checkpoint_folder(),
+            loss_fn=loss_for_task(self.loss_spec),
+            self_supervised=self_supervised,
+            steps_per_epoch=max(
+                1, len(inputs.x_train) // self.batch_size))
+        self._setup_telemetry(run.ck_dir)
+        self._setup_state(run, inputs)
+
+        # stage-per-dispatch (distributed parity, catalyst.py:354-368):
+        # the task's additional_info names the stage this dispatch runs
+        info = dict(getattr(self, 'additional_info', None) or {})
+        dispatch_stage = info.get('stage') if self.stage_per_dispatch \
+            else None
+        stage_names = [s['name'] for s in self.stages]
+        remaining, start_epoch = resume_plan(self.stages, run.meta)
+        if dispatch_stage is not None:
+            remaining = [s for s in remaining
+                         if s['name'] == dispatch_stage] or remaining[:1]
+        for spec in remaining:
+            stage = self._setup_stage(run, inputs, spec)
+            first_epoch = start_epoch if spec is remaining[0] else 0
+            if first_epoch == 0 and spec is not self.stages[0]:
+                # stage boundary: fresh optimizer state, keep params
+                # (resuming mid-stage keeps the restored opt state)
+                run.state = run.state.replace(
+                    opt_state=stage.optimizer.init(run.state.params))
+            self.step.start(1, f'stage {stage.name}',
+                            stage_names.index(stage.name))
+            for epoch in range(first_epoch, stage.epochs):
+                self._epoch(run, inputs, stage, epoch)
+            if (dispatch_stage is not None or self.stage_per_dispatch) \
+                    and stage.name != stage_names[-1]:
+                # return for requeue: next dispatch runs the next stage.
+                # The LAST stage's dispatch falls through instead so the
+                # model export / report-img pass still runs.
+                self._drain_ckpt_writer()   # requeued stage reads last
+                return {'stage': stage.name, 'stages': stage_names,
+                        'best_score': run.best}
+
+        # everything below reads checkpoint files — drain pending writes
+        self._drain_ckpt_writer()
+        x_train, x_valid = inputs.x_train, inputs.x_valid
+        if self._is_main and self.model_name:
+            self._export_model(run.ck_dir, run.best,
+                               input_shape=[int(d) for d in
+                                            x_train.shape[1:]],
+                               input_dtype=str(x_train.dtype))
+        # the post-train passes run collective programs (valid forward,
+        # checkpoint gather) — EVERY rank must execute the same sequence;
+        # only rank 0 touches DB/filesystem inside each helper
+        if self.report_imgs and self.session is not None \
+                and self.task is not None:
+            self._build_report_imgs(run.model, run.state, mesh, x_valid,
+                                    inputs.y_valid,
+                                    max(run.global_epoch - 1, 0))
+        if self.infer_valid:
+            self._infer_valid(run.model, run.state, mesh, run.ck_dir,
+                              x_valid, inputs.y_valid)
+
+        wall = time.time() - t_start
+        return {'stage': stage_names[-1], 'stages': stage_names,
+                'best_score': run.best, 'n_params': run.n_params,
+                'wall_time_s': wall,
+                'samples_per_sec': run.images_seen / max(wall, 1e-9)}
+
+    # --------------------------------------------------------------- set-up
+    @_phase('train.setup.data')
+    def _setup_data(self, mesh, self_supervised) -> _Inputs:
         data = create_dataset(**self.dataset_spec) \
             if self.dataset_spec.get('name') else \
             create_dataset('synthetic_images')
@@ -490,143 +593,151 @@ class JaxTrain(Executor):
                 'device_data: true supports labeled datasets only — '
                 'label-less/self-supervised training uses the host '
                 'pipeline (device_data: auto selects it automatically)')
-        use_device_data = (
-            self.device_data is True
-            or (self.device_data == 'auto'
-                and device_augs is not None
-                and y_train is not None
-                and not self_supervised
-                and seq_dim is None
-                # train AND valid both become HBM-resident
-                and dataset_fits_hbm(x_train,
-                                     extra_bytes=x_valid.nbytes)))
-        transform = None
-        dev_augment = None
-        dequant = False
-        x_all = y_all = None
-        xv_all = yv_all = None
-        dequant_v = False
-        if use_device_data:
-            x_q, dequant = quantize_dataset(x_train)
-            x_all, y_all = place_dataset(x_q, y_train, mesh)
-            xv_q, dequant_v = quantize_dataset(x_valid)
-            xv_all, yv_all = place_dataset(xv_q, y_valid, mesh)
+        inputs = _Inputs(
+            x_train=x_train, y_train=y_train, x_valid=x_valid,
+            y_valid=y_valid, seq_dim=seq_dim,
+            use_device_data=(
+                self.device_data is True
+                or (self.device_data == 'auto'
+                    and device_augs is not None
+                    and y_train is not None
+                    and not self_supervised
+                    and seq_dim is None
+                    # train AND valid both become HBM-resident
+                    and dataset_fits_hbm(x_train,
+                                         extra_bytes=x_valid.nbytes))))
+        if inputs.use_device_data:
+            x_q, inputs.dequant = quantize_dataset(x_train)
+            inputs.x_all, inputs.y_all = place_dataset(x_q, y_train, mesh)
+            xv_q, inputs.dequant_v = quantize_dataset(x_valid)
+            inputs.xv_all, inputs.yv_all = place_dataset(
+                xv_q, y_valid, mesh)
             if device_augs:
-                dev_augment = make_device_augment(
+                inputs.dev_augment = make_device_augment(
                     device_augs, x_train.shape[1:])
         elif self.augment:
             from mlcomp_tpu.contrib.transform import parse_transforms
-            transform = parse_transforms(self.augment)
-        self._close_span()
+            inputs.transform = parse_transforms(self.augment)
+        return inputs
 
-        # resume (reference catalyst.py:218-296): restore last checkpoint,
-        # trim completed stages
-        info = dict(getattr(self, 'additional_info', None) or {})
-        ck_dir = self._checkpoint_folder()
-        steps_per_epoch = max(1, len(x_train) // self.batch_size)
+    def _setup_telemetry(self, ck_dir):
+        """Per-step series recorder + on-demand profiler control (rank
+        0 only — one writer per task, like _report_series). The
+        recorder's hot path is a list append; device values pull at
+        flush (every flush_every steps and at each epoch boundary)."""
+        if self.telemetry_spec is None or self.session is None \
+                or self.task is None or not self._is_main:
+            return
+        from mlcomp_tpu.telemetry import (
+            CompileEventRecorder, DeviceProfiler, HostSyncTripwire,
+            MemorySampler, MetricRecorder, StepAttribution, TaskProfiler,
+        )
+        from mlcomp_tpu.telemetry.deviceprof import (
+            DEFAULT_EVERY, DEFAULT_WINDOW,
+        )
+        # async_flush: the window-full auto-flush (device pull +
+        # DB write) runs on a background thread, never inside the
+        # wrapped train step
+        self._telemetry = MetricRecorder(
+            session=self.session, task=self.task.id,
+            component='train', async_flush=True,
+            flush_every=int(
+                self.telemetry_spec.get('flush_every', 100)))
+        self._profiler = TaskProfiler(self.session, self.task.id,
+                                      ck_dir)
+        # step-time attribution + runtime recompile/host-sync
+        # detection ride the same recorder: phase marks are clock
+        # reads at boundaries the loop already crosses, the
+        # compile listener fires only when XLA actually compiles
+        # (no-op install on builds without jax.monitoring)
+        self._attribution = StepAttribution(recorder=self._telemetry)
+        self._tripwire = HostSyncTripwire(recorder=self._telemetry)
+        self._compile_events = CompileEventRecorder(
+            recorder=self._telemetry)
+        self._compile_events.install()
+        # per-step HBM timeline (telemetry/memory.py): resolves
+        # "does this platform report memory at all" ONCE — inert
+        # on CPU, one allocator-stats read per device on TPU. The
+        # watchdog's OOM predictor and the postmortem bundle both
+        # read the series it emits.
+        self._memory = MemorySampler(
+            self._telemetry,
+            every=int(self.telemetry_spec.get('memory_every', 1)))
+        # sampled device-time profiling (telemetry/deviceprof.py):
+        # like the introspection gates, default ON off-CPU only —
+        # `profile_every: <steps>` in the telemetry spec forces it
+        # either way (0 disables); `profile_steps` sets the window
+        # extent in dispatches
+        prof_every = self.telemetry_spec.get('profile_every')
+        if prof_every is None:
+            prof_every = DEFAULT_EVERY \
+                if jax.default_backend() != 'cpu' else 0
+        if int(prof_every) > 0:
+            self._deviceprof = DeviceProfiler(
+                self.session, self.task.id,
+                every=int(prof_every),
+                window=int(self.telemetry_spec.get(
+                    'profile_steps', DEFAULT_WINDOW)),
+                logger=self.info)
 
-        # telemetry: per-step series recorder + on-demand profiler
-        # control (rank 0 only — one writer per task, like
-        # _report_series). The recorder's hot path is a list append;
-        # device values pull at flush (every flush_every steps and at
-        # each epoch boundary).
-        self._step_flops = None
-        self._memory = None
-        self._comm_probe_ms = None
-        self._introspected = False
-        self._deviceprof = None
-        if self.telemetry_spec is not None and self.session is not None \
-                and self.task is not None and self._is_main:
-            from mlcomp_tpu.telemetry import MetricRecorder, TaskProfiler
-            # async_flush: the window-full auto-flush (device pull +
-            # DB write) runs on a background thread, never inside the
-            # wrapped train step
-            self._telemetry = MetricRecorder(
-                session=self.session, task=self.task.id,
-                component='train', async_flush=True,
-                flush_every=int(
-                    self.telemetry_spec.get('flush_every', 100)))
-            self._profiler = TaskProfiler(self.session, self.task.id,
-                                          ck_dir)
-            # step-time attribution + runtime recompile/host-sync
-            # detection ride the same recorder: phase marks are clock
-            # reads at boundaries the loop already crosses, the
-            # compile listener fires only when XLA actually compiles
-            # (no-op install on builds without jax.monitoring)
-            from mlcomp_tpu.telemetry import (
-                CompileEventRecorder, HostSyncTripwire, MemorySampler,
-                StepAttribution,
-            )
-            self._attribution = StepAttribution(
-                recorder=self._telemetry)
-            self._tripwire = HostSyncTripwire(recorder=self._telemetry)
-            self._compile_events = CompileEventRecorder(
-                recorder=self._telemetry)
-            self._compile_events.install()
-            # per-step HBM timeline (telemetry/memory.py): resolves
-            # "does this platform report memory at all" ONCE — inert
-            # on CPU, one allocator-stats read per device on TPU. The
-            # watchdog's OOM predictor and the postmortem bundle both
-            # read the series it emits.
-            self._memory = MemorySampler(
-                self._telemetry,
-                every=int(self.telemetry_spec.get('memory_every', 1)))
-            # sampled device-time profiling (telemetry/deviceprof.py):
-            # like the introspection gates, default ON off-CPU only —
-            # `profile_every: <steps>` in the telemetry spec forces it
-            # either way (0 disables); `profile_steps` sets the window
-            # extent in dispatches
-            from mlcomp_tpu.telemetry import DeviceProfiler
-            from mlcomp_tpu.telemetry.deviceprof import (
-                DEFAULT_EVERY, DEFAULT_WINDOW,
-            )
-            prof_every = self.telemetry_spec.get('profile_every')
-            if prof_every is None:
-                prof_every = DEFAULT_EVERY \
-                    if jax.default_backend() != 'cpu' else 0
-            if int(prof_every) > 0:
-                self._deviceprof = DeviceProfiler(
-                    self.session, self.task.id,
-                    every=int(prof_every),
-                    window=int(self.telemetry_spec.get(
-                        'profile_steps', DEFAULT_WINDOW)),
-                    logger=self.info)
+    def _want(self, key):
+        """Per-feature introspection gate: 'cost_analysis' /
+        'memory_analysis' / 'collectives' each default ON off-CPU
+        only (the shared AOT lowering is an extra compile the CPU
+        test harness shouldn't pay) and can be forced either way
+        in the telemetry spec."""
+        want = self.telemetry_spec.get(key)
+        if want is None:
+            want = jax.default_backend() != 'cpu'
+        return bool(want)
 
-        def _want(key):
-            """Per-feature introspection gate: 'cost_analysis' /
-            'memory_analysis' / 'collectives' each default ON off-CPU
-            only (the shared AOT lowering is an extra compile the CPU
-            test harness shouldn't pay) and can be forced either way
-            in the telemetry spec."""
-            want = self.telemetry_spec.get(key)
-            if want is None:
-                want = jax.default_backend() != 'cpu'
-            return bool(want)
+    def _abstract_step_args(self, run, inputs):
+        """The train step's arguments after the state, for the
+        introspection compile. The abstract batch carries the REAL
+        input shardings: an unsharded (replicated) one compiles a
+        collective-free program — every device would own the whole
+        batch, no gradient psum — and the collective tally/probe would
+        certify zero comm for a step whose production twin all-reduces
+        every grad."""
+        mesh = run.mesh
+        if inputs.use_device_data:
+            return (inputs.x_all, inputs.y_all, jax.ShapeDtypeStruct(
+                (self.batch_size,), np.int32,
+                sharding=batch_sharding(mesh, 1)))
+        x_train, y_train = inputs.x_train, inputs.y_train
+        return (
+            jax.ShapeDtypeStruct(
+                (self.batch_size,) + x_train.shape[1:], x_train.dtype,
+                sharding=batch_sharding(
+                    mesh, 1 + len(x_train.shape[1:]),
+                    seq_dim=inputs.seq_dim)),
+            None if y_train is None else jax.ShapeDtypeStruct(
+                (self.batch_size,) + y_train.shape[1:], y_train.dtype,
+                sharding=batch_sharding(
+                    mesh, 1 + len(y_train.shape[1:]))))
 
-        def _telemetry_step_introspection(step_fn, *abstract_args):
-            """Compiled-step introspection, once per run off ONE AOT
-            lower+compile: XLA cost analysis (the in-loop half of
-            bench's MFU), static peak memory attribution
-            (telemetry/memory.py), and the collective-communication
-            tally + measured wire probe (telemetry/collectives.py).
-            The ``_introspected`` latch stops later stages from paying
-            the lowering again even when a backend offers none of the
-            analyses."""
-            if self._telemetry is None or self._introspected:
-                return
-            wants = {key: _want(key) for key in
-                     ('cost_analysis', 'memory_analysis',
-                      'collectives')}
-            if not any(wants.values()):
-                return
-            self._introspected = True
-            self._open_span('train.setup.introspect')
+    def _introspect(self, mesh, step_fn, *abstract_args):
+        """Compiled-step introspection, once per run off ONE AOT
+        lower+compile: XLA cost analysis (the in-loop half of
+        bench's MFU), static peak memory attribution
+        (telemetry/memory.py), and the collective-communication
+        tally + measured wire probe (telemetry/collectives.py).
+        The ``_introspected`` latch stops later stages from paying
+        the lowering again even when a backend offers none of the
+        analyses."""
+        if self._telemetry is None or self._introspected:
+            return
+        wants = {key: self._want(key) for key in
+                 ('cost_analysis', 'memory_analysis', 'collectives')}
+        if not any(wants.values()):
+            return
+        self._introspected = True
+        with self._span('train.setup.introspect'):
             try:
                 compiled = step_fn.lower(*abstract_args).compile()
             except Exception as e:
                 self.info(f'telemetry: step introspection skipped '
                           f'({e})')
-                self._close_span()
                 return
             if wants['cost_analysis']:
                 try:
@@ -686,22 +797,19 @@ class JaxTrain(Executor):
                             f'{stats["total_count"]} ops, '
                             f'{stats["total_bytes"] / 1e6:.1f} MB '
                             f'per device{probe}')
-            self._close_span()
 
-        def stage_opt_spec(stage):
-            return stage.get('optimizer') or \
-                self.stages[0].get('optimizer')
+    def _stage_optimizer(self, stage, steps_per_epoch):
+        spec = stage.get('optimizer') or self.stages[0].get('optimizer')
+        return make_optimizer(
+            spec, int(stage.get('epochs', 1)) * steps_per_epoch)[0]
 
-        def stage_steps(stage):
-            return int(stage.get('epochs', 1)) * steps_per_epoch
-
-        # stage-per-dispatch (distributed parity, catalyst.py:354-368):
-        # the task's additional_info names the stage this dispatch runs
-        dispatch_stage = info.get('stage') if self.stage_per_dispatch \
-            else None
-
-        self._open_span('train.setup.state')
-        model = create_model(mesh=mesh, **self.model_spec)
+    @_phase('train.setup.state')
+    def _setup_state(self, run, inputs):
+        """The model and its train state into ``run``: fresh, restored
+        from the last checkpoint (reference catalyst.py:218-296), or
+        seeded with pretrained weights."""
+        mesh, ck_dir, x_train = run.mesh, run.ck_dir, inputs.x_train
+        model = run.model = create_model(mesh=mesh, **self.model_spec)
         stage_names = [s['name'] for s in self.stages]
         # Read the checkpoint meta FIRST: the restore target's opt_state
         # structure must match the optimizer of the stage that SAVED the
@@ -727,47 +835,24 @@ class JaxTrain(Executor):
         target_stage = self.stages[0]
         if meta and meta.get('stage') in stage_names:
             target_stage = self.stages[stage_names.index(meta['stage'])]
-        optimizer, _ = make_optimizer(
-            stage_opt_spec(target_stage), stage_steps(target_stage))
+        optimizer = self._stage_optimizer(target_stage,
+                                          run.steps_per_epoch)
         # init batch must divide the data-parallel axes (shard_map inside
         # the model sees global shapes during init's forward trace)
         sample = x_train[:max(1, data_parallel_size(mesh))]
         state = create_train_state(
             model, optimizer, sample, jax.random.PRNGKey(self.seed),
             mesh=mesh, with_dropout_rng=True)
-        n_params = param_count(state.params)
+        n_params = run.n_params = param_count(state.params)
         self.info(
             f'model={self.model_spec.get("name")} params={n_params:,} '
             f'mesh={dict(mesh.shape)} devices={len(mesh.devices.flat)}')
         self.info('placement: ' + json.dumps(self._placement(
             mesh, state, (self.batch_size,) + x_train.shape[1:],
-            seq_dim)))
+            inputs.seq_dim)))
         if self._telemetry is not None:
-            # the run.snapshot row: the mesh / batch-shape / model
-            # context the postmortem bundle freezes next to the series
-            # (which say WHAT happened — this says on what)
-            from mlcomp_tpu.telemetry import persist_run_snapshot
-            try:
-                persist_run_snapshot(self.session, self.task.id, {
-                    'model': self.model_spec.get('name'),
-                    'model_spec': {k: v for k, v in
-                                   self.model_spec.items()
-                                   if isinstance(v, (str, int, float,
-                                                     bool))},
-                    'n_params': int(n_params),
-                    'mesh': {k: int(v) for k, v in
-                             dict(mesh.shape).items()},
-                    'devices': len(mesh.devices.flat),
-                    'batch_size': int(self.batch_size),
-                    'batch_shape': [int(self.batch_size)]
-                    + [int(d) for d in x_train.shape[1:]],
-                    'input_dtype': str(x_train.dtype),
-                    'loss': self.loss_name,
-                })
-            except Exception:
-                pass            # context is best-effort, never fatal
+            self._persist_run_snapshot(mesh, x_train, n_params)
 
-        epochs_done_global = 0
         restored = None
         if meta is not None:
             try:
@@ -779,9 +864,8 @@ class JaxTrain(Executor):
                 if target_stage is not self.stages[0]:
                     # the state above was built with the saved stage's
                     # optimizer — rebuild for a true from-scratch start
-                    optimizer, _ = make_optimizer(
-                        stage_opt_spec(self.stages[0]),
-                        stage_steps(self.stages[0]))
+                    optimizer = self._stage_optimizer(
+                        self.stages[0], run.steps_per_epoch)
                     state = create_train_state(
                         model, optimizer, sample,
                         jax.random.PRNGKey(self.seed), mesh=mesh,
@@ -815,460 +899,399 @@ class JaxTrain(Executor):
             from mlcomp_tpu.train.pretrained import apply_pretrained
             state, summary = apply_pretrained(state, self.params_file)
             self.info(f'pretrained {self.params_file}: {summary}')
-        best = None
         if restored is not None:
             from mlcomp_tpu.train.loop import place_state
             state = place_state(restored, mesh)
-            epochs_done_global = int(meta.get('epoch', -1)) + 1
-            # seed best-score tracking from the surviving best checkpoint
-            # so a post-resume epoch can't clobber a better best.msgpack
-            best_meta = load_meta(ck_dir, 'best')
-            if best_meta and best_meta.get('score') is not None:
-                best = float(best_meta['score'])
-            if jax.process_count() > 1:
-                # the seed must be UNANIMOUS: is_best gates collective
-                # barriers inside the sharded best-save, so ranks
-                # disagreeing on `best` (a host whose best/ folder
-                # missed the sync) would split at the barrier and hang
-                from jax.experimental import multihost_utils
-                seeds = multihost_utils.process_allgather(np.array(
-                    [best is not None,
-                     float('nan') if best is None else float(best)]))
-                flags, scores = seeds[:, 0], seeds[:, 1]
-                same = flags.all() and (
-                    np.nanmax(scores) - np.nanmin(scores) < 1e-12) \
-                    or not flags.any()
-                if not same:
-                    raise RuntimeError(
-                        f'best-checkpoint meta differs across hosts '
-                        f'({seeds.tolist()}) — sync the checkpoint '
-                        f'folder before resuming')
+            run.first_global_epoch = run.global_epoch = \
+                int(meta.get('epoch', -1)) + 1
+            run.best = self._restored_best(ck_dir)
             self.info(
                 f'resumed from checkpoint: stage={meta.get("stage")} '
-                f'epoch={meta.get("epoch")} best={best}')
-        self._close_span()
-        remaining, start_epoch = resume_plan(self.stages, meta)
-        if dispatch_stage is not None:
-            remaining = [s for s in remaining
-                         if s['name'] == dispatch_stage] or remaining[:1]
-        global_epoch = epochs_done_global
-        images_seen = 0
-        for stage in remaining:
-            stage_name = stage['name']
-            stage_idx = stage_names.index(stage_name)
-            optimizer, _ = make_optimizer(
-                stage_opt_spec(stage), stage_steps(stage))
-            if use_device_data:
-                from mlcomp_tpu.train.loop import (
-                    make_device_epoch_fn, make_device_train_step,
-                )
-                if self.epoch_scan:
-                    epoch_fn = make_device_epoch_fn(
-                        model, optimizer, loss_fn, mesh=mesh,
-                        augment=dev_augment, dequantize=dequant,
-                        row_shape=x_train.shape[1:])
-                else:
-                    train_step = make_device_train_step(
-                        model, optimizer, loss_fn, mesh=mesh,
-                        augment=dev_augment, dequantize=dequant,
-                        row_shape=x_train.shape[1:])
-            else:
-                train_step = make_train_step(
-                    model, optimizer, loss_fn, mesh=mesh,
-                    self_supervised=self_supervised)
-            if self._telemetry is not None \
-                    and not (use_device_data and self.epoch_scan):
-                import jax.numpy as jnp
-                # abstract batch args carry the REAL input shardings:
-                # an unsharded (replicated) abstract batch compiles a
-                # collective-free program — every device would own the
-                # whole batch, no gradient psum — and the collective
-                # tally/probe would silently certify zero comm for a
-                # step whose production twin all-reduces every grad
-                if use_device_data:
-                    _telemetry_step_introspection(
-                        train_step, state, x_all, y_all,
-                        jax.ShapeDtypeStruct(
-                            (self.batch_size,), jnp.int32,
-                            sharding=batch_sharding(mesh, 1)))
-                else:
-                    _telemetry_step_introspection(
-                        train_step, state,
-                        jax.ShapeDtypeStruct(
-                            (self.batch_size,) + x_train.shape[1:],
-                            x_train.dtype,
-                            sharding=batch_sharding(
-                                mesh, 1 + len(x_train.shape[1:]),
-                                seq_dim=seq_dim)),
-                        None if y_train is None else
-                        jax.ShapeDtypeStruct(
-                            (self.batch_size,) + y_train.shape[1:],
-                            y_train.dtype,
-                            sharding=batch_sharding(
-                                mesh,
-                                1 + len(y_train.shape[1:]))))
-                from mlcomp_tpu.train.loop import instrumented_step
-                train_step = instrumented_step(
-                    train_step, self._telemetry,
-                    batch_size=self.batch_size,
-                    attribution=self._attribution,
-                    tripwire=self._tripwire,
-                    compile_events=self._compile_events,
-                    memory=self._memory,
-                    deviceprof=self._deviceprof)
+                f'epoch={meta.get("epoch")} best={run.best}')
+        run.state, run.meta = state, meta
+
+    def _persist_run_snapshot(self, mesh, x_train, n_params):
+        """The run.snapshot row: the mesh / batch-shape / model context
+        the postmortem bundle freezes next to the series (which say
+        WHAT happened — this says on what)."""
+        from mlcomp_tpu.telemetry import persist_run_snapshot
+        try:
+            persist_run_snapshot(self.session, self.task.id, {
+                'model': self.model_spec.get('name'),
+                'model_spec': {k: v for k, v in
+                               self.model_spec.items()
+                               if isinstance(v, (str, int, float,
+                                                 bool))},
+                'n_params': int(n_params),
+                'mesh': {k: int(v) for k, v in
+                         dict(mesh.shape).items()},
+                'devices': len(mesh.devices.flat),
+                'batch_size': int(self.batch_size),
+                'batch_shape': [int(self.batch_size)]
+                + [int(d) for d in x_train.shape[1:]],
+                'input_dtype': str(x_train.dtype),
+                'loss': self.loss_name,
+            })
+        except Exception:
+            pass            # context is best-effort, never fatal
+
+    @staticmethod
+    def _restored_best(ck_dir):
+        """Best-score tracking seeded from the surviving best
+        checkpoint, so a post-resume epoch can't clobber a better
+        best.msgpack."""
+        best = None
+        best_meta = load_meta(ck_dir, 'best')
+        if best_meta and best_meta.get('score') is not None:
+            best = float(best_meta['score'])
+        if jax.process_count() > 1:
+            # the seed must be UNANIMOUS: is_best gates collective
+            # barriers inside the sharded best-save, so ranks
+            # disagreeing on `best` (a host whose best/ folder
+            # missed the sync) would split at the barrier and hang
+            from jax.experimental import multihost_utils
+            seeds = multihost_utils.process_allgather(np.array(
+                [best is not None,
+                 float('nan') if best is None else float(best)]))
+            flags, scores = seeds[:, 0], seeds[:, 1]
+            same = flags.all() and (
+                np.nanmax(scores) - np.nanmin(scores) < 1e-12) \
+                or not flags.any()
+            if not same:
+                raise RuntimeError(
+                    f'best-checkpoint meta differs across hosts '
+                    f'({seeds.tolist()}) — sync the checkpoint '
+                    f'folder before resuming')
+        return best
+
+    # ------------------------------------------------------------ per stage
+    def _setup_stage(self, run, inputs, spec) -> _Stage:
+        """The stage's optimizer, its train step (wrapped for telemetry)
+        and its validation call, by input path."""
+        mesh, model, loss_fn = run.mesh, run.model, run.loss_fn
+        optimizer = self._stage_optimizer(spec, run.steps_per_epoch)
+        # validation takes the rows of one batch and their weights. On
+        # the device-data path the valid set is HBM-resident too —
+        # per-batch transfer is an index + weight vector, not the images
+        if inputs.use_device_data:
+            from mlcomp_tpu.train.loop import (
+                make_device_eval_step, make_device_train_step,
+            )
+            train_step = make_device_train_step(
+                model, optimizer, loss_fn, mesh=mesh,
+                augment=inputs.dev_augment, dequantize=inputs.dequant,
+                row_shape=inputs.x_train.shape[1:])
+            eval_step = make_device_eval_step(
+                model, loss_fn, mesh=mesh, dequantize=inputs.dequant_v,
+                row_shape=inputs.x_valid.shape[1:])
+
+            def evaluate(state, take, w_dev):
+                idx = jax.device_put(take.astype(np.int32),
+                                     batch_sharding(mesh, 1))
+                return eval_step(state, inputs.xv_all, inputs.yv_all,
+                                 idx, w_dev)
+        else:
+            train_step = make_train_step(
+                model, optimizer, loss_fn, mesh=mesh,
+                self_supervised=run.self_supervised)
             eval_step = make_eval_step(
                 model, loss_fn, mesh=mesh,
-                self_supervised=self_supervised)
-            if use_device_data:
-                from mlcomp_tpu.train.loop import make_device_eval_step
-                eval_step_dev = make_device_eval_step(
-                    model, loss_fn, mesh=mesh, dequantize=dequant_v,
-                    row_shape=x_valid.shape[1:])
-            first_epoch = start_epoch if stage is remaining[0] else 0
-            if first_epoch == 0 and stage is not self.stages[0]:
-                # stage boundary: fresh optimizer state, keep params
-                # (resuming mid-stage keeps the restored opt state)
-                state = state.replace(
-                    opt_state=optimizer.init(state.params))
-            self.step.start(1, f'stage {stage_name}', stage_idx)
-            for epoch in range(first_epoch, int(stage.get('epochs', 1))):
-                # a static `profile:` trace opens first, so that it
-                # holds the whole train.epoch annotation
-                profiling = self._maybe_start_profile(global_epoch,
-                                                      ck_dir)
-                # the epoch's phases, in order, as spans (DB rows for
-                # every epoch, host-line annotations in any profiler
-                # trace): begin (up to the first dispatch) -> steps ->
-                # drain (wait for the last step, pull its metrics) ->
-                # valid -> report (nothing queued on the device) ->
-                # checkpoint. Per-step phases stay the step.phase.*
-                # counters.
-                self._open_span('train.epoch', epoch=global_epoch,
-                                stage=stage_name)
-                self._open_span('train.epoch.begin')
-                self.step.start(2, f'epoch {epoch}', epoch)
-                ep_rng = np.random.RandomState(self.seed * 1000 + epoch)
-                t_ep = time.time()
-                if steps_per_epoch * self.batch_size > len(x_train):
-                    raise ValueError(
-                        f'dataset has {len(x_train)} train samples — '
-                        f'fewer than batch_size={self.batch_size}; no '
-                        f'full batch to train on')
-                if use_device_data:
-                    dropped = len(x_train) % self.batch_size
-                    if dropped and global_epoch == epochs_done_global:
-                        self.info(
-                            f'dropping {dropped} tail samples '
-                            f'(n={len(x_train)} not divisible by '
-                            f'batch_size={self.batch_size})')
-                    perm = ep_rng.permutation(
-                        len(x_train))[:steps_per_epoch * self.batch_size]
-                    perm = perm.astype(np.int32).reshape(
-                        steps_per_epoch, self.batch_size)
-                    if self.epoch_scan:
-                        perm_dev = jax.device_put(
-                            perm, batch_sharding(mesh, 2, batch_dim=1))
-                        self._next_span('train.epoch.steps')
-                        # one XLA dispatch runs the whole epoch
-                        state, metric_arrays = epoch_fn(
-                            state, x_all, y_all, perm_dev)
-                        self._next_span('train.epoch.drain')
-                        train_agg = {
-                            k: float(np.mean(np.asarray(v)))
-                            for k, v in metric_arrays.items()}
-                    else:
-                        train_metrics = []
-                        attr = self._attribution
-                        self._next_span('train.epoch.steps')
-                        for s in range(steps_per_epoch):
-                            # device-data path attribution: permutation
-                            # slicing is the data wait, the index
-                            # device_put is the h2d leg (the batch
-                            # itself is already HBM-resident)
-                            if attr is not None:
-                                attr.begin('data_wait')
-                            idx_host = perm[s]
-                            if attr is not None:
-                                attr.begin('h2d')
-                            idx = jax.device_put(
-                                idx_host, batch_sharding(mesh, 1))
-                            state, metrics = train_step(
-                                state, x_all, y_all, idx)
-                            train_metrics.append(metrics)
-                        self._next_span('train.epoch.drain')
-                        train_agg = aggregate_metrics(train_metrics)
-                    images_seen += steps_per_epoch * self.batch_size
-                else:
-                    train_metrics = []
-                    batches = iterate_batches(
-                        x_train, y_train, self.batch_size, ep_rng,
-                        transform=transform,
-                        logger=self.info if global_epoch ==
-                        epochs_done_global else None)
-                    placed = iter(prefetch_batches(
-                        batches, mesh, seq_dim=seq_dim,
-                        depth=self.prefetch,
-                        attribution=self._attribution))
-                    # the first batches are shuffled, augmented and
-                    # placed before anything is dispatched: that is
-                    # still the epoch's beginning
-                    first = next(placed, None)
-                    self._next_span('train.epoch.steps')
-                    for x, y in itertools.chain(
-                            () if first is None else (first,), placed):
-                        state, metrics = train_step(state, x, y)
-                        train_metrics.append(metrics)
-                        images_seen += self.batch_size
-                    if not train_metrics:
-                        raise ValueError(
-                            f'dataset has {len(x_train)} train samples '
-                            f'— fewer than batch_size='
-                            f'{self.batch_size}; no full batch')
-                    # metrics: device→host ONCE per epoch
-                    self._next_span('train.epoch.drain')
-                    train_agg = aggregate_metrics(train_metrics)
-                train_dt = time.time() - t_ep
-                self._next_span('train.epoch.valid')
-                # evaluate EVERY validation sample: tail batches are
-                # padded (duplicate samples) up to a multiple of the
-                # data-parallel width, with zero weights on the padding so
-                # aggregates stay exact. On the device-data path the
-                # valid set is HBM-resident too — per-batch transfer is
-                # an index + weight vector, not the images.
-                dp = max(1, data_parallel_size(mesh))
-                valid_metrics, valid_weights = [], []
-                n_valid_total = len(x_valid)
-                for start in range(0, n_valid_total,
-                                   self.eval_batch_size):
-                    n_real = min(self.eval_batch_size,
-                                 n_valid_total - start)
-                    n_padded = -(-n_real // dp) * dp
-                    take = np.resize(np.arange(start, start + n_real),
-                                     n_padded)
-                    w = np.ones(n_padded, np.float32)
-                    w[n_real:] = 0.0
-                    w_dev = jax.device_put(w, batch_sharding(mesh, 1))
-                    if use_device_data:
-                        idx = jax.device_put(
-                            take.astype(np.int32),
-                            batch_sharding(mesh, 1))
-                        valid_metrics.append(eval_step_dev(
-                            state, xv_all, yv_all, idx, w_dev))
-                    else:
-                        bx = x_valid[take]
-                        by = y_valid[take] if y_valid is not None \
-                            else None
-                        x, y = place_batch((bx, by), mesh,
-                                           seq_dim=seq_dim)
-                        valid_metrics.append(
-                            eval_step(state, x, y, w_dev))
-                    valid_weights.append(n_real)
-                valid_agg = aggregate_metrics(valid_metrics,
-                                              weights=valid_weights)
+                self_supervised=run.self_supervised)
 
-                self._next_span('train.epoch.report')
-                n_train = steps_per_epoch * self.batch_size
-                # the model's counters (train/loop.py STEP_COUNTERS):
-                # the epoch's mean over its steps, a series each
-                counters = {k: train_agg.pop(k) for k in STEP_COUNTERS
-                            if k in train_agg}
-                for k, v in train_agg.items():
-                    self._report_series(k, v, global_epoch, 'train',
-                                        stage_name)
-                for k, v in valid_agg.items():
-                    self._report_series(k, v, global_epoch, 'valid',
-                                        stage_name)
-                self._report_series('images_per_sec', n_train / train_dt,
-                                    global_epoch, 'train', stage_name)
-                if self._telemetry is not None:
-                    tel = self._telemetry
-                    if use_device_data and self.epoch_scan:
-                        # scan path has no per-step host loop — the
-                        # [steps] metric arrays land as series in one
-                        # host pull
-                        base = global_epoch * steps_per_epoch
-                        for k, v in metric_arrays.items():
-                            tel.series_array(k, np.asarray(v), base)
-                    for k, v in counters.items():
-                        tel.series(k, v, step=global_epoch)
-                    tel.gauge('epoch_time_s', train_dt)
-                    tel.gauge('epoch_throughput', n_train / train_dt)
-                    if self._step_flops:
-                        from mlcomp_tpu.telemetry import mfu as _mfu
-                        peak = float(self.telemetry_spec.get(
-                            'peak_tflops',
-                            os.environ.get('MLCOMP_PEAK_TFLOPS', 197)))
-                        tel.gauge('mfu', _mfu(
-                            self._step_flops,
-                            steps_per_epoch / train_dt,
-                            len(mesh.devices.flat), peak))
-                    from mlcomp_tpu.telemetry import record_device_stats
-                    record_device_stats(tel)
-                    if self._comm_probe_ms:
-                        # measured comm share of the observed step:
-                        # the wire time of this step's collectives
-                        # (telemetry/collectives.py probe, once per
-                        # stage) over the epoch's mean step time — the
-                        # "is my step communication-bound" series
-                        step_ms = train_dt * 1e3 / steps_per_epoch
-                        if step_ms > 0:
-                            tel.series(
-                                'comm.fraction',
-                                min(1.0,
-                                    self._comm_probe_ms / step_ms),
-                                step=global_epoch)
-                    if self._attribution is not None \
-                            and self._attribution.steps:
-                        # bench's pipeline_efficiency, from inside the
-                        # real run (per-step step.phase.* series landed
-                        # already; this is the per-epoch derived gauge)
-                        self._attribution.emit_epoch(
-                            tel, epoch=global_epoch)
-                    tel.flush()
-                if self._profiler is not None:
-                    self._profiler.poll()
+            def evaluate(state, take, w_dev):
+                bx = inputs.x_valid[take]
+                by = inputs.y_valid[take] \
+                    if inputs.y_valid is not None else None
+                x, y = place_batch((bx, by), mesh,
+                                   seq_dim=inputs.seq_dim)
+                return eval_step(state, x, y, w_dev)
+        if self._telemetry is not None:
+            self._introspect(mesh, train_step, run.state,
+                             *self._abstract_step_args(run, inputs))
+            from mlcomp_tpu.train.loop import instrumented_step
+            train_step = instrumented_step(
+                train_step, self._telemetry,
+                batch_size=self.batch_size,
+                attribution=self._attribution,
+                tripwire=self._tripwire,
+                compile_events=self._compile_events,
+                memory=self._memory,
+                deviceprof=self._deviceprof)
+        return _Stage(name=spec['name'],
+                      epochs=int(spec.get('epochs', 1)),
+                      optimizer=optimizer, train_step=train_step,
+                      evaluate=evaluate)
+
+    # ------------------------------------------------------------ per epoch
+    def _epoch(self, run, inputs, stage, epoch):
+        """One epoch, its phases in order as spans (DB rows for every
+        epoch, host-line annotations in any profiler trace): begin (up
+        to the first dispatch) -> steps -> drain (wait for the last
+        step, pull its metrics) -> valid -> report (nothing queued on
+        the device) -> checkpoint. Per-step phases stay the
+        step.phase.* counters."""
+        # a static `profile:` trace opens first, so that it holds the
+        # whole train.epoch annotation
+        profiling = self._maybe_start_profile(run.global_epoch,
+                                              run.ck_dir)
+        with self._span('train.epoch', epoch=run.global_epoch,
+                        stage=stage.name):
+            train_agg, train_dt = self._train_epoch(run, inputs, stage,
+                                                    epoch)
+            valid_agg = self._validate(run, inputs, stage)
+            score, is_best, sweep_rung = self._report_epoch(
+                run, stage, train_agg, valid_agg, train_dt)
+            self._checkpoint(run, stage, epoch, score, is_best,
+                             sweep_rung)
+        if profiling:
+            self._stop_profile(run.global_epoch)
+        run.global_epoch += 1
+        # chaos seams (mlcomp_tpu/testing/faults.py): the
+        # kill-worker-mid-epoch fault dies HERE, after epoch
+        # N's checkpoint submit — one module-global check per
+        # seam when no faults are armed. gang.rank_exit
+        # additionally carries the rank + gang so a `when`
+        # filter kills exactly one rank of a multi-host gang
+        # (the elastic-recovery acceptance chaos), even though
+        # MLCOMP_FAULTS arms every rank's subprocess alike
+        from mlcomp_tpu.testing.faults import fault_point
+        fault_point('train.epoch', epoch=run.global_epoch,
+                    task=self.task.id if self.task else None)
+        distr = dict(getattr(self, 'additional_info', None)
+                     or {}).get('distr_info') or {}
+        if distr:
+            fault_point(
+                'gang.rank_exit', phase='epoch',
+                epoch=run.global_epoch,
+                rank=distr.get('process_index'),
+                gang=(distr.get('gang') or {}).get('id'),
+                task=self.task.id if self.task else None)
+
+    def _feed(self, run, inputs, ep_rng):
+        """The epoch's train batches as the arguments of the stage's
+        train step after the state, one tuple a step. What is done here
+        and not in the iterator is still the epoch's beginning."""
+        x_train = inputs.x_train
+        steps = run.steps_per_epoch
+        if steps * self.batch_size > len(x_train):
+            raise ValueError(
+                f'dataset has {len(x_train)} train samples — '
+                f'fewer than batch_size={self.batch_size}; no '
+                f'full batch to train on')
+        first_of_run = run.global_epoch == run.first_global_epoch
+        if inputs.use_device_data:
+            dropped = len(x_train) % self.batch_size
+            if dropped and first_of_run:
                 self.info(
-                    f'[{stage_name}] epoch {global_epoch}: '
-                    f'train {train_agg} valid {valid_agg} '
-                    f'({n_train / train_dt:.0f} samples/s)')
+                    f'dropping {dropped} tail samples '
+                    f'(n={len(x_train)} not divisible by '
+                    f'batch_size={self.batch_size})')
+            perm = ep_rng.permutation(
+                len(x_train))[:steps * self.batch_size]
+            perm = perm.astype(np.int32).reshape(steps, self.batch_size)
+            return self._device_feed(run.mesh, inputs, perm)
+        batches = iterate_batches(
+            x_train, inputs.y_train, self.batch_size, ep_rng,
+            transform=inputs.transform,
+            logger=self.info if first_of_run else None)
+        placed = iter(prefetch_batches(
+            batches, run.mesh, seq_dim=inputs.seq_dim,
+            depth=self.prefetch, attribution=self._attribution))
+        # the first batches are shuffled, augmented and placed before
+        # anything is dispatched
+        first = next(placed, None)
+        return itertools.chain(() if first is None else (first,), placed)
 
-                score = valid_agg.get(self.main_metric,
-                                      train_agg.get(self.main_metric))
-                is_best = score is not None and (
-                    best is None or
-                    (score < best if self.minimize else score > best))
-                if is_best:
-                    best = score
-                    self._update_scores(score)
-                # ASHA sweep cell (additional_info['sweep'], stamped
-                # at submission): report the rung score the supervisor
-                # judges on — immediate row + supervisor wakeup, so a
-                # losing cell is pruned at the next tick instead of
-                # training a whole extra rung
-                sweep_rung = self._report_sweep(
-                    global_epoch, steps_per_epoch, score)
-                # checkpoint cadence: pulling the full state to host is
-                # the dominant per-epoch cost on slow host links — save
-                # on best, every checkpoint_every-th epoch, and at the
-                # stage's final epoch (so resume/export always has a
-                # fresh `last`)
-                last_of_stage = epoch == int(stage.get('epochs', 1)) - 1
-                # checkpoint_every: 0 disables saving entirely — for
-                # grid-search cells whose artifacts are throwaway, the
-                # device->host state gather and its write are cost a
-                # short task need not pay. Such
-                # runs cannot resume or export — incompatible consumers
-                # (stage_per_dispatch, model_name, infer_valid
-                # best_only) are rejected in __init__
-                # sweep rung boundaries force a save: promotion is
-                # checkpoint-aware — a promoted cell that later dies
-                # transiently resumes from its RUNG checkpoint through
-                # the ordinary retry path (checkpoint_every: 0 still
-                # wins: throwaway cells stay saveless by contract)
-                should_save = self.checkpoint_every != 0 and (
-                    is_best or self.checkpoint_every <= 1
-                    or (global_epoch + 1) % self.checkpoint_every == 0
-                    or last_of_stage or sweep_rung)
-                if should_save:
-                    self._next_span('train.epoch.checkpoint')
-                    meta_d = {'stage': stage_name,
-                              'stage_epoch': epoch,
-                              'epoch': global_epoch, 'score': score,
-                              'step': int(state.step)}
-                    from mlcomp_tpu.train.ckpt_shard import (
-                        build_shard_plan, state_needs_sharded_ckpt,
-                        write_shard_plan,
-                    )
-                    if state_needs_sharded_ckpt(state):
-                        # sharded format: each process pulls only ITS
-                        # addressable replica-0 shards (no collective,
-                        # no full-state buffer on any host) and writes
-                        # its own fragment files; rank 0 adds the index
-                        plan = build_shard_plan(state)
-                        if self._ckpt_writer is not None \
-                                and jax.process_count() == 1:
-                            # off-thread only single-process: the
-                            # multi-process write barriers are
-                            # collectives and must stay on the main
-                            # thread, ordered with the train step's
-                            self._ckpt_writer.submit_job(
-                                write_shard_plan, ck_dir, plan,
-                                meta_d, best=is_best)
-                        else:
-                            write_shard_plan(ck_dir, plan, meta_d,
-                                             best=is_best)
-                    else:
-                        # single-process by construction (multi-process
-                        # always takes the sharded branch above): flat
-                        # msgpack blob (reference rank-0 write,
-                        # catalyst.py:298-311)
-                        host_state = jax.device_get(state)
-                        if self._ckpt_writer is not None:
-                            # serialise+write off-thread: the next
-                            # epoch's compute overlaps the disk IO
-                            self._ckpt_writer.submit(
-                                ck_dir, host_state, meta_d,
-                                best=is_best)
-                        else:
-                            save_checkpoint(ck_dir, host_state, meta_d,
-                                            best=is_best)
-                self._close_span()      # report, or checkpoint
-                self._close_span()      # train.epoch
-                if profiling:
-                    self._stop_profile(global_epoch)
-                global_epoch += 1
-                # chaos seams (mlcomp_tpu/testing/faults.py): the
-                # kill-worker-mid-epoch fault dies HERE, after epoch
-                # N's checkpoint submit — one module-global check per
-                # seam when no faults are armed. gang.rank_exit
-                # additionally carries the rank + gang so a `when`
-                # filter kills exactly one rank of a multi-host gang
-                # (the elastic-recovery acceptance chaos), even though
-                # MLCOMP_FAULTS arms every rank's subprocess alike
-                from mlcomp_tpu.testing.faults import fault_point
-                fault_point('train.epoch', epoch=global_epoch,
-                            task=self.task.id if self.task else None)
-                distr = dict(getattr(self, 'additional_info', None)
-                             or {}).get('distr_info') or {}
-                if distr:
-                    fault_point(
-                        'gang.rank_exit', phase='epoch',
-                        epoch=global_epoch,
-                        rank=distr.get('process_index'),
-                        gang=(distr.get('gang') or {}).get('id'),
-                        task=self.task.id if self.task else None)
-            if (dispatch_stage is not None or self.stage_per_dispatch) \
-                    and stage_name != stage_names[-1]:
-                # return for requeue: next dispatch runs the next stage.
-                # The LAST stage's dispatch falls through instead so the
-                # model export / report-img pass still runs.
-                self._drain_ckpt_writer()   # requeued stage reads last
-                return {'stage': stage_name, 'stages': stage_names,
-                        'best_score': best}
+    def _device_feed(self, mesh, inputs, perm):
+        attr, sharding = self._attribution, batch_sharding(mesh, 1)
+        for s in range(len(perm)):
+            # device-data path attribution: permutation slicing is the
+            # data wait, the index device_put is the h2d leg (the batch
+            # itself is already HBM-resident)
+            if attr is not None:
+                attr.begin('data_wait')
+            idx_host = perm[s]
+            if attr is not None:
+                attr.begin('h2d')
+            yield inputs.x_all, inputs.y_all, jax.device_put(
+                idx_host, sharding)
 
-        # everything below reads checkpoint files — drain pending writes
-        self._drain_ckpt_writer()
-        if self._is_main and self.model_name:
-            self._export_model(ck_dir, best,
-                               input_shape=[int(d) for d in
-                                            x_train.shape[1:]],
-                               input_dtype=str(x_train.dtype))
-        # the post-train passes run collective programs (valid forward,
-        # checkpoint gather) — EVERY rank must execute the same sequence;
-        # only rank 0 touches DB/filesystem inside each helper
-        if self.report_imgs and self.session is not None \
-                and self.task is not None:
-            self._build_report_imgs(model, state, mesh, x_valid, y_valid,
-                                    max(global_epoch - 1, 0))
-        if self.infer_valid:
-            self._infer_valid(model, state, mesh, ck_dir, x_valid,
-                              y_valid)
+    def _train_epoch(self, run, inputs, stage, epoch):
+        """begin -> steps -> drain: each phase ends where the next
+        starts. Returns the epoch's mean train metrics and its
+        seconds."""
+        with self._span('train.epoch.begin'):
+            self.step.start(2, f'epoch {epoch}', epoch)
+            ep_rng = np.random.RandomState(self.seed * 1000 + epoch)
+            t_ep = time.time()
+            feed = self._feed(run, inputs, ep_rng)
+            train_metrics = []
+        with self._span('train.epoch.steps'):
+            for args in feed:
+                run.state, metrics = stage.train_step(run.state, *args)
+                train_metrics.append(metrics)
+        with self._span('train.epoch.drain'):
+            # metrics: device→host ONCE per epoch
+            train_agg = aggregate_metrics(train_metrics)
+            run.images_seen += len(train_metrics) * self.batch_size
+            train_dt = time.time() - t_ep
+        return train_agg, train_dt
 
-        wall = time.time() - t_start
-        return {'stage': stage_names[-1], 'stages': stage_names,
-                'best_score': best, 'n_params': n_params,
-                'wall_time_s': wall,
-                'samples_per_sec': images_seen / max(wall, 1e-9)}
+    @_phase('train.epoch.valid')
+    def _validate(self, run, inputs, stage):
+        """Evaluate EVERY validation sample: tail batches are padded
+        (duplicate samples) up to a multiple of the data-parallel
+        width, with zero weights on the padding so aggregates stay
+        exact."""
+        mesh = run.mesh
+        dp = max(1, data_parallel_size(mesh))
+        valid_metrics, valid_weights = [], []
+        n_valid_total = len(inputs.x_valid)
+        for start in range(0, n_valid_total, self.eval_batch_size):
+            n_real = min(self.eval_batch_size, n_valid_total - start)
+            n_padded = -(-n_real // dp) * dp
+            take = np.resize(np.arange(start, start + n_real), n_padded)
+            w = np.ones(n_padded, np.float32)
+            w[n_real:] = 0.0
+            w_dev = jax.device_put(w, batch_sharding(mesh, 1))
+            valid_metrics.append(stage.evaluate(run.state, take, w_dev))
+            valid_weights.append(n_real)
+        return aggregate_metrics(valid_metrics, weights=valid_weights)
+
+    @_phase('train.epoch.report')
+    def _report_epoch(self, run, stage, train_agg, valid_agg, train_dt):
+        """The epoch's host rows (series, gauges, the log line) and
+        what they decide: ``(score, is_best, sweep_rung)``."""
+        global_epoch = run.global_epoch
+        steps_per_epoch = run.steps_per_epoch
+        n_train = steps_per_epoch * self.batch_size
+        # the model's counters (train/loop.py STEP_COUNTERS):
+        # the epoch's mean over its steps, a series each
+        counters = {k: train_agg.pop(k) for k in STEP_COUNTERS
+                    if k in train_agg}
+        for k, v in train_agg.items():
+            self._report_series(k, v, global_epoch, 'train', stage.name)
+        for k, v in valid_agg.items():
+            self._report_series(k, v, global_epoch, 'valid', stage.name)
+        self._report_series('images_per_sec', n_train / train_dt,
+                            global_epoch, 'train', stage.name)
+        if self._telemetry is not None:
+            tel = self._telemetry
+            for k, v in counters.items():
+                tel.series(k, v, step=global_epoch)
+            tel.gauge('epoch_time_s', train_dt)
+            tel.gauge('epoch_throughput', n_train / train_dt)
+            if self._step_flops:
+                from mlcomp_tpu.telemetry import mfu as _mfu
+                peak = float(self.telemetry_spec.get(
+                    'peak_tflops',
+                    os.environ.get('MLCOMP_PEAK_TFLOPS', 197)))
+                tel.gauge('mfu', _mfu(
+                    self._step_flops, steps_per_epoch / train_dt,
+                    len(run.mesh.devices.flat), peak))
+            from mlcomp_tpu.telemetry import record_device_stats
+            record_device_stats(tel)
+            if self._comm_probe_ms:
+                # measured comm share of the observed step: the wire
+                # time of this step's collectives
+                # (telemetry/collectives.py probe, once per stage)
+                # over the epoch's mean step time — the "is my step
+                # communication-bound" series
+                step_ms = train_dt * 1e3 / steps_per_epoch
+                if step_ms > 0:
+                    tel.series(
+                        'comm.fraction',
+                        min(1.0, self._comm_probe_ms / step_ms),
+                        step=global_epoch)
+            if self._attribution is not None \
+                    and self._attribution.steps:
+                # bench's pipeline_efficiency, from inside the real
+                # run (per-step step.phase.* series landed already;
+                # this is the per-epoch derived gauge)
+                self._attribution.emit_epoch(tel, epoch=global_epoch)
+            tel.flush()
+        if self._profiler is not None:
+            self._profiler.poll()
+        self.info(
+            f'[{stage.name}] epoch {global_epoch}: '
+            f'train {train_agg} valid {valid_agg} '
+            f'({n_train / train_dt:.0f} samples/s)')
+
+        score = valid_agg.get(self.main_metric,
+                              train_agg.get(self.main_metric))
+        is_best = score is not None and (
+            run.best is None or
+            (score < run.best if self.minimize else score > run.best))
+        if is_best:
+            run.best = score
+            self._update_scores(score)
+        # ASHA sweep cell (additional_info['sweep'], stamped at
+        # submission): report the rung score the supervisor judges on
+        # — immediate row + supervisor wakeup, so a losing cell is
+        # pruned at the next tick instead of training a whole extra
+        # rung
+        sweep_rung = self._report_sweep(
+            global_epoch, steps_per_epoch, score)
+        return score, is_best, sweep_rung
+
+    def _checkpoint(self, run, stage, epoch, score, is_best, sweep_rung):
+        """Save by the cadence: pulling the full state to host is the
+        dominant per-epoch cost on slow host links — save on best,
+        every checkpoint_every-th epoch, and at the stage's final epoch
+        (so resume/export always has a fresh `last`). The span is
+        absent where nothing is saved."""
+        # checkpoint_every: 0 disables saving entirely — for
+        # grid-search cells whose artifacts are throwaway (they cannot
+        # resume or export: __init__ rejects the consumers). Sweep rung
+        # boundaries force a save: promotion is checkpoint-aware — a
+        # promoted cell that later dies transiently resumes from its
+        # RUNG checkpoint through the ordinary retry path
+        # (checkpoint_every: 0 still wins)
+        if self.checkpoint_every == 0 or not (
+                is_best or self.checkpoint_every <= 1
+                or (run.global_epoch + 1) % self.checkpoint_every == 0
+                or epoch == stage.epochs - 1 or sweep_rung):
+            return
+        with self._span('train.epoch.checkpoint'):
+            state, ck_dir = run.state, run.ck_dir
+            meta_d = {'stage': stage.name, 'stage_epoch': epoch,
+                      'epoch': run.global_epoch, 'score': score,
+                      'step': int(state.step)}
+            from mlcomp_tpu.train.ckpt_shard import (
+                build_shard_plan, state_needs_sharded_ckpt,
+                write_shard_plan,
+            )
+            if state_needs_sharded_ckpt(state):
+                # sharded format: each process pulls only ITS
+                # addressable replica-0 shards (no collective, no
+                # full-state buffer on any host) and writes its own
+                # fragment files; rank 0 adds the index
+                plan = build_shard_plan(state)
+                if self._ckpt_writer is not None \
+                        and jax.process_count() == 1:
+                    # off-thread only single-process: the
+                    # multi-process write barriers are collectives and
+                    # must stay on the main thread, ordered with the
+                    # train step's
+                    self._ckpt_writer.submit_job(
+                        write_shard_plan, ck_dir, plan, meta_d,
+                        best=is_best)
+                else:
+                    write_shard_plan(ck_dir, plan, meta_d,
+                                     best=is_best)
+            else:
+                # single-process by construction (multi-process always
+                # takes the sharded branch above): flat msgpack blob
+                # (reference rank-0 write, catalyst.py:298-311)
+                host_state = jax.device_get(state)
+                if self._ckpt_writer is not None:
+                    # serialise+write off-thread: the next epoch's
+                    # compute overlaps the disk IO
+                    self._ckpt_writer.submit(
+                        ck_dir, host_state, meta_d, best=is_best)
+                else:
+                    save_checkpoint(ck_dir, host_state, meta_d,
+                                    best=is_best)
 
     def _maybe_start_profile(self, global_epoch, ck_dir) -> bool:
         """Start an XLA device trace if this epoch is in the profile
@@ -1308,21 +1331,14 @@ class JaxTrain(Executor):
         jitted forward is cached so both passes compile it once)."""
         forward = getattr(self, '_eval_forward', None)
         if forward is None:
-            import flax.linen as nn
             import jax.numpy as jnp
-            from mlcomp_tpu.parallel.sharding import logical_rules
-            from mlcomp_tpu.train.loop import _apply
+            from mlcomp_tpu.train.loop import _apply, _jit_in_mesh
 
-            rules = logical_rules(mesh)
+            def softmax(s, x):
+                logits = _apply(model, s, x, train=False)[0]
+                return jax.nn.softmax(jnp.asarray(logits, jnp.float32))
 
-            @jax.jit
-            def forward(s, x):
-                with mesh, nn.logical_axis_rules(rules):
-                    logits = _apply(model, s, x, train=False)[0]
-                    return jax.nn.softmax(
-                        jnp.asarray(logits, jnp.float32))
-
-            self._eval_forward = forward
+            self._eval_forward = forward = _jit_in_mesh(softmax, mesh)
 
         dp = max(1, data_parallel_size(mesh))
         probs = []

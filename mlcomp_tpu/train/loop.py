@@ -12,7 +12,6 @@ bf16 policy: params/opt-state stay f32, compute dtype comes from the
 model (`dtype='bfloat16'`), loss/metrics reduce in f32 on the MXU.
 """
 
-import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -202,6 +201,52 @@ def _with_sown(loss, metrics, aux, counters):
     return loss, metrics
 
 
+def _jit_in_mesh(step, mesh: Optional[Mesh], donate_argnums=()):
+    """``jax.jit`` of ``step``; given a mesh, traced inside it and its
+    logical axis rules."""
+    if mesh is None:
+        return jax.jit(step, donate_argnums=donate_argnums)
+
+    rules = logical_rules(mesh)
+
+    def step_in_context(*args):
+        with mesh, nn.logical_axis_rules(rules):
+            return step(*args)
+
+    return jax.jit(step_in_context, donate_argnums=donate_argnums)
+
+
+def _update(model, optimizer, loss_fn, state: TrainState, x, target,
+            step_rng):
+    """The update rule of both train steps: the loss of ``x`` against
+    ``target`` and its gradient, the optimizer's update, the new state
+    and the step's metrics."""
+
+    def loss_wrapped(params):
+        logits, new_stats, aux, counters = _apply(
+            model, state.replace(params=params), x, train=True,
+            rng=step_rng)
+        loss, metrics = _with_sown(*loss_fn(logits, target), aux,
+                                   counters)
+        return loss, (metrics, new_stats)
+
+    grads, (metrics, new_stats) = jax.grad(
+        loss_wrapped, has_aux=True)(state.params)
+    updates, new_opt = optimizer.update(
+        grads, state.opt_state, state.params)
+    new_params = optax.apply_updates(state.params, updates)
+    new_state = state.replace(
+        step=state.step + 1, params=new_params, opt_state=new_opt,
+        batch_stats=(new_stats if new_stats is not None
+                     else state.batch_stats))
+    return new_state, metrics
+
+
+def _step_rng(state: TrainState):
+    return (jax.random.fold_in(state.rng, state.step)
+            if state.rng is not None else None)
+
+
 def make_train_step(model, optimizer, loss_fn: Callable,
                     mesh: Optional[Mesh] = None,
                     self_supervised: bool = False):
@@ -212,64 +257,32 @@ def make_train_step(model, optimizer, loss_fn: Callable,
     """
 
     def step(state: TrainState, x, y):
-        step_rng = (jax.random.fold_in(state.rng, state.step)
-                    if state.rng is not None else None)
+        return _update(model, optimizer, loss_fn, state, x,
+                       x if self_supervised else y, _step_rng(state))
 
-        def loss_wrapped(params):
-            logits, new_stats, aux, counters = _apply(
-                model, state.replace(params=params), x, train=True,
-                rng=step_rng)
-            target = x if self_supervised else y
-            loss, metrics = _with_sown(*loss_fn(logits, target), aux,
-                                       counters)
-            return loss, (metrics, new_stats)
-
-        grads, (metrics, new_stats) = jax.grad(
-            loss_wrapped, has_aux=True)(state.params)
-        updates, new_opt = optimizer.update(
-            grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = state.replace(
-            step=state.step + 1, params=new_params, opt_state=new_opt,
-            batch_stats=(new_stats if new_stats is not None
-                         else state.batch_stats))
-        return new_state, metrics
-
-    if mesh is None:
-        return jax.jit(step, donate_argnums=(0,))
-
-    rules = logical_rules(mesh)
-
-    def step_in_context(state, x, y):
-        with mesh, nn.logical_axis_rules(rules):
-            return step(state, x, y)
-
-    return jax.jit(step_in_context, donate_argnums=(0,))
+    return _jit_in_mesh(step, mesh, donate_argnums=(0,))
 
 
 def make_device_train_step(model, optimizer, loss_fn: Callable,
                            mesh: Optional[Mesh] = None,
                            augment=None, dequantize: bool = False,
-                           compute_dtype=None, row_shape=None):
+                           row_shape=None):
     """Device-resident-data variant of make_train_step: the step takes
     the FULL dataset (in HBM, held flat by ``place_dataset``) plus a
     [B] vector of row indices in the set's own order; the gather, the
     reshape of the batch to ``row_shape``, augmentation and
-    dequantization run inside the jit. Host→device traffic per step is
-    the index vector (8 KB at batch 2,048) instead of the batch (6 MB).
-    On the v5e trace (PERF.md, PR 26) the gather of 2,048 CIFAR rows is
-    one fusion of 0.07 ms in a 59.6 ms ResNet-18 step, and the step
-    holds no other operation over the set.
+    dequantization (uint8 to float32 in [0, 1]) run inside the jit,
+    ahead of the update rule the two steps share. Host→device traffic
+    per step is the index vector (8 KB at batch 2,048) instead of the
+    batch (6 MB). On the v5e trace (PERF.md, PR 26) the gather of 2,048
+    CIFAR rows is one fusion of 0.07 ms in a 59.6 ms ResNet-18 step,
+    and the step holds no other operation over the set.
     """
-    import jax.numpy as jnp
 
     def step(state: TrainState, x_all, y_all, idx):
-        step_rng = (jax.random.fold_in(state.rng, state.step)
-                    if state.rng is not None else None)
+        step_rng = _step_rng(state)
         x = gather_rows(x_all, idx, row_shape)
         y = jnp.take(y_all, idx, axis=0) if y_all is not None else None
-        if not dequantize and compute_dtype is not None:
-            x = x.astype(compute_dtype)
         if augment is not None:
             # even without a dropout rng, fold the step counter so the
             # crop/flip pattern varies every step and epoch. Augment
@@ -280,75 +293,10 @@ def make_device_train_step(model, optimizer, loss_fn: Callable,
                 jax.random.fold_in(jax.random.PRNGKey(0), state.step)
             x = augment(x, jax.random.fold_in(base, 1))
         if dequantize:
-            x = x.astype(compute_dtype or jnp.float32) / 255.0
+            x = x.astype(jnp.float32) / 255.0
+        return _update(model, optimizer, loss_fn, state, x, y, step_rng)
 
-        def loss_wrapped(params):
-            logits, new_stats, aux, counters = _apply(
-                model, state.replace(params=params), x, train=True,
-                rng=step_rng)
-            loss, metrics = _with_sown(*loss_fn(logits, y), aux, counters)
-            return loss, (metrics, new_stats)
-
-        grads, (metrics, new_stats) = jax.grad(
-            loss_wrapped, has_aux=True)(state.params)
-        updates, new_opt = optimizer.update(
-            grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = state.replace(
-            step=state.step + 1, params=new_params, opt_state=new_opt,
-            batch_stats=(new_stats if new_stats is not None
-                         else state.batch_stats))
-        return new_state, metrics
-
-    if mesh is None:
-        return jax.jit(step, donate_argnums=(0,))
-
-    rules = logical_rules(mesh)
-
-    def step_in_context(state, x_all, y_all, idx):
-        with mesh, nn.logical_axis_rules(rules):
-            return step(state, x_all, y_all, idx)
-
-    return jax.jit(step_in_context, donate_argnums=(0,))
-
-
-def make_device_epoch_fn(model, optimizer, loss_fn: Callable,
-                         mesh: Optional[Mesh] = None,
-                         augment=None, dequantize: bool = False,
-                         compute_dtype=None, row_shape=None):
-    """One WHOLE training epoch as a single XLA computation:
-    ``lax.scan`` over a [steps, batch] index permutation with the
-    device-resident dataset. One dispatch per epoch removes per-step
-    host dispatch entirely (bench.py times it against the per-step
-    path).
-    Returns ``(state, metrics)`` where each metric is a [steps] array.
-    """
-    import jax.numpy as jnp
-
-    inner = make_device_train_step(
-        model, optimizer, loss_fn, mesh=None, augment=augment,
-        dequantize=dequantize, compute_dtype=compute_dtype,
-        row_shape=row_shape)
-    # unwrap the jit — scan bodies must be plain traceable fns
-    inner = inner.__wrapped__
-
-    def epoch(state: TrainState, x_all, y_all, perm):
-        def body(st, idx):
-            new_st, metrics = inner(st, x_all, y_all, idx)
-            return new_st, metrics
-        state, metrics = jax.lax.scan(body, state, perm)
-        return state, jax.tree.map(jnp.asarray, metrics)
-
-    if mesh is None:
-        return jax.jit(epoch, donate_argnums=(0,))
-
-    rules = logical_rules(mesh)
-
-    def epoch_in_context(state, x_all, y_all, perm):
-        with mesh, nn.logical_axis_rules(rules):
-            return epoch(state, x_all, y_all, perm)
-
-    return jax.jit(epoch_in_context, donate_argnums=(0,))
+    return _jit_in_mesh(step, mesh, donate_argnums=(0,))
 
 
 def make_device_eval_step(model, loss_fn: Callable,
@@ -357,7 +305,6 @@ def make_device_eval_step(model, loss_fn: Callable,
     """Eval against the device-resident dataset: ships a [B] index
     vector + [B] weight vector per batch instead of the batch itself
     (the weights zero out tail padding so aggregates stay exact)."""
-    import jax.numpy as jnp
 
     def step(state: TrainState, x_all, y_all, idx, w):
         x = gather_rows(x_all, idx, row_shape)
@@ -368,16 +315,7 @@ def make_device_eval_step(model, loss_fn: Callable,
         _, metrics = loss_fn(logits, y, weights=w)
         return metrics
 
-    if mesh is None:
-        return jax.jit(step)
-
-    rules = logical_rules(mesh)
-
-    def step_in_context(state, x_all, y_all, idx, w):
-        with mesh, nn.logical_axis_rules(rules):
-            return step(state, x_all, y_all, idx, w)
-
-    return jax.jit(step_in_context)
+    return _jit_in_mesh(step, mesh)
 
 
 def make_eval_step(model, loss_fn: Callable,
@@ -389,23 +327,15 @@ def make_eval_step(model, loss_fn: Callable,
         _, metrics = loss_fn(logits, target, weights=w)
         return metrics
 
-    if mesh is None:
-        return jax.jit(step)
-
-    rules = logical_rules(mesh)
-
-    def step_in_context(state, x, y, w=None):
-        with mesh, nn.logical_axis_rules(rules):
-            return step(state, x, y, w)
-
-    return jax.jit(step_in_context)
+    return _jit_in_mesh(step, mesh)
 
 
 def instrumented_step(step_fn, recorder, batch_size: int = None,
                       metric_keys=('loss',), attribution=None,
                       tripwire=None, compile_events=None,
                       memory=None, deviceprof=None):
-    """Wrap a jit'd train step with per-step telemetry recording
+    """Wrap a jit'd train step of either maker, called as
+    ``(state, *feed)``, with per-step telemetry recording
     (telemetry/metrics.py). Hot-path cost per step: a perf_counter
     read and 2-3 list appends — the device arrays in ``metrics`` are
     buffered as-is, NOT converted (no device sync; the recorder pulls
@@ -567,7 +497,7 @@ def place_state(state: TrainState, mesh: Mesh) -> TrainState:
 
 
 __all__ = ['TrainState', 'make_train_step', 'make_device_train_step',
-           'make_device_epoch_fn', 'make_eval_step',
+           'make_eval_step',
            'make_device_eval_step', 'aggregate_metrics',
            'instrumented_step',
            'create_train_state', 'state_sharding', 'place_state',

@@ -114,38 +114,6 @@ class TestIndexedSteps:
         assert float(m_host['loss']) == pytest.approx(
             float(m_dev['loss']), rel=1e-5)
 
-    def test_epoch_scan_matches_stepwise(self):
-        """lax.scan epoch == the same steps issued one by one."""
-        import jax
-        from mlcomp_tpu.parallel import mesh_from_spec
-        from mlcomp_tpu.parallel.sharding import batch_sharding
-        from mlcomp_tpu.train.device_data import place_dataset
-        from mlcomp_tpu.train.loop import (
-            make_device_epoch_fn, make_device_train_step,
-        )
-
-        mesh = mesh_from_spec({'dp': -1})
-        model, opt, x, y, state, loss_fn = self._setup(mesh)
-        state2 = _clone(state)
-        x_all, y_all = place_dataset(x, y, mesh)
-        perm = np.arange(64, dtype=np.int32).reshape(4, 16)
-
-        dev_step = make_device_train_step(model, opt, loss_fn, mesh=mesh)
-        step_losses = []
-        st = state
-        for s in range(4):
-            st, m = dev_step(st, x_all, y_all,
-                             jax.device_put(perm[s],
-                                            batch_sharding(mesh, 1)))
-            step_losses.append(float(m['loss']))
-
-        epoch_fn = make_device_epoch_fn(model, opt, loss_fn, mesh=mesh)
-        perm_dev = jax.device_put(
-            perm, batch_sharding(mesh, 2, batch_dim=1))
-        _, metrics = epoch_fn(state2, x_all, y_all, perm_dev)
-        np.testing.assert_allclose(
-            np.asarray(metrics['loss']), step_losses, rtol=1e-5)
-
     def test_dequantize_matches_float(self):
         import jax
         from mlcomp_tpu.parallel import mesh_from_spec
@@ -235,17 +203,10 @@ class TestFlatResidentSet:
         import jax
         from mlcomp_tpu.parallel.sharding import batch_sharding
         from mlcomp_tpu.train.loop import (
-            make_device_epoch_fn, make_device_eval_step,
-            make_device_train_step,
+            make_device_eval_step, make_device_train_step,
         )
         model, opt, state = _echo_setup(mesh)
         sh1 = batch_sharding(mesh, 1)
-        if kind == 'epoch_scan':
-            fn = make_device_epoch_fn(model, opt, _echo_loss, mesh=mesh,
-                                      **kw)
-            _, m = fn(state, x_all, y_all, jax.device_put(
-                perm, batch_sharding(mesh, 2, batch_dim=1)))
-            return np.asarray(m['batch']), np.asarray(m['labels'])
         if kind == 'eval':
             kw.pop('augment', None)
             fn = make_device_eval_step(model, _echo_loss, mesh=mesh,
@@ -265,7 +226,7 @@ class TestFlatResidentSet:
                 np.stack([np.asarray(m['labels']) for m in out]))
 
     @pytest.mark.parametrize('devices', [1, 8])
-    @pytest.mark.parametrize('kind', ['train', 'epoch_scan', 'eval'])
+    @pytest.mark.parametrize('kind', ['train', 'eval'])
     @pytest.mark.parametrize('case', [
         'uint8_images', 'float_images_outside_01', 'rank1_rows'])
     def test_batch_is_the_original_rows(self, case, kind, devices):
@@ -390,23 +351,85 @@ class TestExecutorSelection:
         result = ex.work()
         assert result['best_score'] is not None
 
-    def test_epoch_scan_option(self, tmp_path):
+    @pytest.mark.parametrize('option, value', [
+        ('epoch_scan', True), ('log_every', 10)])
+    def test_a_removed_option_is_an_unknown_key(self, option, value,
+                                                tmp_path, monkeypatch):
+        """The whole-epoch-scan switch and ``log_every`` are gone: a
+        config that still sets one is told so, loudly, and trains on
+        the per-step path like any other."""
         from test_train import DummyStep
+        import mlcomp_tpu.train.loop as loop
         from mlcomp_tpu.train import JaxTrain
+
+        dispatches = []
+        make = loop.make_device_train_step
+
+        def counted(*args, **kwargs):
+            step = make(*args, **kwargs)
+
+            def call(state, *feed):
+                dispatches.append(len(feed))
+                return step(state, *feed)
+            return call
+
+        monkeypatch.setattr(loop, 'make_device_train_step', counted)
         ex = JaxTrain(
             model={'name': 'mlp', 'num_classes': 4, 'hidden': [16],
                    'dtype': 'float32'},
             dataset={'name': 'synthetic_images', 'n_train': 128,
                      'n_valid': 32, 'image_size': 8, 'channels': 1,
                      'num_classes': 4},
-            batch_size=32, epochs=2, epoch_scan=True,
-            checkpoint_dir=str(tmp_path / 'ck'))
+            batch_size=32, epochs=2, device_data=True,
+            checkpoint_dir=str(tmp_path / 'ck'), **{option: value})
+        assert not hasattr(ex, option)
         ex.step = DummyStep()
+        said = []
+        ex.step.info = said.append
         ex.task = None
         ex.session = None
         ex.additional_info = {}
         result = ex.work()
         assert result['best_score'] is not None
+        warned = [m for m in said if m.startswith('WARNING: config keys')]
+        assert len(warned) == 1 and repr([option]) in warned[0]
+        # a dispatch a step, each fed (x_all, y_all, idx)
+        assert dispatches == [3] * (2 * 128 // 32)
+
+
+    @pytest.mark.parametrize('device_data', [True, False])
+    def test_the_dropped_tail_is_said_once_a_dispatch(self, device_data,
+                                                      tmp_path):
+        """Both input paths go through one epoch loop: the samples that
+        fill no batch are named in the first epoch a dispatch trains,
+        fresh or resumed, and in no later one."""
+        from test_train import DummyStep
+        from mlcomp_tpu.train import JaxTrain
+
+        def dispatch(epochs):
+            ex = JaxTrain(
+                model={'name': 'mlp', 'num_classes': 4, 'hidden': [16],
+                       'dtype': 'float32'},
+                dataset={'name': 'synthetic_images', 'n_train': 100,
+                         'n_valid': 32, 'image_size': 8, 'channels': 1,
+                         'num_classes': 4},
+                batch_size=32, epochs=epochs, device_data=device_data,
+                checkpoint_dir=str(tmp_path / 'ck'))
+            ex.step = DummyStep()
+            said = []
+            ex.step.info = said.append
+            ex.task = None
+            ex.session = None
+            ex.additional_info = {}
+            ex.work()
+            return said
+
+        said = dispatch(2)
+        assert len([m for m in said if 'dropping 4 tail' in m]) == 1
+        said = dispatch(4)      # resumes at epoch 2, trains 2 more
+        assert any(m.startswith('resumed from checkpoint') for m in said)
+        assert len([m for m in said if 'dropping 4 tail' in m]) == 1
+        assert len([m for m in said if '] epoch ' in m]) == 2
 
 
 class TestDataHelpers:

@@ -136,7 +136,6 @@ def tiny_job(session, ck_dir, epochs=3, **kwargs):
 INPUT_PATHS = {
     'device_data': dict(device_data=True,
                         telemetry={'cost_analysis': True}),
-    'epoch_scan': dict(device_data=True, epoch_scan=True),
     'host_prefetch': dict(device_data=False,
                           telemetry={'cost_analysis': True}),
     'device_data_no_checkpoint': dict(device_data=True,
@@ -156,8 +155,7 @@ def test_epoch_and_setup_spans_of_a_job(path, session, tmp_path):
     setup = [r for r in rows if r.name.startswith('train.setup.')]
     want = ['train.setup.data', 'train.setup.state']
     if 'cost_analysis' in (INPUT_PATHS[path].get('telemetry') or {}):
-        # the AOT compile of the step, once a job (the scan path has
-        # no per-step program to introspect)
+        # the AOT compile of the step, once a job
         want.append('train.setup.introspect')
     assert [r.name for r in setup] == want
     assert all(r.parent_id == work.span_id for r in setup)
@@ -184,25 +182,100 @@ def test_epoch_and_setup_spans_of_a_job(path, session, tmp_path):
     # every span of the job is closed and parents inside the job
     ids = {r.span_id for r in rows}
     assert all(r.parent_id in ids for r in rows if r is not work)
-    assert ex._open_spans == []
+    assert spans.current_span_id() is None
 
 
-def test_an_exception_closes_the_open_phases_as_errors(session, tmp_path):
-    ex, task = tiny_job(session, tmp_path / 'ck', epochs=2,
-                        device_data=True)
+def test_two_stages_tag_their_epochs_and_compile_the_step_once(
+        session, tmp_path):
+    """The stage loop around the phases: an epoch's span names its
+    stage and counts epochs across stages, and the introspection
+    compile is the first stage's alone."""
+    stages = [{'name': 'warm', 'epochs': 1,
+               'optimizer': {'name': 'sgd', 'lr': 0.1}},
+              {'name': 'main', 'epochs': 2,
+               'optimizer': {'name': 'adam', 'lr': 1e-3}}]
+    ex, task = tiny_job(session, tmp_path / 'ck', stages=stages,
+                        device_data=True,
+                        telemetry={'cost_analysis': True})
+    result = ex.work()
+    assert result['stage'] == 'main'
+    rows = sorted(TelemetrySpanProvider(session).by_task(task.id),
+                  key=lambda r: (r.started, r.id))
+    assert [json.loads(r.tags) for r in rows
+            if r.name == 'train.epoch'] == [
+        {'epoch': 0, 'stage': 'warm'}, {'epoch': 1, 'stage': 'main'},
+        {'epoch': 2, 'stage': 'main'}]
+    assert [r.name for r in rows if r.name.startswith('train.setup.')] \
+        == ['train.setup.data', 'train.setup.state',
+            'train.setup.introspect']
+    assert all(r.status == 'ok' for r in rows)
 
-    # the step rows of epoch 1 fail: inside train.epoch.begin
+
+def _planted(*args, **kwargs):
+    raise RuntimeError('planted')
+
+
+def _plant_in_begin(ex, monkeypatch):
+    # the step rows of epoch 1 fail
     def start(level, name, index):
         if name == 'epoch 1':
             raise RuntimeError('planted')
 
     ex.step.start = start
+
+
+def _plant_in_report(ex, monkeypatch):
+    ex._report_series = _planted
+
+
+#: phase -> how a fault is planted in it: (module, attribute) to replace
+#: with a call that raises (or that builds a step that raises), or a
+#: function that plants it
+FAULTS = {
+    'train.setup.data': ('mlcomp_tpu.train.executor', 'create_dataset'),
+    'train.setup.state': ('mlcomp_tpu.train.executor',
+                          'create_train_state'),
+    'train.setup.introspect': ('mlcomp_tpu.telemetry',
+                               'memory_attribution'),
+    'train.epoch.begin': _plant_in_begin,
+    'train.epoch.steps': ('mlcomp_tpu.train.loop',
+                          'make_device_train_step', 'built'),
+    'train.epoch.drain': ('mlcomp_tpu.train.executor',
+                          'aggregate_metrics'),
+    'train.epoch.valid': ('mlcomp_tpu.train.loop',
+                          'make_device_eval_step', 'built'),
+    'train.epoch.report': _plant_in_report,
+    'train.epoch.checkpoint': ('mlcomp_tpu.train.ckpt_shard',
+                               'state_needs_sharded_ckpt'),
+}
+
+
+@pytest.mark.parametrize('phase', sorted(FAULTS))
+def test_an_exception_closes_the_open_phases_as_errors(
+        phase, session, tmp_path, monkeypatch):
+    import importlib
+    ex, task = tiny_job(session, tmp_path / 'ck', epochs=2,
+                        device_data=True,
+                        telemetry={'memory_analysis': True})
+    fault = FAULTS[phase]
+    if callable(fault):
+        fault(ex, monkeypatch)
+    else:
+        module, name, *built = fault
+        monkeypatch.setattr(
+            importlib.import_module(module), name,
+            (lambda *args, **kwargs: _planted) if built else _planted)
     with pytest.raises(RuntimeError, match='planted'):
         ex.work()
     rows = TelemetrySpanProvider(session).by_task(task.id)
-    failed = {r.name for r in rows if r.status == 'error'}
-    assert failed == {'train.work', 'train.epoch', 'train.epoch.begin'}
-    assert ex._open_spans == []
+    # exactly that phase and its parents, and nothing is left open
+    parents = {'train.work'} | (
+        {'train.epoch'} if phase.startswith('train.epoch.') else set())
+    assert {r.name for r in rows
+            if r.status == 'error'} == parents | {phase}
+    assert len([r for r in rows if r.status == 'error']) \
+        == len(parents) + 1
+    assert all(r.duration is not None for r in rows)
     assert spans.current_span_id() is None
 
 

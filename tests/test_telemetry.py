@@ -143,15 +143,6 @@ class TestMetrics:
         assert s['mean'] == pytest.approx(49.5)
         assert s['p99'] >= 95
 
-    def test_series_array_bulk(self, session):
-        task = make_task(session)
-        rec = MetricRecorder(session=session, task=task.id,
-                             flush_every=10 ** 9)
-        rec.series_array('loss', np.linspace(1, 0, 5), start_step=10)
-        rec.flush()
-        points = MetricProvider(session).series(task_id=task.id)['loss']
-        assert [p['step'] for p in points] == [10, 11, 12, 13, 14]
-
 
 @pytest.fixture()
 def api(session):
