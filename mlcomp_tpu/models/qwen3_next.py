@@ -35,12 +35,14 @@ Counters (sown under ``intermediates``, carried out of the step by
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from flax.core import meta as flax_meta
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from mlcomp_tpu.models.base import register_model
@@ -204,8 +206,9 @@ class GatedDeltaNet(nn.Module):
         dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
         b, t, _ = x.shape
         key_w, val_w = hk * dk, hv * dv
-        qkvz = _dense(2 * key_w + 2 * val_w, ('embed', 'mlp'), dtype,
-                      'in_proj_qkvz')(x)
+        qkvz = checkpoint_name(
+            _dense(2 * key_w + 2 * val_w, ('embed', 'mlp'), dtype,
+                   'in_proj_qkvz')(x), 'linear_attn.qkvz')
         ba = _dense(2 * hv, ('embed', 'heads'), dtype, 'in_proj_ba')(x)
         conv = self.param(
             'conv', nn.with_logical_partitioning(
@@ -267,6 +270,32 @@ def grouped_matmul(lhs, rhs, group_sizes, impl: str):
                     tile(rhs.shape[2])), interpret=impl == 'interpret')
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def routing_top_k(probs, k: int):
+    """``lax.top_k`` over the experts of probs [N,E], its results named
+    ``moe.routing`` and its gradient a scatter to the NAMED indices.
+    ``lax.top_k``'s own gradient rule reads its un-named index output,
+    for which a save-by-name `remat` sorts a second time; the numbers
+    and the gradient are ``lax.top_k``'s."""
+    return _routing_top_k_fwd(probs, k)[0]
+
+
+def _routing_top_k_fwd(probs, k):
+    top_w, top_i = (checkpoint_name(x, 'moe.routing')
+                    for x in jax.lax.top_k(probs, k))
+    # `probs` (held under its own name) gives the gradient its shape
+    return (top_w, top_i), (top_i, probs)
+
+
+def _routing_top_k_bwd(k, residuals, cotangents):
+    top_i, probs = residuals
+    rows = jnp.arange(top_i.shape[0])[:, None]
+    return (jnp.zeros_like(probs).at[rows, top_i].add(cotangents[0]),)
+
+
+routing_top_k.defvjp(_routing_top_k_fwd, _routing_top_k_bwd)
+
+
 def buffer_rows(cfg: Qwen3NextConfig, tokens: int) -> int:
     """Rows of the sorted (token, expert) buffer: the worst case, or
     ``moe_buffer_factor`` times the even share; whole row tiles of the
@@ -314,10 +343,10 @@ class SparseMoe(nn.Module):
             n = b * t
             flat = x.reshape(n, m)
             # the router over ALL experts, in float32
-            probs = jax.nn.softmax(jnp.dot(
+            probs = checkpoint_name(jax.nn.softmax(jnp.dot(
                 flat.astype(f32), router,
-                precision=jax.lax.Precision.HIGHEST), -1)
-            top_w, top_i = jax.lax.top_k(probs, cfg.top_k)
+                precision=jax.lax.Precision.HIGHEST), -1), 'moe.probs')
+            top_w, top_i = routing_top_k(probs, cfg.top_k)
             if cfg.norm_topk_prob:
                 top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
             # pairs that land on a held expert, sorted by expert; the
@@ -326,9 +355,11 @@ class SparseMoe(nn.Module):
             local = jnp.where((local >= 0) & (local < held), local,
                               held).reshape(-1)
             rows = buffer_rows(cfg, n)
-            order = jnp.argsort(local, stable=True)[:rows]
+            order = checkpoint_name(
+                jnp.argsort(local, stable=True)[:rows], 'moe.routing')
             token = order // cfg.top_k
-            sizes = jnp.bincount(local, length=held + 1)[:held]
+            sizes = checkpoint_name(
+                jnp.bincount(local, length=held + 1)[:held], 'moe.routing')
             landed = jnp.sum(sizes)
             # groups cut to the buffer (nothing is cut at the default)
             ends = jnp.minimum(jnp.cumsum(sizes), rows)
@@ -383,6 +414,36 @@ class Qwen3NextLayer(nn.Module):
         return nn.with_logical_constraint(x, ('batch', 'seq', 'embed'))
 
 
+# What a `remat`ted layer holds for its backward pass besides its
+# input: the values that are dear to make again and cheap to hold
+# (docs/qwen3_next.md, "What remat holds"). The names are given where
+# the values are made: in the forward rules of ``ops/gated_delta.py``
+# and ``ops/flash_attention.py``, in ``SparseMoe.routed`` and in
+# ``GatedDeltaNet``. Everything else of a layer is computed again.
+REMAT_SAVED = (
+    # the delta op: without these the backward runs `gated_delta_prepare`
+    # and `gated_delta_fwd` once more
+    'gated_delta.out', 'gated_delta.states', 'gated_delta.inputs',
+    # the flash forward kernel
+    'flash_attn.out', 'flash_attn.lse', 'flash_attn.qkv',
+    # the router's float32 product at Precision.HIGHEST and softmax;
+    # top-k (weights, indices), argsort (order), bincount (sizes)
+    'moe.probs', 'moe.routing',
+    # the widest projection of the stack, [tokens, 12288]
+    'linear_attn.qkvz',
+)
+
+
+def _layer_class(cfg, **remat_kwargs):
+    """``Qwen3NextLayer``, under ``cfg.remat`` with the policy that
+    holds ``REMAT_SAVED``."""
+    if not cfg.remat:
+        return Qwen3NextLayer
+    return nn.remat(
+        Qwen3NextLayer, policy=jax.checkpoint_policies
+        .save_only_these_names(*REMAT_SAVED), **remat_kwargs)
+
+
 class Qwen3NextPeriod(nn.Module):
     """One period of the layer pattern: ``interval - 1`` linear layers
     and a full one; the body of the scan over periods."""
@@ -392,8 +453,7 @@ class Qwen3NextPeriod(nn.Module):
     @nn.compact
     def __call__(self, x, _=None):
         cfg = self.cfg
-        layer = nn.remat(Qwen3NextLayer, prevent_cse=False) \
-            if cfg.remat else Qwen3NextLayer
+        layer = _layer_class(cfg, prevent_cse=False)    # inside a scan
         for i in range(cfg.full_attention_interval):
             full = i == cfg.full_attention_interval - 1
             x = layer(cfg, full, self.mesh, name=f'layer_{i}')(x)
@@ -429,7 +489,7 @@ class Qwen3NextLM(nn.Module):
                 metadata_params={flax_meta.PARTITION_NAME: 'layers'})
             x, _ = scanned(cfg, self.mesh, name='periods')(x, None)
             first = periods * interval
-        layer = nn.remat(Qwen3NextLayer) if cfg.remat else Qwen3NextLayer
+        layer = _layer_class(cfg)
         for i in range(first, cfg.n_layers):
             # preflight: disable=jax-layer-loop
             full = (i + 1) % interval == 0

@@ -50,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -555,7 +556,11 @@ def _fa_fwd(q, k, v, causal, scale, interpret):
     out, lse = flash_attention_forward(q, k, v, causal=causal,
                                        scale=scale, interpret=interpret,
                                        with_lse=True)
-    return out, (q, k, v, out, lse)
+    # named for a caller's save-by-name ``remat`` policy; without one a
+    # name is an identity that lowers to nothing
+    q, k, v = (checkpoint_name(x, 'flash_attn.qkv') for x in (q, k, v))
+    out = checkpoint_name(out, 'flash_attn.out')
+    return out, (q, k, v, out, checkpoint_name(lse, 'flash_attn.lse'))
 
 
 def _fa_bwd(causal, scale, interpret, residuals, g):

@@ -50,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -636,14 +637,20 @@ def _delta_fwd_rule(q, k, v, g, beta, static):
         operands = _prepare_pallas(*block, chunk, group, interpret)
         return _scan_pallas_fwd(*operands, group, interpret)
 
-    inputs = (q, k, v, g, beta)
+    # named, so that a caller's ``remat`` can hold them by a
+    # save-by-name policy and not run the forward kernels again
+    # (without such a policy a name is an identity that lowers to
+    # nothing)
+    inputs = tuple(checkpoint_name(x, 'gated_delta.inputs')
+                   for x in (q, k, v, g, beta))
     out, states = lax.map(
         one, tuple(_head_blocks(x, heads) for x in inputs))
     # [H/heads, B*heads, N, C, dv] -> [B,T,H,dv]
     out = out.reshape(h // heads, b, heads, t, v.shape[-1])
     out = jnp.transpose(out, (1, 3, 0, 2, 4)).reshape(
         b, t, h, v.shape[-1])
-    return out, (inputs, states)
+    return (checkpoint_name(out, 'gated_delta.out'),
+            (inputs, checkpoint_name(states, 'gated_delta.states')))
 
 
 def _delta_bwd_rule(static, residuals, do):

@@ -739,6 +739,12 @@ class JaxTrain(Executor):
                 self.info(f'telemetry: step introspection skipped '
                           f'({e})')
                 return
+            # how many Pallas kernels the step runs: says whether a
+            # save-by-name `remat` policy (models/qwen3_next.py) took
+            # the forward kernels out of the backward pass
+            text = compiled.as_text()
+            self._telemetry.gauge('step.kernel_calls',
+                                  text.count('tpu_custom_call'))
             if wants['cost_analysis']:
                 try:
                     cost = compiled.cost_analysis()
@@ -776,7 +782,7 @@ class JaxTrain(Executor):
                     persist_collective_stats,
                 )
                 try:
-                    stats = collective_stats(compiled)
+                    stats = collective_stats(text)
                 except Exception:
                     stats = None
                 if stats is not None:

@@ -181,3 +181,66 @@ def test_expert_grouped_matmul(one_chip):
                     ((32, 2048, 512), jnp.bfloat16),
                     ((32, 512, 2048), jnp.bfloat16),
                     ((32,), jnp.int32)) >= 8
+
+
+@pytest.mark.parametrize('full', [False, True], ids=['linear', 'full'])
+def test_qwen3_next_remat_holds_the_kernels_results(one_chip, full):
+    """``jax.grad`` of one `remat`ted layer of ``qwen3-next-80b-a3b.
+    steady`` at its widths and 2 x 8,192 tokens: with the save-by-name
+    policy (``REMAT_SAVED``) the backward pass runs no forward kernel
+    again — the delta op's operands are made once more
+    (``gated_delta_prepare``, the backward rule's own), its scan and
+    the flash forward are not."""
+    import collections
+    import json
+    import re
+
+    import flax
+    from mlcomp_tpu.models import create_model, qwen3_next
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, 'benchmark/configs/qwen3-next-80b-a3b.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = create_model(**dict(
+        kwargs, attn_impl='pallas', delta_impl='pallas',
+        moe_impl='gmm')).cfg
+    assert cfg.remat
+    layer = qwen3_next._layer_class(cfg)(cfg, full)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x)['params']))
+
+    def loss(p, x):
+        y = layer.apply({'params': p}, x, mutable=['intermediates'])[0]
+        return y.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = collections.Counter(
+        re.search(r'gated_delta_\w+|gqa_attn|tgmm|gmm|$', re.search(
+            r'op_name="([^"]*)"', line).group(1)).group(0)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    if full:
+        # forward, dq, dk/dv
+        assert kernels['gqa_attn'] == 3
+    else:
+        assert kernels['gated_delta_fwd'] == 1
+        assert kernels['gated_delta_prepare'] == 2
+        assert kernels['gated_delta_bwd_scan'] == 1
+        assert kernels['gated_delta_prepare_bwd'] == 1
+    # the grouped products are not held: three made again and three
+    # for the rows' gradients (the forward's own are dead code here: a
+    # sum's gradient does not read the layer's output), three for the
+    # weights' gradients
+    assert kernels['gmm'] == 6 and kernels['tgmm'] == 3, kernels
+    assert '' not in kernels        # no kernel but these
+    # a layer routes once: `lax.top_k`'s sort of the probabilities and
+    # the argsort of the (token, expert) pairs, by the ops' own names
+    sorts = [line for line in text.splitlines() if ' sort(' in line]
+    assert sum('/moe/top_k"' in line for line in sorts) == 1
+    assert sum('/moe/jit(argsort)/sort"' in line for line in sorts) == 1

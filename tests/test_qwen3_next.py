@@ -4,10 +4,14 @@ weights, small sizes: the model against the plain reference
 periods; the chunked gated delta rule against the token recurrence; the
 grouped-query flash kernels (interpret mode) against dense attention;
 partial rotary against a hand-written rotation; the expert layer with
-every token on ONE held expert; and the share test — the parts all the
-shares give add up to the uncut layer."""
+every token on ONE held expert; the share test — the parts all the
+shares give add up to the uncut layer; and what a `remat`ted layer
+holds by name (``REMAT_SAVED``): the plain gradients, every name read
+by the backward pass, no kernel of the forward pass run again."""
 
+import collections
 import dataclasses
+import functools
 import os
 import sys
 
@@ -23,7 +27,7 @@ if ROOT not in sys.path:
 
 from benchmark import weights  # noqa: E402
 from benchmark.reference import qwen3_next as ref  # noqa: E402
-from mlcomp_tpu.models import create_model  # noqa: E402
+from mlcomp_tpu.models import create_model, qwen3_next  # noqa: E402
 from mlcomp_tpu.models.qwen3_next import (  # noqa: E402
     Qwen3NextConfig, SparseMoe, rotary,
 )
@@ -54,12 +58,12 @@ def rel(a, b):
     return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
 
 
-def seeded(model_kwargs, seed=7, gain=8.0):
+def seeded(model_kwargs, seed=7, gain=8.0, seq=32):
     """(module, its parameter tree and the reference's dict) with the
     benchmark's seeded weights, the kernels scaled up so that gates,
     decays and the router are far from their flat middle."""
     model = create_model('qwen3_next', **model_kwargs)
-    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, seq), 0,
                                 model_kwargs['vocab_size'])
     tree = meta.unbox(jax.eval_shape(
         model.init, jax.random.PRNGKey(1), tokens)['params'])
@@ -76,6 +80,7 @@ def seeded(model_kwargs, seed=7, gain=8.0):
     ('linear_layer', dict(n_layers=1)),
     ('full_layer', dict(n_layers=1, full_attention_interval=1)),
     ('one_period', dict()),
+    ('one_period_remat', dict(remat=True)),
     ('two_periods_scanned', dict(n_layers=8, remat=True)),
 ])
 def test_model_against_reference(case, over):
@@ -476,3 +481,165 @@ def test_data_parallel_devices_see_their_own_rows():
     with pytest.raises(NotImplementedError, match='ep=2'):
         create_model('qwen3_next', mesh=ep, **kwargs).apply(
             {'params': params}, tokens)
+
+
+# ------------------------------------------- what a `remat`ted layer holds
+#: both mixers' kernels under the interpreter, 128 tokens (a flash
+#: tile): the flash kernels are the program's only unnamed ones
+KERNELS = dict(SMALL, attn_impl='interpret', delta_impl='interpret',
+               moe_impl='ragged')
+
+
+def kernel_program(**over):
+    """(loss of the parameters, parameters) of the small model on the
+    kernels' path."""
+    model, params, _, tokens = seeded(dict(KERNELS, **over), seq=128)
+
+    def program(p):
+        logits = model.apply({'params': p}, tokens).astype(jnp.float32)
+        return jnp.mean(jax.nn.logsumexp(logits, -1) - logits[..., 0])
+
+    return program, params
+
+
+@pytest.mark.parametrize('layers', [2, 4], ids=['unscanned', 'scanned'])
+def test_remat_with_the_policy_gives_the_plain_gradients(layers):
+    """Both `nn.remat` sites (periods of a linear and a full layer: one
+    is looped, two are scanned): the loss and every gradient leaf with
+    the save-by-name policy are those of the model without `remat`."""
+    over = dict(n_layers=layers, full_attention_interval=2)
+    plain, params = kernel_program(**over)
+    loss, grads = jax.jit(jax.value_and_grad(
+        kernel_program(remat=True, **over)[0]))(params)
+    want_loss, want_grads = jax.jit(jax.value_and_grad(plain))(params)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    for (path, a), b in zip(weights.flat_paths(grads),
+                            jax.tree.leaves(want_grads)):
+        assert float(jnp.linalg.norm(b)) > 0, path
+        assert rel(a, b) < 1e-5, path
+
+
+def test_the_routers_top_k_is_lax_top_k():
+    """``routing_top_k`` reads its gradient at the named indices: the
+    numbers and the gradient are ``lax.top_k``'s, to the bit."""
+    probs = jax.nn.softmax(
+        4 * jax.random.normal(jax.random.PRNGKey(5), (48, 16)), -1)
+    weigh = jax.random.normal(jax.random.PRNGKey(6), (48, 3))
+
+    def loss(top_k):
+        def fn(p):
+            top_w, top_i = top_k(p, 3)
+            return jnp.sum(weigh * top_w / jnp.sum(top_w, -1, keepdims=True)
+                           * (1 + top_i))
+        return fn
+
+    for got, want in zip(qwen3_next.routing_top_k(probs, 3),
+                         jax.lax.top_k(probs, 3)):
+        assert (got == want).all()
+    got = jax.grad(loss(qwen3_next.routing_top_k))(probs)
+    want = jax.grad(loss(jax.lax.top_k))(probs)
+    assert float(jnp.abs(want).max()) > 0 and (got == want).all()
+
+
+@functools.lru_cache(maxsize=None)
+def backward_census(saved):
+    """Equations of the gradient program of one linear and one full
+    layer under `remat` holding the names ``saved``: a count by
+    primitive, kernels by their name (the flash kernels have none)."""
+    census = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == 'pallas_call':
+                name = eqn.params['name'] or 'flash'
+            if name == 'name':
+                census['name:' + eqn.params['name']] += 1
+            census[name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(qwen3_next, 'REMAT_SAVED', saved)
+        program, params = kernel_program(
+            n_layers=2, full_attention_interval=2, remat=True)
+        walk(jax.make_jaxpr(jax.grad(program))(params).jaxpr)
+    return census
+
+
+def test_the_backward_pass_runs_no_forward_kernel_again():
+    plain = backward_census(())
+    held = backward_census(qwen3_next.REMAT_SAVED)
+    # the forward pass, `remat`'s forward and the backward's own
+    assert plain['gated_delta_prepare'] == 3
+    assert plain['gated_delta_fwd'] == 2
+    # forward and the backward's own; the scan forward once
+    assert held['gated_delta_prepare'] == 2
+    assert held['gated_delta_fwd'] == 1
+    for census in plain, held:
+        assert census['gated_delta_bwd_scan'] == 1
+        assert census['gated_delta_prepare_bwd'] == 1
+    # the three flash kernels once each, not the forward twice
+    assert plain['flash'] == 4 and held['flash'] == 3
+    # a layer routes once: top-k, the argsort of the pairs
+    assert plain['top_k'] == 4 and held['top_k'] == 2
+    assert plain['sort'] == 4 and held['sort'] == 2
+
+
+@pytest.mark.parametrize('name', qwen3_next.REMAT_SAVED)
+def test_every_saved_name_is_given_and_read(name):
+    """A name nothing gives, or that the backward pass does not read,
+    is a silent no-op: each name of the list is on a value of the
+    forward pass, and without it the gradient program computes more."""
+    saved = qwen3_next.REMAT_SAVED
+    held = backward_census(saved)
+    assert held['name:' + name] > 0
+    without = backward_census(tuple(n for n in saved if n != name))
+    # a held value costs an equation of its own: the name, and the
+    # `reduce_precision` that keeps XLA from merging it away
+    work = lambda census: sum(  # noqa: E731
+        n for key, n in census.items()
+        if not key.startswith('name') and key != 'reduce_precision')
+    assert work(without) > work(held)
+
+
+@pytest.mark.parametrize('remat', [False, True], ids=['plain', 'remat'])
+def test_the_names_leave_transformer_lm_as_it_was(remat, monkeypatch):
+    """``_fa_fwd`` is ``transformer_lm``'s forward rule too, whose
+    `remat` has no policy: its lowered train step with the names is,
+    letter for letter, the one without them — but for the number the
+    module's symbol table appends to a private function's name
+    (``@_take_128`` / ``@_take_126``: a counter of the lowering, which
+    two identities more advance; the compiled program has no such
+    functions)."""
+    import re
+    from mlcomp_tpu.ops import flash_attention as fa
+    from mlcomp_tpu.train.loop import (
+        create_train_state, loss_for_task, make_train_step)
+    from mlcomp_tpu.train.optim import make_optimizer
+    tokens = jnp.zeros((2, 128), jnp.int32)
+
+    def lowered():
+        model = create_model(
+            'transformer_lm', vocab_size=64, d_model=32, n_layers=2,
+            n_heads=2, d_ff=64, max_seq_len=128, dtype='float32',
+            attn_impl='interpret', remat=remat)
+        optimizer = make_optimizer({'name': 'adamw', 'lr': 1e-3})[0]
+        state = jax.eval_shape(lambda: create_train_state(
+            model, optimizer, tokens, jax.random.PRNGKey(1)))
+        step = make_train_step(model, optimizer, loss_for_task('lm_ce'),
+                               self_supervised=True)
+        return re.sub(r'@(\w+?)_\d+\b', r'@\1',
+                      step.lower(state, tokens, None).as_text())
+
+    named, seen = lowered(), []
+
+    def unnamed(x, name):
+        seen.append(name)
+        return x
+
+    monkeypatch.setattr(fa, 'checkpoint_name', unnamed)
+    assert lowered() == named
+    assert {'flash_attn.qkv', 'flash_attn.out', 'flash_attn.lse'} \
+        == set(seen)
+    assert 'tpu_custom_call' not in named and 'while' in named

@@ -211,6 +211,30 @@ def test_two_stages_tag_their_epochs_and_compile_the_step_once(
     assert all(r.status == 'ok' for r in rows)
 
 
+def test_the_introspection_counts_the_steps_kernels(session, tmp_path,
+                                                    monkeypatch):
+    """``step.kernel_calls``: one gauge a job, the `tpu_custom_call`s
+    of the compiled train step (none on the CPU; with two named in the
+    text, two)."""
+    from jax import stages
+    from mlcomp_tpu.db.providers.telemetry import MetricProvider
+
+    def job(name):
+        ex, task = tiny_job(session, tmp_path / name, epochs=1,
+                            device_data=True,
+                            telemetry={'cost_analysis': True})
+        ex.work()
+        return MetricProvider(session).recent_values(
+            task.id, 'step.kernel_calls')
+
+    assert job('cpu') == [0.0]
+    as_text = stages.Compiled.as_text
+    monkeypatch.setattr(
+        stages.Compiled, 'as_text', lambda self, *a, **k:
+        as_text(self, *a, **k) + 'tpu_custom_call\ntpu_custom_call\n')
+    assert job('two') == [2.0]
+
+
 def _planted(*args, **kwargs):
     raise RuntimeError('planted')
 
