@@ -18,6 +18,7 @@ from mlcomp_tpu.models.transformer import (
     TransformerConfig, TransformerLM,
 )
 from mlcomp_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
+from mlcomp_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLM
 from mlcomp_tpu.models.unet import UNet
 from mlcomp_tpu.models.vit import ViT
 
@@ -25,7 +26,7 @@ __all__ = [
     'create_model', 'model_names', 'param_count', 'register_model',
     'MLP', 'ResNet', 'BasicBlock', 'Bottleneck',
     'TransformerConfig', 'TransformerLM', 'UNet', 'ViT',
-    'Qwen3NextConfig', 'Qwen3NextLM',
+    'Qwen3NextConfig', 'Qwen3NextLM', 'Lfm2MoeConfig', 'Lfm2MoeLM',
     'ResNetEncoder', 'FPN', 'LinkNet', 'PSPNet', 'DeepLabV3',
     'PipelinedTransformerLM',
     'VGGEncoder', 'DenseNetEncoder', 'EfficientNetEncoder',

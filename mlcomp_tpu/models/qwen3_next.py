@@ -13,20 +13,11 @@ device trace; the kernels inside carry names of their own
 (``gated_delta_prepare`` / ``gated_delta_fwd`` / ``gated_delta_bwd_scan``
 / ``gated_delta_prepare_bwd``, ``gqa_attn``, ``expert_matmul``).
 
-**The share.** ``experts_held`` / ``expert_offset`` tell an expert layer
-which of the ``n_experts`` it holds: ``[offset, offset + held)``. The
-router keeps all ``n_experts`` outputs, the top-k and its
-renormalisation run over all of them, the layer computes the part of
-the sum that its own experts give for the tokens routed to them, and
-the shared expert whole. What the absent experts would add is left out:
-under expert parallelism their ranks add it. No expert has a capacity:
-the (token, expert) pairs that land here are sorted by expert and go
-through grouped matrix products (``jax.lax.ragged_dot``, or the
-megablox Pallas kernel on a TPU) whatever the split between experts.
-``moe_buffer_factor`` bounds the rows of that sorted buffer at a
-multiple of the even share (``tokens * top_k * held / n_experts``);
-``None`` sizes it for the worst case, so that nothing can ever be left
-out. The ``moe.dropped`` counter says how many pairs did not fit.
+The expert layer is ``models/decoder_parts.py``'s ``SparseMoe`` — the
+share of the experts a program holds (``experts_held`` /
+``expert_offset``), the sorted buffer and its counters are described
+there — with this model's router: a softmax whose top-k values,
+renormalised, are the weights, and a gated shared expert.
 
 Counters (sown under ``intermediates``, carried out of the step by
 ``train/loop.py`` and emitted per epoch by ``JaxTrain``):
@@ -35,7 +26,6 @@ Counters (sown under ``intermediates``, carried out of the step by
 """
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -46,8 +36,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from mlcomp_tpu.models.base import register_model
-from mlcomp_tpu.models.transformer import (
-    MlpBlock, TransformerConfig, _dense,
+from mlcomp_tpu.models.decoder_parts import (
+    MoeConfig, SparseMoe, dense, per_device, remat_saving, rms_norm,
+    rotary,
 )
 
 
@@ -94,56 +85,6 @@ class Qwen3NextConfig:
             else int(self.experts_held)
 
 
-def _norm(cfg, name, axes=('norm',)):
-    return nn.RMSNorm(
-        epsilon=cfg.rms_eps, dtype=jnp.dtype(cfg.dtype), name=name,
-        param_dtype=jnp.float32,
-        scale_init=nn.with_logical_partitioning(
-            nn.initializers.ones, axes))
-
-
-def _per_device(mesh, fn, n_batched, *args, n_out=1):
-    """``fn`` on each device's rows of the first ``n_batched`` arguments
-    (the others whole; ``n_out`` batch-major results): the Pallas
-    kernels see local shards. One device or data-parallel only."""
-    if mesh is None or mesh.size == 1:
-        return fn(*args)
-    for axis in ('sp', 'tp', 'ep', 'pp'):
-        if mesh.shape.get(axis, 1) > 1:
-            raise NotImplementedError(
-                f'qwen3_next runs on one device or data-parallel; the '
-                f'mesh has {axis}={mesh.shape[axis]}')
-    from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:                                 # older jax
-        from jax.experimental.shard_map import shard_map
-    data = tuple(a for a in ('dp', 'fsdp') if a in mesh.axis_names)
-    specs = tuple(P(data) if i < n_batched else P()
-                  for i in range(len(args)))
-    out = P(data) if n_out == 1 else (P(data),) * n_out
-    return shard_map(fn, mesh=mesh, in_specs=specs, out_specs=out,
-                     check_vma=False)(*args)
-
-
-# ---------------------------------------------------------------- rotary
-def rotary(x, theta: float, rotary_dim: int):
-    """Rotary positions on the first ``rotary_dim`` of the head
-    dimension of x [B,T,H,D] (the rest passes), halves rotated against
-    each other as the published model does."""
-    t = x.shape[1]
-    half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:rotary_dim].astype(jnp.float32)
-    turned = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
-    return jnp.concatenate([turned, x[..., rotary_dim:]], -1)
-
-
 # ------------------------------------------------------------ the mixers
 class GatedAttention(nn.Module):
     """Grouped-query causal attention with per-head q/k RMSNorm, partial
@@ -156,15 +97,15 @@ class GatedAttention(nn.Module):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        qg = _dense((h, 2 * d), ('embed', 'heads', 'kv'), dtype,
-                    'q_proj')(x)
+        qg = dense((h, 2 * d), ('embed', 'heads', 'kv'), dtype,
+                   'q_proj')(x)
         q, gate = qg[..., :d], qg[..., d:]
-        k = _dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
-                   'k_proj')(x)
-        v = _dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
-                   'v_proj')(x)
-        q = _norm(cfg, 'q_norm', ('kv',))(q)
-        k = _norm(cfg, 'k_norm', ('kv',))(k)
+        k = dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
+                  'k_proj')(x)
+        v = dense((hkv, d), ('embed', 'heads', 'kv'), dtype,
+                  'v_proj')(x)
+        q = rms_norm(cfg, 'q_norm', ('kv',))(q)
+        k = rms_norm(cfg, 'k_norm', ('kv',))(k)
         rot = int(d * cfg.partial_rotary_factor)
         q = rotary(q, cfg.rope_theta, rot)
         k = rotary(k, cfg.rope_theta, rot)
@@ -177,10 +118,10 @@ class GatedAttention(nn.Module):
                 return fused_attention(q, k, v, causal=True,
                                        impl=cfg.attn_impl)
 
-        out = _per_device(self.mesh, attend, 3, q, k, v)
+        out = per_device(self.mesh, attend, 3, q, k, v)
         out = out * jax.nn.sigmoid(gate)
-        out = _dense(cfg.d_model, ('heads', 'kv', 'embed'), dtype,
-                     'o_proj', axis=(-2, -1))(out)
+        out = dense(cfg.d_model, ('heads', 'kv', 'embed'), dtype,
+                    'o_proj', axis=(-2, -1))(out)
         return nn.with_logical_constraint(out, ('batch', 'seq', 'embed'))
 
 
@@ -207,9 +148,9 @@ class GatedDeltaNet(nn.Module):
         b, t, _ = x.shape
         key_w, val_w = hk * dk, hv * dv
         qkvz = checkpoint_name(
-            _dense(2 * key_w + 2 * val_w, ('embed', 'mlp'), dtype,
-                   'in_proj_qkvz')(x), 'linear_attn.qkvz')
-        ba = _dense(2 * hv, ('embed', 'heads'), dtype, 'in_proj_ba')(x)
+            dense(2 * key_w + 2 * val_w, ('embed', 'mlp'), dtype,
+                  'in_proj_qkvz')(x), 'linear_attn.qkvz')
+        ba = dense(2 * hv, ('embed', 'heads'), dtype, 'in_proj_ba')(x)
         conv = self.param(
             'conv', nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), (None, 'mlp')),
@@ -241,158 +182,17 @@ class GatedDeltaNet(nn.Module):
 
         from mlcomp_tpu.ops.gated_delta import chunk_count, \
             gated_delta_rule
-        o = _per_device(
+        o = per_device(
             self.mesh,
             lambda *a: gated_delta_rule(*a, chunk=cfg.delta_chunk,
                                         impl=cfg.delta_impl),
             5, q, k, v, g, beta)
         self.sow('intermediates', 'gated_delta.chunks',
                  jnp.float32(chunk_count(b, t, hv, cfg.delta_chunk)))
-        o = _norm(cfg, 'norm', ('kv',))(o)
+        o = rms_norm(cfg, 'norm', ('kv',))(o)
         o = o * nn.silu(z.reshape(b, t, hv, dv))
-        out = _dense(cfg.d_model, ('mlp', 'embed'), dtype, 'out_proj')(o.reshape(b, t, val_w))
+        out = dense(cfg.d_model, ('mlp', 'embed'), dtype, 'out_proj')(o.reshape(b, t, val_w))
         return nn.with_logical_constraint(out, ('batch', 'seq', 'embed'))
-
-
-# ------------------------------------------------------------ the experts
-def grouped_matmul(lhs, rhs, group_sizes, impl: str):
-    """[M,K] x [G,K,N] -> [M,N], rows grouped by ``group_sizes``; rows
-    past their sum come back undefined (the caller masks them)."""
-    if impl == 'auto':
-        impl = 'gmm' if jax.default_backend() == 'tpu' else 'ragged'
-    with jax.named_scope('expert_matmul'):
-        if impl == 'ragged':
-            return jax.lax.ragged_dot(lhs, rhs, group_sizes)
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-        tile = lambda n: min(512, n)  # noqa: E731
-        return gmm(lhs, rhs, group_sizes, lhs.dtype,
-                   (min(128, lhs.shape[0]), tile(lhs.shape[1]),
-                    tile(rhs.shape[2])), interpret=impl == 'interpret')
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def routing_top_k(probs, k: int):
-    """``lax.top_k`` over the experts of probs [N,E], its results named
-    ``moe.routing`` and its gradient a scatter to the NAMED indices.
-    ``lax.top_k``'s own gradient rule reads its un-named index output,
-    for which a save-by-name `remat` sorts a second time; the numbers
-    and the gradient are ``lax.top_k``'s."""
-    return _routing_top_k_fwd(probs, k)[0]
-
-
-def _routing_top_k_fwd(probs, k):
-    top_w, top_i = (checkpoint_name(x, 'moe.routing')
-                    for x in jax.lax.top_k(probs, k))
-    # `probs` (held under its own name) gives the gradient its shape
-    return (top_w, top_i), (top_i, probs)
-
-
-def _routing_top_k_bwd(k, residuals, cotangents):
-    top_i, probs = residuals
-    rows = jnp.arange(top_i.shape[0])[:, None]
-    return (jnp.zeros_like(probs).at[rows, top_i].add(cotangents[0]),)
-
-
-routing_top_k.defvjp(_routing_top_k_fwd, _routing_top_k_bwd)
-
-
-def buffer_rows(cfg: Qwen3NextConfig, tokens: int) -> int:
-    """Rows of the sorted (token, expert) buffer: the worst case, or
-    ``moe_buffer_factor`` times the even share; whole row tiles of the
-    grouped product."""
-    rows = tokens * min(cfg.top_k, cfg.held)
-    if cfg.moe_buffer_factor is not None:
-        even = tokens * cfg.top_k * cfg.held / cfg.n_experts
-        rows = min(rows, int(cfg.moe_buffer_factor * even))
-    tile = 128 if tokens >= 128 else 8
-    return max(1, -(-rows // tile)) * tile
-
-
-class SparseMoe(nn.Module):
-    """Top-k routed experts on a share of the experts, with a gated
-    shared expert (module docstring)."""
-    cfg: Qwen3NextConfig
-    mesh: Optional[Mesh] = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        dtype = jnp.dtype(cfg.dtype)
-        f32 = jnp.float32
-        held, m, f = cfg.held, cfg.d_model, cfg.d_expert
-        if not 0 <= cfg.expert_offset <= cfg.n_experts - held:
-            raise ValueError(
-                f'experts [{cfg.expert_offset}, {cfg.expert_offset + held})'
-                f' are not among {cfg.n_experts}')
-        router = self.param(
-            'router', nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ('embed', None)),
-            (m, cfg.n_experts), f32)
-
-        def experts(name, shape, axes):
-            return self.param(name, nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), axes), shape, jnp.float32)
-
-        wi_gate = experts('wi_gate', (held, m, f),
-                          ('expert', 'embed', 'mlp'))
-        wi_up = experts('wi_up', (held, m, f), ('expert', 'embed', 'mlp'))
-        wo = experts('wo', (held, f, m), ('expert', 'mlp', 'embed'))
-
-        def routed(x, router, wi_gate, wi_up, wo):
-            b, t, _ = x.shape
-            n = b * t
-            flat = x.reshape(n, m)
-            # the router over ALL experts, in float32
-            probs = checkpoint_name(jax.nn.softmax(jnp.dot(
-                flat.astype(f32), router,
-                precision=jax.lax.Precision.HIGHEST), -1), 'moe.probs')
-            top_w, top_i = routing_top_k(probs, cfg.top_k)
-            if cfg.norm_topk_prob:
-                top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
-            # pairs that land on a held expert, sorted by expert; the
-            # others sort behind them under the id `held`
-            local = top_i - cfg.expert_offset
-            local = jnp.where((local >= 0) & (local < held), local,
-                              held).reshape(-1)
-            rows = buffer_rows(cfg, n)
-            order = checkpoint_name(
-                jnp.argsort(local, stable=True)[:rows], 'moe.routing')
-            token = order // cfg.top_k
-            sizes = checkpoint_name(
-                jnp.bincount(local, length=held + 1)[:held], 'moe.routing')
-            landed = jnp.sum(sizes)
-            # groups cut to the buffer (nothing is cut at the default)
-            ends = jnp.minimum(jnp.cumsum(sizes), rows)
-            fitted = jnp.diff(ends, prepend=0).astype(jnp.int32)
-            valid = (jnp.arange(rows) < ends[-1])[:, None]
-            xs = jnp.where(valid, flat[token], 0).astype(dtype)
-            gm = lambda a, w: grouped_matmul(  # noqa: E731
-                a, w.astype(dtype), fitted, cfg.moe_impl)
-            hidden = nn.silu(gm(xs, wi_gate)) * gm(xs, wi_up)
-            # rows past the pairs that landed are undefined, in both
-            # passes: masked before anything multiplies them
-            ys = jnp.where(valid, gm(hidden, wo).astype(f32), 0) \
-                * top_w.reshape(-1)[order][:, None]
-            out = jnp.zeros((n, m), f32).at[token].add(ys)
-            mean = jnp.maximum(landed / held, 1e-9)
-            counters = jnp.stack([
-                landed / (n * cfg.top_k), jnp.max(sizes) / mean,
-                (landed - ends[-1]).astype(f32)]).astype(f32)
-            return out.astype(dtype).reshape(b, t, m), counters[None]
-
-        y, counters = _per_device(
-            self.mesh, routed, 1, x, router, wi_gate, wi_up, wo, n_out=2)
-        for i, name in enumerate(('moe.local_assign_share',
-                                  'moe.load_max_over_mean',
-                                  'moe.dropped')):
-            self.sow('intermediates', name, jnp.mean(counters[:, i]))
-
-        shared_cfg = TransformerConfig(
-            d_model=m, d_ff=cfg.d_shared, dtype=cfg.dtype)
-        shared = MlpBlock(shared_cfg, name='shared')(x)
-        gate = _dense(1, ('embed', None), dtype, 'shared_gate')(x)
-        y = y + jax.nn.sigmoid(gate) * shared
-        return nn.with_logical_constraint(y, ('batch', 'seq', 'embed'))
 
 
 # -------------------------------------------------------------- the stack
@@ -404,13 +204,13 @@ class Qwen3NextLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        y = _norm(cfg, 'norm_mixer')(x)
+        y = rms_norm(cfg, 'norm_mixer')(x)
         if self.full:
             x = x + GatedAttention(cfg, self.mesh, name='full_attn')(y)
         else:
             x = x + GatedDeltaNet(cfg, self.mesh, name='linear_attn')(y)
-        y = _norm(cfg, 'norm_moe')(x)
-        x = x + SparseMoe(cfg, self.mesh, name='moe')(y)
+        y = rms_norm(cfg, 'norm_moe')(x)
+        x = x + SparseMoe(MoeConfig.of(cfg), self.mesh, name='moe')(y)
         return nn.with_logical_constraint(x, ('batch', 'seq', 'embed'))
 
 
@@ -435,13 +235,9 @@ REMAT_SAVED = (
 
 
 def _layer_class(cfg, **remat_kwargs):
-    """``Qwen3NextLayer``, under ``cfg.remat`` with the policy that
-    holds ``REMAT_SAVED``."""
-    if not cfg.remat:
-        return Qwen3NextLayer
-    return nn.remat(
-        Qwen3NextLayer, policy=jax.checkpoint_policies
-        .save_only_these_names(*REMAT_SAVED), **remat_kwargs)
+    """``Qwen3NextLayer``, under ``cfg.remat`` holding ``REMAT_SAVED``."""
+    return remat_saving(Qwen3NextLayer, cfg.remat, REMAT_SAVED,
+                        **remat_kwargs)
 
 
 class Qwen3NextPeriod(nn.Module):
@@ -495,9 +291,9 @@ class Qwen3NextLM(nn.Module):
             full = (i + 1) % interval == 0
             x = layer(cfg, full, self.mesh, name=f'layer_{i}')(x)
 
-        x = _norm(cfg, 'norm_final')(x)
-        logits = _dense(cfg.vocab_size, ('embed', 'vocab'), dtype,
-                        'lm_head')(x)
+        x = rms_norm(cfg, 'norm_final')(x)
+        logits = dense(cfg.vocab_size, ('embed', 'vocab'), dtype,
+                       'lm_head')(x)
         return nn.with_logical_constraint(
             logits, ('batch', 'seq', 'vocab'))
 
@@ -510,5 +306,4 @@ def _qwen3_next(mesh=None, **kwargs):
     return Qwen3NextLM(cfg, mesh=mesh)
 
 
-__all__ = ['Qwen3NextConfig', 'Qwen3NextLM', 'rotary',
-           'causal_depthwise_conv', 'grouped_matmul']
+__all__ = ['Qwen3NextConfig', 'Qwen3NextLM', 'causal_depthwise_conv']

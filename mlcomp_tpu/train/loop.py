@@ -147,13 +147,22 @@ STEP_COUNTERS = {
     'moe.load_max_over_mean': jnp.mean,
     'moe.dropped': jnp.sum,
     'gated_delta.chunks': jnp.sum,
+    'short_conv.rows': jnp.sum,
 }
 
 
+#: the collection under which a model may sow, from the module that
+#: owns a parameter and under the parameter's name, what is ADDED to
+#: that leaf after the optimizer's update: an update that is no
+#: gradient's (``SparseMoe``'s selection bias moves by its load rule)
+LEAF_UPDATES = 'leaf_updates'
+
+
 def _apply(model, state: TrainState, x, train: bool, rng=None):
-    """Returns (logits, new_batch_stats, aux_loss, counters) — aux_loss
-    is the summed sown ``moe_aux_loss`` (None when the model sows
-    none), counters the sown ``STEP_COUNTERS`` ({} likewise)."""
+    """Returns (logits, new_batch_stats, aux_loss, counters,
+    leaf_updates) — aux_loss is the summed sown ``moe_aux_loss`` (None
+    when the model sows none), counters the sown ``STEP_COUNTERS`` and
+    leaf_updates the sown ``LEAF_UPDATES`` ({} likewise)."""
     variables = {'params': state.params}
     mutable = []
     if state.batch_stats is not None:
@@ -161,7 +170,7 @@ def _apply(model, state: TrainState, x, train: bool, rng=None):
         if train:
             mutable = ['batch_stats']
     if train:
-        mutable = list(mutable) + ['intermediates']
+        mutable = list(mutable) + ['intermediates', LEAF_UPDATES]
     rngs = {'dropout': rng} if (train and rng is not None) else None
     out = model.apply(variables, x, train=train, mutable=mutable,
                       rngs=rngs)
@@ -186,8 +195,18 @@ def _apply(model, state: TrainState, x, train: bool, rng=None):
         aux = sum(a.sum() for a in aux_leaves) if aux_leaves else None
         counters = {key: STEP_COUNTERS[key](jnp.concatenate(leaves))
                     for key, leaves in sown.items()}
-        return logits, updates.get('batch_stats'), aux, counters
-    return (out[0] if isinstance(out, tuple) else out), None, None, {}
+        return (logits, updates.get('batch_stats'), aux, counters,
+                updates.get(LEAF_UPDATES, {}))
+    return (out[0] if isinstance(out, tuple) else out), None, None, {}, {}
+
+
+def _add_leaf_updates(params, sown):
+    """``params`` with what was sown under ``LEAF_UPDATES`` added to
+    the leaves of the same paths."""
+    if isinstance(sown, tuple):         # what `sow` keeps: (value,)
+        return jax.tree.map(lambda leaf: leaf + sum(sown), params)
+    return {**params, **{key: _add_leaf_updates(params[key], value)
+                         for key, value in sown.items()}}
 
 
 def _with_sown(loss, metrics, aux, counters):
@@ -219,22 +238,25 @@ def _jit_in_mesh(step, mesh: Optional[Mesh], donate_argnums=()):
 def _update(model, optimizer, loss_fn, state: TrainState, x, target,
             step_rng):
     """The update rule of both train steps: the loss of ``x`` against
-    ``target`` and its gradient, the optimizer's update, the new state
-    and the step's metrics."""
+    ``target`` and its gradient, the optimizer's update, what the model
+    sowed under ``LEAF_UPDATES``, the new state and the step's
+    metrics."""
 
     def loss_wrapped(params):
-        logits, new_stats, aux, counters = _apply(
+        logits, new_stats, aux, counters, leaf_updates = _apply(
             model, state.replace(params=params), x, train=True,
             rng=step_rng)
         loss, metrics = _with_sown(*loss_fn(logits, target), aux,
                                    counters)
-        return loss, (metrics, new_stats)
+        return loss, (metrics, new_stats, leaf_updates)
 
-    grads, (metrics, new_stats) = jax.grad(
+    grads, (metrics, new_stats, leaf_updates) = jax.grad(
         loss_wrapped, has_aux=True)(state.params)
     updates, new_opt = optimizer.update(
         grads, state.opt_state, state.params)
     new_params = optax.apply_updates(state.params, updates)
+    if leaf_updates:
+        new_params = _add_leaf_updates(new_params, leaf_updates)
     new_state = state.replace(
         step=state.step + 1, params=new_params, opt_state=new_opt,
         batch_stats=(new_stats if new_stats is not None

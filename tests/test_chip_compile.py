@@ -167,7 +167,7 @@ def test_gated_delta_rule(one_chip, sequences, grad):
 def test_expert_grouped_matmul(one_chip):
     """The held experts' gate, up and down products and their backward
     (megablox gmm / tgmm) over a buffer of 40,960 routed rows."""
-    from mlcomp_tpu.models.qwen3_next import grouped_matmul
+    from mlcomp_tpu.models.decoder_parts import grouped_matmul
 
     def fwd(x, gate, up, down, sizes):
         hidden = jax.nn.silu(grouped_matmul(x, gate, sizes, 'gmm')) \
@@ -244,3 +244,109 @@ def test_qwen3_next_remat_holds_the_kernels_results(one_chip, full):
     sorts = [line for line in text.splitlines() if ' sort(' in line]
     assert sum('/moe/top_k"' in line for line in sorts) == 1
     assert sum('/moe/jit(argsort)/sort"' in line for line in sorts) == 1
+
+
+# ---- the kernels of lfm2_moe at the published widths (PR 33)
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+def test_grouped_query_flash_attention_at_head_size_64(one_chip, grad):
+    """32 query heads over 8 key-value heads of 64 (half a lane tile) at
+    2 x 8,192 tokens: what a step of ``lfm2-8b-a1b.steady`` hands the
+    three flash kernels."""
+    shapes = [((2, 8192, 32, 64), jnp.bfloat16),
+              ((2, 8192, 8, 64), jnp.bfloat16),
+              ((2, 8192, 8, 64), jnp.bfloat16)]
+    assert _compile(_flash(grad), one_chip, *shapes) >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+@pytest.mark.parametrize('block_t', [128, 256, 512])
+def test_gated_short_conv(one_chip, block_t, grad):
+    """``short_conv_fwd`` / ``short_conv_bwd`` over 2 x 8,192 rows of
+    3 x 2,048 channels: the sublane rolls, the 16-row halo blocks and
+    the whole-width tiles have to fit the VMEM the kernels ask for."""
+    from mlcomp_tpu.ops.short_conv import gated_short_conv
+
+    def fwd(bcx, taps):
+        return gated_short_conv(bcx, taps, impl='pallas', block_t=block_t)
+
+    fn = fwd if not grad else jax.grad(
+        lambda *a: (fwd(*a).astype(jnp.float32) ** 2).sum(),
+        argnums=(0, 1))
+    assert _compile(fn, one_chip, ((2, 8192, 6144), jnp.bfloat16),
+                    ((3, 2048), jnp.float32)) == (2 if grad else 1)
+
+
+def test_expert_grouped_matmul_at_2048_rows_an_expert(one_chip):
+    """8 held experts of 2,048 x 1,792 over the worst-case buffer of
+    65,536 routed rows at the cell's row tile, forward and backward
+    (megablox gmm / tgmm)."""
+    from mlcomp_tpu.models.decoder_parts import grouped_matmul, row_tile
+    tile = row_tile(2048, 65536)
+    assert tile == 512
+
+    def fwd(x, gate, up, down, sizes):
+        hidden = jax.nn.silu(grouped_matmul(x, gate, sizes, 'gmm', tile)) \
+            * grouped_matmul(x, up, sizes, 'gmm', tile)
+        return grouped_matmul(hidden, down, sizes, 'gmm', tile)
+
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                  argnums=(0, 1, 2, 3))
+    assert _compile(fn, one_chip, ((65536, 2048), jnp.bfloat16),
+                    ((8, 2048, 1792), jnp.bfloat16),
+                    ((8, 2048, 1792), jnp.bfloat16),
+                    ((8, 1792, 2048), jnp.bfloat16),
+                    ((8,), jnp.int32)) >= 8
+
+
+@pytest.mark.parametrize('kind', ['conv', 'full_attention'])
+def test_lfm2_moe_remat_holds_the_kernels_results(one_chip, kind):
+    """``jax.grad`` of one `remat`ted sparse layer of
+    ``lfm2-8b-a1b.steady`` at its widths and 2 x 8,192 tokens: with the
+    save-by-name policy (``models/lfm2_moe.py`` ``REMAT_SAVED``) the
+    backward pass runs no forward kernel again — not the conv op, not
+    the flash forward, not one grouped product."""
+    import collections
+    import json
+    import re
+
+    import flax
+    from mlcomp_tpu.models import create_model, lfm2_moe
+    from mlcomp_tpu.models.decoder_parts import remat_saving
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, 'benchmark/configs/lfm2-8b-a1b.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = create_model(**dict(
+        kwargs, attn_impl='pallas', conv_impl='pallas',
+        moe_impl='gmm')).cfg
+    assert cfg.remat
+    layer = remat_saving(lfm2_moe.Lfm2MoeLayer, True,
+                         lfm2_moe.REMAT_SAVED)(cfg, kind, True)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x)['params']))
+
+    def loss(p, x):
+        y = layer.apply({'params': p}, x, mutable=['intermediates'])[0]
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = collections.Counter(
+        re.search(r'short_conv_\w+|gqa_attn|tgmm|gmm|$', re.search(
+            r'op_name="([^"]*)"', line).group(1)).group(0)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    if kind == 'conv':
+        assert kernels['short_conv_fwd'] == 1
+        assert kernels['short_conv_bwd'] == 1
+    else:
+        assert kernels['gqa_attn'] == 3     # forward, dq, dk/dv
+    # gate, up, down once; three for the rows' gradients, three for the
+    # weights'
+    assert kernels['gmm'] == 6 and kernels['tgmm'] == 3, kernels
+    assert '' not in kernels        # no kernel but these
