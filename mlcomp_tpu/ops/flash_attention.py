@@ -1,47 +1,76 @@
-"""Fused causal flash attention as a Pallas TPU kernel.
+"""Fused causal flash attention as Pallas TPU kernels: one forward, one
+backward.
 
 SURVEY.md §2.2: the reference delegates all device math to torch/CUDA;
 the TPU build promises custom ops via Pallas. This is the first: an
-online-softmax attention forward that never materialises the [T, T]
-score matrix in HBM — scores live in VMEM one (block_q, block_k) tile
-at a time, flowing through the MXU per tile.
+online-softmax attention that never materialises the [T, T] score
+matrix in HBM — scores live in VMEM one tile at a time, flowing through
+the MXU per tile. Grouped-query attention is an index: query head ``i``
+reads key-value head ``i // group``.
 
-Kernel structure (the canonical TPU flash layout):
-- grid = (batch*heads, T/block_q, T/block_k); the LAST axis is
-  sequential ("arbitrary" dimension semantics) so VMEM scratch carries
-  the running max / normaliser / accumulator across k-blocks
-- causal blocks strictly above the diagonal are skipped whole
-  (``pl.when`` on the block predicate — ~2x fewer tiles)
-- MXU dots take the INPUT dtype (bf16 pairs multiply exactly, f32
-  accumulation via preferred_element_type — bit-identical to f32-cast
-  operand dots at a multiple of the FLOP rate; back-to-back on the
-  chip the forward ran 1.8x faster than the f32-cast version); the
-  final normalised block is cast back on write
+What both kernels share:
+- MXU products take the INPUT dtype (bf16 pairs multiply exactly, f32
+  accumulation via preferred_element_type); P and dS round to the input
+  dtype for the products they feed (standard flash practice, exact for
+  f32 inputs); masked scores are ``NEG_INF``; the residual is the
+  logsumexp a row, lane-tiled.
+- THE TILE WALK (``_walk_tile``, ``_diagonal_strips``). Causal tiles
+  are square, so the diagonal crosses tile (i, i) corner to corner and
+  no other. A tile under it runs whole with NO iota / compare / select;
+  a tile above it is skipped (and its DMA elided: the index map re-names
+  the resident tile); the diagonal's own tile is walked in static row
+  strips, strip ``a`` against the tile's first ``(a + 1) * sub`` keys,
+  the mask on its last ``sub`` columns' worth of pairs only. Four
+  strips run 10 of a tile's 16 sub-tiles. ``executed_pairs`` counts
+  what a walk multiplies: the backward runs 1.12 x the pairs causal
+  attention requires at T = 2,048 and 1.03 x at 8,192 (1.50 and 1.125
+  with every live tile whole, as before PR 34).
+- tiles of 1024 x 1024 at every head size, each kernel asking for the
+  scoped VMEM its shapes need (``_vmem_limit``).
 
-Backward: FUSED Pallas kernels — residuals are just (q, k, v, out,
-lse), O(T) extra memory; P tiles are reconstructed exactly in VMEM
-from the saved logsumexp. Two kernels: dq accumulates over k-blocks,
-dk/dv over q-blocks, both skipping causal-dead tiles; p/ds round to
-the input dtype for the gradient dots (standard flash practice, exact
-for f32 inputs). Measured on the chip (B=1, H=16, D=64 bf16): fwd+bwd
-16 ms at seq 8,192 — 3.9x the tokens/sec of dense+remat attention in
-the full-model BENCH — and runs at seq 32,768 where the dense backward
-cannot compile (its [T, T] probability tensor alone is 8.6 GB at 16k).
-Block defaults re-swept on-chip in round 5 AFTER the dead-tile DMA
-elision landed: forward 1024x1024 (12.9 vs 14.3 ms at the old
-512x1024, B=1/H=16/T=8192/D=64 with lse; 2048x1024 measured 10.0
-standalone but exceeds the 16 MB scoped-vmem limit inside the full
-model — 17.25 MB — so it is not the default), backward 1024x1024
-(14.3 vs 15.8 at the old 512x512; larger backward tiles also fail
-VMEM). The
-earlier "larger backward blocks 2-5x slower" anomaly was the
-causally-DEAD tile DMA — pl.when skips compute, not the BlockSpec
-copies — which the clamped index maps now elide; with dead tiles no
-longer fetched, bigger tiles amortize better and the anomaly is gone.
+Forward (``_fa_kernel``): grid (batch*heads, q-blocks, k-blocks), the
+LAST axis sequential so VMEM scratch carries the running max /
+normaliser / accumulator across k-blocks; K and V stream tile by tile.
+It is bound by the VPU's passes over the scores at head sizes up to 128
+(2 products a pair), so there the diagonal tile runs as ONE strip —
+strips measured slower — and in two at 256 (``_strips``).
 
-``fused_attention`` is the entry point the transformer uses: it picks
-the kernel on TPU, the interpreter in tests, and the dense jnp path
-anywhere else or for shapes the kernel doesn't tile.
+Backward (``_fa_bwd_kernel``): ONE kernel. Grid (batch*kv-heads, spans,
+(query head of the group, q-block)): a key-value head's K and V stay in
+VMEM whole while its group's q-blocks pass, dK and dV accumulate beside
+them in float32 and are written once a head, dQ accumulates over the
+k-blocks of its q-block in a loop INSIDE the step (no grid step for a
+dead tile). S, P, dP and dS are made once a strip and feed all three
+gradients: 5 products a pair where the dq and dk/dv kernels this
+replaces ran 7, one ``exp`` where they ran two; the dS correction
+``delta = rowsum(dO * O)`` is made in the step from the q-block's own
+rows, not by an XLA fusion into a lane-tiled array. The residents (K, V,
+the dK / dV blocks, two float32 accumulators: 50 MB a head of 8,192 x
+256) are counted (``_resident_bytes``) and a sequence too long for
+``RESIDENT_BYTES`` goes through the same kernel in spans of keys, dQ
+summed from one float32 partial a span. Residuals are (q, k, v, out,
+lse): O(T) extra memory.
+
+Measured on a v5e (my chip runs, PR 34, ``scripts/flash_probe.py``;
+bf16, causal, the kernels' own device time from a trace, and as a share
+of 197 TFLOP/s on the FLOPs attention REQUIRES; before -> after):
+- 4 x 2,048 tokens, 16 heads of 128 (``olmo-1b``): forward 0.875 ->
+  0.776 ms (39.9 -> 45.0%), backward 2.220 -> 1.158 ms (31.4 -> 60.3%)
+- 2 x 8,192, 32 over 8 heads of 64 (``lfm2-8b-a1b``): forward 9.862 ->
+  9.451 ms (28.3 -> 29.5%), backward 25.966 -> 15.767 ms (21.5 -> 35.4%)
+- 2 x 8,192, 16 over 2 heads of 256 (``qwen3-next-80b-a3b``): forward
+  10.488 -> 7.367 ms (53.2 -> 75.8%), backward 24.840 -> 15.008 ms
+  (44.9 -> 74.4%)
+The backward runs at 85–96% of the MXU's rate on the products it
+executes (half the rate at head size 64, whose products are 64 deep or
+wide); what is left in the forward is the VPU's share of a tile, which
+the compiler does not overlap with the MXU's. It runs at T = 32,768
+where the dense backward cannot compile (its [T, T] probability tensor
+alone is 8.6 GB at 16k).
+
+``fused_attention`` is the entry point the models use: it picks the
+kernels on TPU, the interpreter in tests, and the dense jnp path
+anywhere else or for shapes the kernels do not tile.
 """
 
 import functools
@@ -78,8 +107,27 @@ def reference_attention(q, k, v, causal: bool = True,
     return out.astype(q.dtype)
 
 
+def _scores(q, k, scale, masked_from=None):
+    """One strip of scaled scores in float32. ``masked_from`` is the
+    column of the strip's first row at which the causal mask starts
+    (row ``r`` sees columns ``<= masked_from + r``): only a strip the
+    diagonal crosses passes it, so a strip wholly under the diagonal
+    pays no iota / compare / select."""
+    # operands stay in the input dtype (bf16 for bf16 models): the MXU
+    # multiplies bf16 pairs exactly and accumulates in f32 via
+    # preferred_element_type
+    s = lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if masked_from is not None:
+        ahead = lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+            - lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(ahead > masked_from, NEG_INF, s)
+    return s
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
-               block_q, block_k, n_k, emit_lse):
+               block_q, block_k, n_k, strips, emit_lse):
     if emit_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -94,41 +142,27 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: skip whole blocks above the diagonal (shared rule —
-    # the index-map clamps derive from the same helpers)
-    live = _block_live(i_q, i_k, block_q, block_k, causal)
-
-    @pl.when(live)
-    def _accumulate():
-        # operands stay in the input dtype (bf16 for bf16 models): the
-        # MXU multiplies bf16 pairs exactly and accumulates in f32 via
-        # preferred_element_type, so `s` is bit-identical to the old
-        # f32-cast dot at a multiple of the FLOP rate
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = i_q * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = i_k * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos > q_pos, NEG_INF, s)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
+    def strip(r0, rows, cols, masked):
+        """Online-softmax update of q rows ``[r0, r0 + rows)`` with the
+        tile's first ``cols`` keys."""
+        at = slice(r0, r0 + rows)
+        v = v_ref[0, :cols]
+        s = _scores(q_ref[0, at], k_ref[0, :cols], scale,
+                    cols - rows if masked else None)
+        m_prev = m_scr[at, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l_scr[at, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         # p rounds to the value dtype for the MXU (standard flash
         # practice; exact when inputs are f32)
-        acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
+        acc_scr[at] = acc_scr[at] * corr + lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[at] = jnp.broadcast_to(m_new, (rows, m_scr.shape[1]))
+        l_scr[at] = jnp.broadcast_to(l_new, (rows, l_scr.shape[1]))
+
+    _walk_tile(i_q, i_k, causal, block_q, block_k, strips, strip)
 
     @pl.when(i_k == n_k - 1)
     def _finalise():
@@ -142,25 +176,76 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
                 m_scr[:, :1] + jnp.log(norm[:, :1]), lse_ref.shape[1:])
 
 
-def _last_live_k(i_q, block_q: int, block_k: int):
-    """Highest k-block index with any unmasked element for q-block
-    ``i_q`` — THE causal liveness rule. The kernels' skip predicates
-    and the index-map clamps below both derive from it, so they cannot
-    drift apart (a divergence would DMA the wrong tile for a live
-    step, a correctness bug, not just lost elision)."""
-    return ((i_q + 1) * block_q - 1) // block_k
+# ---------------------------------------------------------- the tile walk
+# Causal tiles are square (``_blocks``), so tile (i_q, i_k) is wholly
+# under the diagonal where i_k < i_q, crossed by it where i_k == i_q and
+# dead above. The kernels' predicates, the index-map clamp and the
+# count the tests hold (``executed_pairs``) all derive from the three
+# helpers below, so they cannot drift apart (a divergence would DMA the
+# wrong tile for a live step, a correctness bug, not just lost elision).
+
+def _last_live_k(i_q):
+    """Highest k-block with any unmasked element for q-block ``i_q``:
+    the tile the diagonal crosses. Every k-block below it is whole."""
+    return i_q
 
 
-def _first_live_q(i_k, block_q: int, block_k: int):
-    """Dual: lowest live q-block index for k-block ``i_k``."""
-    return (i_k * block_k) // block_q
+def _strips(products: int, d: int) -> int:
+    """Strips a diagonal tile is walked in, from the MXU passes a pair
+    costs the kernel (its products x the 128-deep passes a head of
+    ``d`` takes). A strip saves the products above the diagonal and
+    pays for it in short operand streams and in K transposed once a
+    strip, so it pays where the MXU binds. On the chip (PR 34, the
+    kernels alone, ms at 1 / 2 / 4 strips a 1024-tile): the forward at
+    head size 128 (4 x 2,048 x 16 heads) 0.770 / 0.875 / 0.910, at 64
+    (2 x 8,192 x 32) 9.45 / 9.86 / 9.99, at 256 (2 x 8,192 x 16) 7.74 /
+    7.37 / 7.44; the backward 1.438 / 1.217 / 1.126, 16.89 / 16.00 /
+    15.64 and 16.24 / 15.36 / 14.94."""
+    work = products * -(-d // 128)
+    return 1 if work < 4 else 2 if work < 5 else 4
 
 
-def _block_live(i_q, i_k, block_q: int, block_k: int, causal: bool):
-    """The kernels' skip predicate: does tile (i_q, i_k) contain any
-    unmasked element?"""
-    return (i_k <= _last_live_k(i_q, block_q, block_k)) \
-        if causal else True
+def _diagonal_strips(block: int, strips: int):
+    """The static walk of a tile the diagonal crosses, as (first row,
+    rows, keys): strip ``a`` multiplies its ``sub`` rows by the tile's
+    first ``(a + 1) * sub`` keys only — four strips run 10 of the
+    tile's 16 sub-tiles, one runs the tile whole — and only its last
+    ``sub`` columns hold masked pairs. ``sub`` is in whole 128s (a
+    strip's keys are the score strip's lanes)."""
+    sub = _fit_block(block, max(128, block // strips))
+    return [(a * sub, sub, (a + 1) * sub) for a in range(block // sub)]
+
+
+def _walk_tile(i_q, i_k, causal, block_q, block_k, strips, strip):
+    """Run ``strip(first row, rows, keys, masked)`` over tile
+    (i_q, i_k) of a grid whose last axis is the k-block: whole and
+    unmasked where the tile lies under the diagonal (or nothing is
+    causal), by ``_diagonal_strips`` where the diagonal crosses it,
+    not at all above it."""
+    if not causal:
+        strip(0, block_q, block_k, False)
+        return
+
+    @pl.when(i_k < _last_live_k(i_q))
+    def _whole():
+        strip(0, block_q, block_k, False)
+
+    @pl.when(i_k == _last_live_k(i_q))
+    def _diagonal():
+        for r0, rows, cols in _diagonal_strips(block_q, strips):
+            strip(r0, rows, cols, True)
+
+
+def executed_pairs(t: int, block: int, strips: int, causal: bool) -> int:
+    """(query, key) pairs a kernel multiplies for one head of ``t``
+    tokens in tiles of ``block`` — what its walk EXECUTES, against the
+    ``t (t + 1) / 2`` a causal head requires (``t * t`` otherwise)."""
+    if not causal:
+        return t * t
+    diagonal = sum(rows * cols for _r0, rows, cols
+                   in _diagonal_strips(block, strips))
+    return sum(_last_live_k(i_q) * block * block + diagonal
+               for i_q in range(t // block))
 
 
 def _kv_head(bh, group: int):
@@ -170,8 +255,7 @@ def _kv_head(bh, group: int):
     return bh if group == 1 else bh // group
 
 
-def _causal_kv_ix(block_q: int, block_k: int, causal: bool,
-                  group: int = 1):
+def _causal_kv_ix(causal: bool, group: int = 1):
     """Index map for operands streamed over k-blocks (grid order
     (bh, iq, ik)). ``pl.when`` skips a masked block's COMPUTE but
     Pallas still copies the tiles the index map names — half the K/V
@@ -182,38 +266,8 @@ def _causal_kv_ix(block_q: int, block_k: int, causal: bool,
     and skip logic are unaffected."""
     if not causal:
         return lambda bh, iq, ik: (_kv_head(bh, group), ik, 0)
-
-    def ix(bh, iq, ik):
-        return (_kv_head(bh, group),
-                jnp.minimum(ik, _last_live_k(iq, block_q, block_k)), 0)
-    return ix
-
-
-def _causal_q_ix(block_q: int, block_k: int, causal: bool,
-                 group: int = 1, n_q: int = 0):
-    """Dual of ``_causal_kv_ix`` for operands streamed over q-blocks
-    (grid order (bh, ik, iq)): the dead steps sit BELOW the diagonal
-    start, so clamp iq from below to this k-block's first live
-    q-block. With grouped-query attention the grid runs over key-value
-    heads and its last axis over (query head of the group, q-block):
-    step ``j`` reads q-block ``j % n_q`` of query head
-    ``bh * group + j // n_q``."""
-    if group == 1:
-        if not causal:
-            return lambda bh, ik, iq: (bh, iq, 0)
-
-        def ix(bh, ik, iq):
-            return (bh,
-                    jnp.maximum(iq, _first_live_q(ik, block_q, block_k)),
-                    0)
-        return ix
-
-    def grouped(bh, ik, j):
-        iq = j % n_q
-        if causal:
-            iq = jnp.maximum(iq, _first_live_q(ik, block_q, block_k))
-        return (bh * group + j // n_q, iq, 0)
-    return grouped
+    return lambda bh, iq, ik: (
+        _kv_head(bh, group), jnp.minimum(ik, _last_live_k(iq)), 0)
 
 
 def _kv_group(q, k) -> int:
@@ -224,11 +278,21 @@ def _kv_group(q, k) -> int:
     return h // h_kv
 
 
-def _head_block(d: int, want: int) -> int:
-    """Heads wider than 128 take tiles of at most 512: at 1024 x 1024
-    the backward kernels' score-sized temporaries and the doubled q/k/v
-    tiles pass the scoped VMEM limit."""
-    return want if d <= 128 else min(want, 512)
+def _fold(x):
+    """[B, T, H, D] -> [B*H, T, D]: contiguous (seq, head_dim) tiles."""
+    b, t, h, d = x.shape
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+
+
+def _unfold(x, b: int):
+    """[B*H, T, D] -> [B, T, H, D]."""
+    _, t, d = x.shape
+    return jnp.transpose(x.reshape(b, -1, t, d), (0, 2, 1, 3))
+
+
+def _lanes(d: int) -> int:
+    """A head's width in VMEM: the minor dimension pads to whole 128s."""
+    return -(-d // 128) * 128
 
 
 def _fit_block(t: int, want: int) -> int:
@@ -240,6 +304,21 @@ def _fit_block(t: int, want: int) -> int:
             return cand
     raise ValueError(f'seq len {t} not divisible by any 128-multiple '
                      f'block ≤ {want}')
+
+
+def _blocks(t: int, block_q: int, block_k: int, causal: bool):
+    """The tile of a call, from the sequence and what the caller
+    wants: square where the mask is causal, so that the diagonal
+    crosses only tiles (i, i) and crosses them corner to corner — the
+    static walk of ``_diagonal_strips``. The default, 1024, is every
+    head size's since PR 34: heads of 256 took 512-tiles to fit the
+    default scoped VMEM, and at 2 x 8,192 x 16 heads their forward ran
+    10.49 ms for 7.37 at 1024 (a grid step costs ~0.35 us, dead ones
+    too), their backward 15.19 for 14.94."""
+    block_q, block_k = _fit_block(t, block_q), _fit_block(t, block_k)
+    if causal:
+        block_q = block_k = min(block_q, block_k)
+    return block_q, block_k
 
 
 def flash_attention_forward(q, k, v, causal: bool = True,
@@ -255,24 +334,19 @@ def flash_attention_forward(q, k, v, causal: bool = True,
     b, t, h, d = q.shape
     group = _kv_group(q, k)
     scale = scale if scale is not None else d ** -0.5
-    block_q = _fit_block(t, _head_block(d, block_q))
-    block_k = _fit_block(t, _head_block(d, block_k))
+    block_q, block_k = _blocks(t, block_q, block_k, causal)
     n_q, n_k = t // block_q, t // block_k
 
-    # [B, T, H, D] -> [B*H, T, D]: contiguous (seq, head_dim) tiles
-    def fold(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            b * x.shape[2], t, d)
-
-    qf, kf, vf = fold(q), fold(k), fold(v)
+    qf, kf, vf = _fold(q), _fold(k), _fold(v)
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, emit_lse=with_lse)
+        block_k=block_k, n_k=n_k, strips=_strips(2, d),
+        emit_lse=with_lse)
 
     # causal dead-tile DMA elision for the streamed k/v operands (see
     # _causal_kv_ix)
-    kv_ix = _causal_kv_ix(block_q, block_k, causal, group)
+    kv_ix = _causal_kv_ix(causal, group)
 
     out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d),
@@ -301,109 +375,139 @@ def flash_attention_forward(q, k, v, causal: bool = True,
             pltpu.VMEM((block_q, d), jnp.float32),     # output accum
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            # resident: the K and V tiles, double-buffered
+            vmem_limit_bytes=_vmem_limit(
+                4 * block_k * _lanes(d) * q.dtype.itemsize, block_q,
+                block_k, d, q.dtype.itemsize)),
         interpret=interpret,
     )(qf, kf, vf)
 
+    out = _unfold(result[0], b)
     if with_lse:
-        out, lse = result
-        out = jnp.transpose(out.reshape(b, h, t, d), (0, 2, 1, 3))
-        return out, lse[:, :, 0].reshape(b, h, t)
-    out = result[0]
-    return jnp.transpose(out.reshape(b, h, t, d), (0, 2, 1, 3))
+        return out, result[1][:, :, 0].reshape(b, h, t)
+    return out
 
 
-def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    i_q, i_k, *, scale, causal, block_q, block_k):
-    """Rebuild this tile's probabilities and dS exactly as the forward
-    computed them — shared by both backward kernels so their numerics
-    cannot drift apart."""
-    # operands stay in the input dtype: bf16 pairs multiply exactly on
-    # the MXU with f32 accumulation (preferred_element_type), matching
-    # the old f32-cast dots bit-for-bit at a multiple of the FLOP rate;
-    # p/ds round to the input dtype for the gradient dots (standard
-    # flash practice; exact when inputs are f32)
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if causal:
-        q_pos = i_q * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = i_k * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(k_pos > q_pos, NEG_INF, s)
-    p = jnp.exp(s - lse_ref[0][:, :1])
-    dov = lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dov - delta_ref[0][:, :1])
-    return q, k, do, p.astype(q.dtype), ds.astype(q.dtype)
-
-
-def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_scr, *, scale, causal, block_q,
-                      block_k, n_k):
-    i_q = pl.program_id(1)
-    i_k = pl.program_id(2)
-
-    @pl.when(i_k == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    live = _block_live(i_q, i_k, block_q, block_k, causal)
-
-    @pl.when(live)
-    def _accumulate():
-        _q, k, _do, _p, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i_q, i_k,
-            scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k)
-        dq_scr[:] += lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(i_k == n_k - 1)
-    def _finalise():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal,
-                       block_q, block_k, n_q, group=1):
-    i_k = pl.program_id(1)
-    # the last grid axis: q-blocks, and with grouped-query attention
-    # the group's query heads one after the other (see _causal_q_ix)
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   delta_scr, *,
+                   scale, causal, block_q, block_k, n_q, n_k, strips):
+    """One q-block of one query head against the ``n_k`` k-blocks of
+    its key-value head that this span holds in VMEM: S, P, dP and dS
+    are made once a strip and feed all three gradients."""
+    first_k = pl.program_id(1) * n_k     # the span's first k-block
+    # the last grid axis: the group's query heads one after the other,
+    # each over its q-blocks
     step = pl.program_id(2)
-    i_q = step if group == 1 else step % n_q
+    i_q = step % n_q
 
     @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live = _block_live(i_q, i_k, block_q, block_k, causal)
+    dq_scr[:] = jnp.zeros_like(dq_scr)
+    # delta_i = rowsum(dO_i · O_i), the dS correction term, made here
+    # from the q-block's own rows and kept lane-tiled like lse: no
+    # [bh, t, 128] array of it in HBM, no XLA fusion to make one
+    delta_scr[:] = jnp.broadcast_to(jnp.sum(
+        do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+        axis=-1, keepdims=True), delta_scr.shape)
 
-    @pl.when(live)
-    def _accumulate():
-        q, _k, do, p, ds = _recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i_q, i_k,
-            scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k)
-        dv_scr[:] += lax.dot_general(
+    def strip(r0, rows, k0, cols, masked):
+        """q rows ``[r0, r0 + rows)`` against the span's keys
+        ``[k0, k0 + cols)``."""
+        at = slice(r0, r0 + rows)
+        keys = pl.ds(k0, cols)
+        q, do = q_ref[0, at], do_ref[0, at]
+        k, v = k_ref[0, keys], v_ref[0, keys]
+        # the probabilities exactly as the forward made them, from the
+        # saved logsumexp; p and ds round to the input dtype for the
+        # gradient products (standard flash practice; exact when
+        # inputs are f32)
+        s = _scores(q, k, scale, cols - rows if masked else None)
+        p = jnp.exp(s - lse_ref[0, at, :1])
+        dp = lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_scr[at, :1])).astype(q.dtype)
+        p = p.astype(q.dtype)
+        dv_scr[keys] += lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bk, d]
-        dk_scr[:] += lax.dot_general(
+            preferred_element_type=jnp.float32)       # [cols, d]
+        dk_scr[keys] += lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        dq_scr[at] += lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
-    @pl.when(step == group * n_q - 1)
+    def whole(i_k, carry):
+        strip(0, block_q, pl.multiple_of(i_k * block_k, block_k),
+              block_k, False)
+        return carry
+
+    if causal:
+        # of the tiles under the diagonal, those this span holds; then
+        # the diagonal's own tile by strips, if it is the span's
+        here = _last_live_k(i_q) - first_k
+        lax.fori_loop(0, jnp.clip(here, 0, n_k), whole, None)
+
+        @pl.when((here >= 0) & (here < n_k))
+        def _diagonal():
+            k0 = pl.multiple_of(here * block_k, block_k)
+            for r0, rows, cols in _diagonal_strips(block_q, strips):
+                strip(r0, rows, k0, cols, True)
+    else:
+        lax.fori_loop(0, n_k, whole, None)
+
+    dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalise():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+# What one backward call may keep in VMEM for a key-value head's K and
+# V, their gradients' blocks and the two float32 accumulators: half of a
+# v5e core's 128 MiB, which leaves the q-side tiles, the score-sized
+# temporaries and the compiler's own room. A longer sequence goes
+# through in spans of this size (``_span``).
+RESIDENT_BYTES = 64 << 20
+
+
+def _resident_bytes(span: int, d: int, itemsize: int) -> int:
+    """VMEM that ``span`` keys of one key-value head hold through the
+    backward: K, V and the dK, dV blocks (each double-buffered by the
+    pipeline) and the two float32 accumulators, lanes padded to 128."""
+    return span * _lanes(d) * (8 * itemsize + 2 * 4)
+
+
+def _span(t: int, block_k: int, d: int, itemsize: int) -> int:
+    """Keys the backward holds at once: the whole sequence where it
+    fits ``RESIDENT_BYTES`` (every shape the cells run), else its
+    largest part in whole k-blocks that does."""
+    n_k = t // block_k
+    for spans in range(1, n_k + 1):
+        if n_k % spans == 0 and _resident_bytes(
+                t // spans, d, itemsize) <= RESIDENT_BYTES:
+            return t // spans
+    return block_k
+
+
+def _vmem_limit(resident, block_q, block_k, d, itemsize) -> int:
+    """A kernel's scoped-VMEM limit from its shapes (the default, 16 MB,
+    holds neither a 1024 x 1024 tile at head size 256 nor a resident
+    head): what stays through the walk, the q-side tiles (q, O, dO and
+    the result block double-buffered, the lane-tiled row statistics,
+    a float32 accumulator), eight score-sized float32 temporaries (S,
+    P, dP, dS, their rounded copies and the transposes the MXU is
+    fed), and a quarter on top."""
+    tiles = block_q * (_lanes(d) * (8 * itemsize + 4) + 4 * 128 * 4)
+    need = resident + tiles + 8 * block_q * block_k * 4
+    return min(max(need + need // 4, 32 << 20), 110 << 20)
 
 
 def flash_attention_backward(q, k, v, out, lse, do,
@@ -413,86 +517,70 @@ def flash_attention_backward(q, k, v, out, lse, do,
                              interpret: bool = False):
     """Fused flash backward: O(T) residuals (just out + lse), the
     probability tiles reconstructed in VMEM from lse exactly as the
-    forward computed them. Two kernels: dq accumulates over k-blocks,
-    dk/dv accumulate over q-blocks."""
+    forward computed them. ONE kernel: a key-value head's K and V stay
+    in VMEM while its ``group`` query heads' q-blocks pass, dK and dV
+    accumulate beside them in float32 and dQ over the k-blocks of each
+    q-block, so every tile's S, P, dP and dS are made once (5 products
+    a tile). A sequence too long to stay (``_span``) goes through in
+    spans, each adding a float32 partial of dQ that is summed here."""
     b, t, h, d = q.shape
     group = _kv_group(q, k)
     h_kv = h // group
     scale = scale if scale is not None else d ** -0.5
-    block_q = _fit_block(t, _head_block(d, block_q))
-    block_k = _fit_block(t, _head_block(d, block_k))
-    n_q, n_k = t // block_q, t // block_k
+    block_q, block_k = _blocks(t, block_q, block_k, causal)
+    n_q = t // block_q
+    span = _span(t, block_k, d, q.dtype.itemsize)
+    spans = t // span
 
-    def fold(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            b * x.shape[2], t, d)
-
-    qf, kf, vf, of, dof = fold(q), fold(k), fold(v), fold(out), fold(do)
-    # row statistics live lane-tiled ([bh, t, 128]) so their blocks meet
-    # the TPU (8, 128) trailing-dim constraint
+    qf, kf, vf, of, dof = (_fold(x) for x in (q, k, v, out, do))
+    # the row statistic lives lane-tiled ([bh, t, 128]) so its blocks
+    # meet the TPU (8, 128) trailing-dim constraint
     lsef = jnp.broadcast_to(
         lse.reshape(b * h, t)[..., None], (b * h, t, 128))
-    # delta_i = rowsum(dO_i · O_i) — the dS correction term
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (b * h, t, 128))
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0))
-    row_spec = pl.BlockSpec((1, block_q, 128),
-                            lambda bh, iq, ik: (bh, iq, 0))
+    # grid (key-value head, span, (query head of the group, q-block)):
+    # step ``j`` of the last axis reads q-block ``j % n_q`` of query
+    # head ``bh * group + j // n_q``
+    def q_ix(bh, s, j):
+        return (bh * group + j // n_q, j % n_q, 0)
 
-    # dead-tile DMA elision, same as the forward: dq streams k/v
-    kv_ix = _causal_kv_ix(block_q, block_k, causal, group)
+    q_spec = pl.BlockSpec((1, block_q, d), q_ix)
+    row_spec = pl.BlockSpec((1, block_q, 128), q_ix)
+    kv_spec = pl.BlockSpec((1, span, d), lambda bh, s, j: (bh, s, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, block_k, d), kv_ix),
-            pl.BlockSpec((1, block_k, d), kv_ix),
-            q_spec, row_spec, row_spec,
-        ],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    # dk/dv streams q/do/lse/delta with iq innermost (see _causal_q_ix)
-    q_ix = _causal_q_ix(block_q, block_k, causal, group, n_q)
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, ik, iq: (bh, ik, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_q=n_q,
-                          group=group),
+                          n_k=span // block_k, strips=_strips(5, d)),
         out_shape=[
+            # one partial of dq a span; a single span's is dq itself
+            jax.ShapeDtypeStruct(
+                (spans, b * h, t, d),
+                q.dtype if spans == 1 else jnp.float32),
             jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
             jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype),
         ],
-        grid=(b * h_kv, n_k, group * n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_ix),
-            k_spec, k_spec,
-            pl.BlockSpec((1, block_q, d), q_ix),
-            pl.BlockSpec((1, block_q, 128), q_ix),
-            pl.BlockSpec((1, block_q, 128), q_ix),
+        grid=(b * h_kv, spans, group * n_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda bh, s, j: (s,) + q_ix(bh, s, j)),
+            kv_spec, kv_spec,
         ],
-        out_specs=[k_spec, k_spec],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((span, d), jnp.float32),
+                        pltpu.VMEM((span, d), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
+            vmem_limit_bytes=_vmem_limit(
+                _resident_bytes(span, d, q.dtype.itemsize), block_q,
+                block_k, d, q.dtype.itemsize)),
         interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
+    )(qf, kf, vf, of, dof, lsef)
+    dq = dq[0] if spans == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
 
-    def unfold(x):
-        return jnp.transpose(x.reshape(b, -1, t, d), (0, 2, 1, 3))
-
-    return unfold(dq), unfold(dk), unfold(dv)
+    return _unfold(dq, b), _unfold(dk, b), _unfold(dv, b)
 
 
 def blockwise_attention(q, k, v, causal: bool = True,

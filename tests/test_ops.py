@@ -180,6 +180,130 @@ class TestFusedBackward:
             assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 
 
+# -- PR 34: one backward kernel, diagonal tiles walked by strips
+def _heads(h, h_kv, t, d, seed=3):
+    """(q, k, v, do) with ``h`` query heads over ``h_kv`` key-value
+    heads of ``d``."""
+    import jax
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (1, t, h, d)),
+            jax.random.normal(ks[1], (1, t, h_kv, d)),
+            jax.random.normal(ks[2], (1, t, h_kv, d)),
+            jax.random.normal(ks[3], (1, t, h, d)))
+
+
+def _rel(a, b):
+    import jax.numpy as jnp
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+HEADS = [(2, 2, 128), (4, 1, 64), (8, 1, 256)]
+HEAD_IDS = ['equal_d128', '4to1_d64', '8to1_d256']
+# (tokens, tile): one tile of four strips; two tiles of two strips (a
+# whole tile under the diagonal, then the diagonal's own); four tiles of
+# one strip each; T = tile with a single strip
+WALKS = [(512, 512), (512, 256), (512, 128), (128, 128)]
+
+
+@pytest.mark.parametrize('t,block', WALKS,
+                         ids=[f't{t}_tile{b}' for t, b in WALKS])
+@pytest.mark.parametrize('causal', [True, False],
+                         ids=['causal', 'full'])
+@pytest.mark.parametrize('h,h_kv,d', HEADS, ids=HEAD_IDS)
+def test_fused_flash_backward_against_dense(h, h_kv, d, causal, t, block):
+    """dq, dk, dv of the ONE backward kernel against autodiff of the
+    dense reference, and the forward with and without ``with_lse``."""
+    import functools
+
+    import jax
+    from mlcomp_tpu.ops import flash_attention as fa
+    if causal:      # the backward's walk, from its own rule
+        strips = fa._diagonal_strips(block, fa._strips(5, d))
+        assert len(strips) == {512: 4, 256: 2, 128: 1}[block]
+    q, k, v, do = _heads(h, h_kv, t, d)
+    want, pull = jax.vjp(functools.partial(
+        reference_attention, causal=causal), q, k, v)
+    kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
+    plain = fa.flash_attention_forward(q, k, v, **kw)
+    out, lse = fa.flash_attention_forward(q, k, v, with_lse=True, **kw)
+    assert _rel(plain, want) < 1e-5 and _rel(out, want) < 1e-5
+    grads = fa.flash_attention_backward(q, k, v, out, lse, do, **kw)
+    for name, a, b in zip('dq dk dv'.split(), grads, pull(do)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize('causal', [True, False], ids=['causal', 'full'])
+@pytest.mark.parametrize('spans', [2, 4])
+def test_fused_flash_backward_in_spans(monkeypatch, causal, spans):
+    """A key-value head too long to stay in VMEM whole goes through the
+    same kernel in spans, dq summed from one float32 partial a span:
+    the budget is made small, the COMPUTED bytes choose."""
+    import functools
+
+    import jax
+    from mlcomp_tpu.ops import flash_attention as fa
+    t, d, block = 512, 64, 128
+    monkeypatch.setattr(fa, 'RESIDENT_BYTES',
+                        fa._resident_bytes(t // spans, d, 4))
+    assert fa._span(t, block, d, 4) == t // spans
+    q, k, v, do = _heads(4, 2, t, d)
+    _, pull = jax.vjp(functools.partial(
+        reference_attention, causal=causal), q, k, v)
+    kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
+    out, lse = fa.flash_attention_forward(q, k, v, with_lse=True, **kw)
+    grads = fa.flash_attention_backward(q, k, v, out, lse, do, **kw)
+    for name, a, b in zip('dq dk dv'.split(), grads, pull(do)):
+        assert a.dtype == b.dtype and _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize('t,d,most', [
+    (2048, 128, 1.13),      # olmo-1b: two of three tiles diagonal
+    (8192, 64, 1.04),       # lfm2-8b-a1b
+    (8192, 256, 1.04),      # qwen3-next-80b-a3b
+])
+def test_pairs_the_walk_executes(t, d, most):
+    """What the backward kernel multiplies (5 of a trained sequence's 7
+    products) against what attention requires, from the helpers the
+    kernels and the index map themselves walk by; the forward walks
+    its diagonal tiles whole where the chip said strips lose (PR 34)."""
+    from mlcomp_tpu.ops import flash_attention as fa
+    block, same = fa._blocks(t, 1024, 1024, True)
+    assert block == same == 1024
+    need = t * (t + 1) // 2
+    n = t // block
+    whole = n * (n + 1) // 2 * block * block    # every live tile whole
+    assert fa._strips(5, d) == 4
+    pairs = fa.executed_pairs(t, block, fa._strips(5, d), True)
+    assert need <= pairs <= most * need and pairs < whole
+    forward = fa.executed_pairs(t, block, fa._strips(2, d), True)
+    assert fa._strips(2, d) == (2 if d > 128 else 1)
+    assert pairs <= forward <= whole and (forward < whole) == (d > 128)
+    # the strips cover a diagonal tile's lower triangle exactly once
+    for strips in (1, 2, 4, 8):
+        seen = np.zeros((block, block), int)
+        for r0, rows, cols in fa._diagonal_strips(block, strips):
+            seen[r0:r0 + rows, :cols] += 1
+        assert (seen[np.tril_indices(block)] == 1).all()
+        assert seen.max() == 1
+    assert fa.executed_pairs(t, block, 4, False) == t * t
+    # the index map names a dead step's tile after the diagonal's own
+    ix = fa._causal_kv_ix(True)
+    assert [int(ix(0, 1, ik)[1]) for ik in range(4)] == [0, 1, 1, 1]
+
+
+def test_causal_tiles_are_square():
+    from mlcomp_tpu.ops import flash_attention as fa
+    assert fa._blocks(8192, 1024, 512, True) == (512, 512)
+    assert fa._blocks(8192, 1024, 512, False) == (1024, 512)
+    assert fa._blocks(384, 1024, 1024, True) == (384, 384)
+    # strips are whole 128s that divide the tile
+    assert [len(fa._diagonal_strips(b, 4))
+            for b in (128, 256, 384, 512, 640, 1024)] == [1, 2, 3, 4, 5, 4]
+    assert fa._diagonal_strips(1024, 2) == [(0, 512, 512), (512, 512, 1024)]
+    assert fa._diagonal_strips(1024, 1) == [(0, 1024, 1024)]
+
+
 class TestFusedCE:
     """Blocked CE kernel (ops/fused_ce.py) vs the exact reference —
     run in interpret mode (auto resolves to dense on TPU; see the

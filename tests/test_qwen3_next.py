@@ -282,19 +282,22 @@ def attention_inputs(h, h_kv, t=256, d=256):
             jax.random.normal(ks[3], (1, t, h, d)))
 
 
+@pytest.mark.parametrize('causal', [True, False], ids=['causal', 'full'])
 @pytest.mark.parametrize('block', [128, 256])
-def test_grouped_query_flash_against_dense(block):
+def test_grouped_query_flash_against_dense(block, causal):
     """8 query heads a key-value head at head_dim 256; at block 128 the
-    dk/dv kernel's last grid axis runs over 8 heads x 2 q-blocks."""
+    backward kernel's last grid axis runs over 8 heads x 2 q-blocks,
+    at 256 the one tile is walked in two strips."""
     q, k, v, do = attention_inputs(8, 1)
-    want, pull = jax.vjp(reference_attention, q, k, v)
+    want, pull = jax.vjp(functools.partial(
+        reference_attention, causal=causal), q, k, v)
     out, lse = flash_attention_forward(
-        q, k, v, block_q=block, block_k=block, interpret=True,
-        with_lse=True)
+        q, k, v, causal=causal, block_q=block, block_k=block,
+        interpret=True, with_lse=True)
     assert rel(out, want) < 1e-5
     grads = flash_attention_backward(
-        q, k, v, out, lse, do, block_q=block, block_k=block,
-        interpret=True)
+        q, k, v, out, lse, do, causal=causal, block_q=block,
+        block_k=block, interpret=True)
     for name, a, b in zip('dq dk dv'.split(), grads, pull(do)):
         assert a.shape == b.shape
         assert rel(a, b) < 1e-5, name
@@ -318,11 +321,11 @@ def test_equal_heads_flash_is_what_it_was():
     assert rel(one[0], two[0]) < 1e-5
     assert rel(one[1], jnp.sum(two[1], 2, keepdims=True)) < 1e-5
     assert fa._kv_head(5, 1) == 5 and fa._kv_head(17, 8) == 2
-    assert fa._causal_kv_ix(128, 128, False)(3, 1, 2) == (3, 2, 0)
-    assert fa._causal_q_ix(128, 128, False)(3, 1, 2) == (3, 2, 0)
-    assert fa._causal_q_ix(128, 128, False, 8, 2)(3, 1, 5) == (26, 1, 0)
-    assert fa._head_block(128, 1024) == 1024
-    assert fa._head_block(256, 1024) == 512
+    assert fa._causal_kv_ix(False)(3, 1, 2) == (3, 2, 0)
+    assert fa._causal_kv_ix(False, 8)(17, 1, 2) == (2, 2, 0)
+    # heads of 256 take the tile of every other head since PR 34 (the
+    # kernels ask for the VMEM their shapes need)
+    assert fa._blocks(8192, 1024, 1024, True) == (1024, 1024)
 
 
 # ------------------------------------------------------------------ rotary
@@ -582,8 +585,9 @@ def test_the_backward_pass_runs_no_forward_kernel_again():
     for census in plain, held:
         assert census['gated_delta_bwd_scan'] == 1
         assert census['gated_delta_prepare_bwd'] == 1
-    # the three flash kernels once each, not the forward twice
-    assert plain['flash'] == 4 and held['flash'] == 3
+    # the two flash kernels (one backward since PR 34) once each, not
+    # the forward twice
+    assert plain['flash'] == 3 and held['flash'] == 2
     # a layer routes once: top-k, the argsort of the pairs
     assert plain['top_k'] == 4 and held['top_k'] == 2
     assert plain['sort'] == 4 and held['sort'] == 2
