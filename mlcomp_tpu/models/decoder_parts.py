@@ -1,14 +1,16 @@
-"""What the decoders of ``models/qwen3_next.py`` and ``models/lfm2_moe.py``
-share: the RMSNorm with a gain, rotary positions, the per-device call
-round a Pallas kernel, the save-by-name ``remat`` class and the sparse
-expert layer, ``SparseMoe``, with its own settings (``MoeConfig``).
+"""What the decoders of ``models/qwen3_next.py``, ``models/lfm2_moe.py``
+and ``models/deepseek_v3.py`` share: the RMSNorm with a gain, rotary
+positions, the per-device call round a Pallas kernel, the save-by-name
+``remat`` class and the sparse expert layer, ``SparseMoe``, with its own
+settings (``MoeConfig``).
 
 **The share.** ``experts_held`` / ``expert_offset`` tell an expert layer
 which of the ``n_experts`` it holds: ``[offset, offset + held)``. The
 router keeps all ``n_experts`` outputs, the top-k and its
 renormalisation run over all of them, the layer computes the part of
 the sum that its own experts give for the tokens routed to them, and
-the shared expert (where there is one) whole. What the absent experts
+the shared expert (where there is one; behind a sigmoid gate or,
+``shared_gate: False``, added as it is) whole. What the absent experts
 would add is left out: under expert parallelism their ranks add it. No
 expert has a capacity: the (token, expert) pairs that land here are
 sorted by expert and go through grouped matrix products
@@ -74,20 +76,31 @@ def per_device(mesh, fn, n_batched, *args, n_out=1):
 
 
 # ---------------------------------------------------------------- rotary
-def rotary(x, theta: float, rotary_dim: int):
+def rotary(x, theta: float, rotary_dim: int, interleaved: bool = False):
     """Rotary positions on the first ``rotary_dim`` of the head
-    dimension of x [B,T,H,D] (the rest passes), halves rotated against
-    each other as the published model does."""
+    dimension of x [B,T,H,D] (the rest passes): halves rotated against
+    each other, dimension ``i`` with ``i + rotary_dim / 2``, or,
+    ``interleaved``, neighbours, ``2 i`` with ``2 i + 1`` — each pair
+    by ``t * theta ** (-2 i / rotary_dim)``, as the published model
+    does."""
     t = x.shape[1]
     half = rotary_dim // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:rotary_dim].astype(jnp.float32)
-    turned = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+    if interleaved:
+        pairs = x[..., :rotary_dim].astype(jnp.float32).reshape(
+            x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).reshape(
+            x.shape[:-1] + (rotary_dim,)).astype(x.dtype)
+    else:
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:rotary_dim].astype(jnp.float32)
+        turned = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
     return jnp.concatenate([turned, x[..., rotary_dim:]], -1)
 
 
@@ -171,7 +184,8 @@ class MoeConfig:
     d_expert: int
     n_experts: int
     top_k: int
-    d_shared: int = 0                   # the gated shared expert; 0: none
+    d_shared: int = 0                   # the shared expert; 0: none
+    shared_gate: bool = True            # times a sigmoid gate of its own
     norm_topk_prob: bool = True
     router_score: str = 'softmax'       # | 'sigmoid', each on its own
     norm_topk_eps: float = 0.0          # added to the renormalising sum
@@ -217,9 +231,9 @@ def buffer_rows(cfg: MoeConfig, tokens: int) -> int:
 
 
 class SparseMoe(nn.Module):
-    """Top-k routed experts on a share of the experts, with a gated
-    shared expert where ``cfg.d_shared`` says so (module docstring; the
-    router's forms are ``MoeConfig``'s fields)."""
+    """Top-k routed experts on a share of the experts, with a shared
+    expert where ``cfg.d_shared`` says so, gated or not (module
+    docstring; the router's forms are ``MoeConfig``'s fields)."""
     cfg: MoeConfig
     mesh: Optional[Mesh] = None
 
@@ -342,8 +356,10 @@ class SparseMoe(nn.Module):
             shared_cfg = TransformerConfig(
                 d_model=m, d_ff=cfg.d_shared, dtype=cfg.dtype)
             shared = MlpBlock(shared_cfg, name='shared')(x)
-            gate = dense(1, ('embed', None), dtype, 'shared_gate')(x)
-            y = y + jax.nn.sigmoid(gate) * shared
+            if cfg.shared_gate:
+                gate = dense(1, ('embed', None), dtype, 'shared_gate')(x)
+                shared = jax.nn.sigmoid(gate) * shared
+            y = y + shared
         return nn.with_logical_constraint(y, ('batch', 'seq', 'embed'))
 
 

@@ -6,7 +6,11 @@ the TPU build promises custom ops via Pallas. This is the first: an
 online-softmax attention that never materialises the [T, T] score
 matrix in HBM — scores live in VMEM one tile at a time, flowing through
 the MXU per tile. Grouped-query attention is an index: query head ``i``
-reads key-value head ``i // group``.
+reads key-value head ``i // group``. The score head and the value head
+may be of two sizes (latent attention: q, k [B, T, H, 192], v, o, do
+[B, T, H, 128]; dq and dk come back as q and k, dv as v): V is never
+padded to the score head's width and P V, dP and dV run at the value
+head's.
 
 What both kernels share:
 - MXU products take the INPUT dtype (bf16 pairs multiply exactly, f32
@@ -26,7 +30,15 @@ What both kernels share:
   attention requires at T = 2,048 and 1.03 x at 8,192 (1.50 and 1.125
   with every live tile whole, as before PR 34).
 - tiles of 1024 x 1024 at every head size, each kernel asking for the
-  scoped VMEM its shapes need (``_vmem_limit``).
+  scoped VMEM its shapes need (``_vmem_limit``); tile, strips, span and
+  limit are functions of (T, the two head sizes, dtype, the kernel's
+  products) and of nothing else.
+- WHAT IS EXECUTED OVER THE REQUIRED PAIRS AT 192 / 128 (T = 8,192): the
+  walk's x1.06 forward (the diagonal tile in two strips) and x1.03
+  backward, and, in VMEM only, the lanes of the 192-wide q, k, dq, dk
+  padded to 256: S, dQ and dK take the MXU passes of a 256-deep head, 3
+  passes a pair forward for the 2.5 that 192 + 128 would need and 8
+  backward for 6.5. Nothing else: no padded V, no second S.
 
 Forward (``_fa_kernel``): grid (batch*heads, q-blocks, k-blocks), the
 LAST axis sequential so VMEM scratch carries the running max /
@@ -61,6 +73,11 @@ of 197 TFLOP/s on the FLOPs attention REQUIRES; before -> after):
 - 2 x 8,192, 16 over 2 heads of 256 (``qwen3-next-80b-a3b``): forward
   10.488 -> 7.367 ms (53.2 -> 75.8%), backward 24.840 -> 15.008 ms
   (44.9 -> 74.4%)
+- 2 x 8,192, 32 heads with scores of 192 and values of 128
+  (``kanana-2-30b-a3b``; PR 35, new — required FLOPs ``2 pairs (192 +
+  128)`` a head forward, twice that backward): forward 12.98 ms (53.8%;
+  13.30 with the diagonal tile whole), backward 24.64 ms (56.6%); with
+  their layout copies 15.7 and 29.6 ms a call
 The backward runs at 85–96% of the MXU's rate on the products it
 executes (half the rate at head size 64, whose products are 64 deep or
 wide); what is left in the forward is the VPU's share of a tile, which
@@ -89,7 +106,8 @@ NEG_INF = -1e30
 
 def reference_attention(q, k, v, causal: bool = True,
                         scale: Optional[float] = None):
-    """Dense softmax attention over [B, T, H, D] — the numerics the
+    """Dense softmax attention over q, k [B, T, H, Dqk] and v
+    [B, T, H, Dv] (the two head sizes may differ) — the numerics the
     kernel must reproduce, and the fallback/backward path."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     group = q.shape[2] // k.shape[2]
@@ -190,19 +208,25 @@ def _last_live_k(i_q):
     return i_q
 
 
-def _strips(products: int, d: int) -> int:
+def _strips(products: int, d: int, dv: int) -> int:
     """Strips a diagonal tile is walked in, from the MXU passes a pair
-    costs the kernel (its products x the 128-deep passes a head of
-    ``d`` takes). A strip saves the products above the diagonal and
+    costs the kernel: of its products the larger half (S forward; S, dK
+    and dQ backward) is as deep or wide as the score head ``d``, the
+    rest (P V; dP and dV) as the value head ``dv``, each in 128-deep
+    passes. A strip saves the products above the diagonal and
     pays for it in short operand streams and in K transposed once a
     strip, so it pays where the MXU binds. On the chip (PR 34, the
     kernels alone, ms at 1 / 2 / 4 strips a 1024-tile): the forward at
     head size 128 (4 x 2,048 x 16 heads) 0.770 / 0.875 / 0.910, at 64
     (2 x 8,192 x 32) 9.45 / 9.86 / 9.99, at 256 (2 x 8,192 x 16) 7.74 /
     7.37 / 7.44; the backward 1.438 / 1.217 / 1.126, 16.89 / 16.00 /
-    15.64 and 16.24 / 15.36 / 14.94."""
-    work = products * -(-d // 128)
-    return 1 if work < 4 else 2 if work < 5 else 4
+    15.64 and 16.24 / 15.36 / 14.94. PR 35, score heads of 192 over
+    value heads of 128 (2 x 8,192 x 32; 3 passes a pair forward, 8
+    backward): the forward 13.30 / 12.98 / 12.98, the backward 26.39 /
+    25.09 / 24.64 — two strips forward from 3 passes on."""
+    work = -(-products // 2) * (_lanes(d) // 128) \
+        + products // 2 * (_lanes(dv) // 128)
+    return 1 if work < 3 else 2 if work < 5 else 4
 
 
 def _diagonal_strips(block: int, strips: int):
@@ -326,12 +350,15 @@ def flash_attention_forward(q, k, v, causal: bool = True,
                             block_q: int = 1024, block_k: int = 1024,
                             interpret: bool = False,
                             with_lse: bool = False):
-    """Pallas forward over q [B, T, H, D] and k, v [B, T, Hkv, D]
-    (grouped-query attention where Hkv < H: query head ``i`` reads
-    key-value head ``i // (H / Hkv)``). T must divide by both block
+    """Pallas forward over q [B, T, H, D], k [B, T, Hkv, D] and v
+    [B, T, Hkv, Dv] — the value head may be of another size than the
+    score head, the result is [B, T, H, Dv] — (grouped-query attention
+    where Hkv < H: query head ``i`` reads key-value head
+    ``i // (H / Hkv)``). T must divide by both block
     sizes (caller falls back to dense otherwise). ``with_lse`` also
     returns the per-row logsumexp [B, H, T] the fused backward needs."""
     b, t, h, d = q.shape
+    d_v = v.shape[-1]
     group = _kv_group(q, k)
     scale = scale if scale is not None else d ** -0.5
     block_q, block_k = _blocks(t, block_q, block_k, causal)
@@ -341,15 +368,15 @@ def flash_attention_forward(q, k, v, causal: bool = True,
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, strips=_strips(2, d),
+        block_k=block_k, n_k=n_k, strips=_strips(2, d, d_v),
         emit_lse=with_lse)
 
     # causal dead-tile DMA elision for the streamed k/v operands (see
     # _causal_kv_ix)
     kv_ix = _causal_kv_ix(causal, group)
 
-    out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d),
+    out_shape = [jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, d_v),
                               lambda bh, iq, ik: (bh, iq, 0))]
     if with_lse:
         # lse is only materialised when the caller needs residuals —
@@ -366,20 +393,21 @@ def flash_attention_forward(q, k, v, causal: bool = True,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, block_k, d), kv_ix),
-            pl.BlockSpec((1, block_k, d), kv_ix),
+            pl.BlockSpec((1, block_k, d_v), kv_ix),
         ],
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((block_q, 128), jnp.float32),   # normaliser
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accum
+            pltpu.VMEM((block_q, d_v), jnp.float32),   # output accum
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary'),
             # resident: the K and V tiles, double-buffered
             vmem_limit_bytes=_vmem_limit(
-                4 * block_k * _lanes(d) * q.dtype.itemsize, block_q,
-                block_k, d, q.dtype.itemsize)),
+                2 * block_k * (_lanes(d) + _lanes(d_v))
+                * q.dtype.itemsize, block_q, block_k, d, d_v,
+                q.dtype.itemsize)),
         interpret=interpret,
     )(qf, kf, vf)
 
@@ -478,34 +506,37 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 RESIDENT_BYTES = 64 << 20
 
 
-def _resident_bytes(span: int, d: int, itemsize: int) -> int:
+def _resident_bytes(span: int, d: int, dv: int, itemsize: int) -> int:
     """VMEM that ``span`` keys of one key-value head hold through the
-    backward: K, V and the dK, dV blocks (each double-buffered by the
-    pipeline) and the two float32 accumulators, lanes padded to 128."""
-    return span * _lanes(d) * (8 * itemsize + 2 * 4)
+    backward: K and the dK block at the score head's width, V and the
+    dV block at the value head's (each double-buffered by the pipeline)
+    and the two float32 accumulators, lanes padded to 128."""
+    return span * (_lanes(d) + _lanes(dv)) * (4 * itemsize + 4)
 
 
-def _span(t: int, block_k: int, d: int, itemsize: int) -> int:
+def _span(t: int, block_k: int, d: int, dv: int, itemsize: int) -> int:
     """Keys the backward holds at once: the whole sequence where it
     fits ``RESIDENT_BYTES`` (every shape the cells run), else its
     largest part in whole k-blocks that does."""
     n_k = t // block_k
     for spans in range(1, n_k + 1):
         if n_k % spans == 0 and _resident_bytes(
-                t // spans, d, itemsize) <= RESIDENT_BYTES:
+                t // spans, d, dv, itemsize) <= RESIDENT_BYTES:
             return t // spans
     return block_k
 
 
-def _vmem_limit(resident, block_q, block_k, d, itemsize) -> int:
+def _vmem_limit(resident, block_q, block_k, d, dv, itemsize) -> int:
     """A kernel's scoped-VMEM limit from its shapes (the default, 16 MB,
     holds neither a 1024 x 1024 tile at head size 256 nor a resident
-    head): what stays through the walk, the q-side tiles (q, O, dO and
-    the result block double-buffered, the lane-tiled row statistics,
-    a float32 accumulator), eight score-sized float32 temporaries (S,
-    P, dP, dS, their rounded copies and the transposes the MXU is
-    fed), and a quarter on top."""
-    tiles = block_q * (_lanes(d) * (8 * itemsize + 4) + 4 * 128 * 4)
+    head): what stays through the walk, the q-side tiles (q and the dq
+    block at the score head's width, O and dO at the value head's,
+    double-buffered, the lane-tiled row statistics, a float32
+    accumulator at the wider of the two), eight score-sized float32
+    temporaries (S, P, dP, dS, their rounded copies and the transposes
+    the MXU is fed), and a quarter on top."""
+    tiles = block_q * ((_lanes(d) + _lanes(dv)) * 4 * itemsize
+                       + max(_lanes(d), _lanes(dv)) * 4 + 4 * 128 * 4)
     need = resident + tiles + 8 * block_q * block_k * 4
     return min(max(need + need // 4, 32 << 20), 110 << 20)
 
@@ -524,12 +555,13 @@ def flash_attention_backward(q, k, v, out, lse, do,
     a tile). A sequence too long to stay (``_span``) goes through in
     spans, each adding a float32 partial of dQ that is summed here."""
     b, t, h, d = q.shape
+    d_v = v.shape[-1]
     group = _kv_group(q, k)
     h_kv = h // group
     scale = scale if scale is not None else d ** -0.5
     block_q, block_k = _blocks(t, block_q, block_k, causal)
     n_q = t // block_q
-    span = _span(t, block_k, d, q.dtype.itemsize)
+    span = _span(t, block_k, d, d_v, q.dtype.itemsize)
     spans = t // span
 
     qf, kf, vf, of, dof = (_fold(x) for x in (q, k, v, out, do))
@@ -544,38 +576,41 @@ def flash_attention_backward(q, k, v, out, lse, do,
     def q_ix(bh, s, j):
         return (bh * group + j // n_q, j % n_q, 0)
 
+    # q and dq at the score head's width, O and dO at the value head's
     q_spec = pl.BlockSpec((1, block_q, d), q_ix)
+    o_spec = pl.BlockSpec((1, block_q, d_v), q_ix)
     row_spec = pl.BlockSpec((1, block_q, 128), q_ix)
-    kv_spec = pl.BlockSpec((1, span, d), lambda bh, s, j: (bh, s, 0))
+    k_spec = pl.BlockSpec((1, span, d), lambda bh, s, j: (bh, s, 0))
+    v_spec = pl.BlockSpec((1, span, d_v), lambda bh, s, j: (bh, s, 0))
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_q=n_q,
-                          n_k=span // block_k, strips=_strips(5, d)),
+                          n_k=span // block_k, strips=_strips(5, d, d_v)),
         out_shape=[
             # one partial of dq a span; a single span's is dq itself
             jax.ShapeDtypeStruct(
                 (spans, b * h, t, d),
                 q.dtype if spans == 1 else jnp.float32),
             jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, t, d_v), v.dtype),
         ],
         grid=(b * h_kv, spans, group * n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bh, s, j: (s,) + q_ix(bh, s, j)),
-            kv_spec, kv_spec,
+            k_spec, v_spec,
         ],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((span, d), jnp.float32),
-                        pltpu.VMEM((span, d), jnp.float32),
+                        pltpu.VMEM((span, d_v), jnp.float32),
                         pltpu.VMEM((block_q, 128), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
             vmem_limit_bytes=_vmem_limit(
-                _resident_bytes(span, d, q.dtype.itemsize), block_q,
-                block_k, d, q.dtype.itemsize)),
+                _resident_bytes(span, d, d_v, q.dtype.itemsize), block_q,
+                block_k, d, d_v, q.dtype.itemsize)),
         interpret=interpret,
     )(qf, kf, vf, of, dof, lsef)
     dq = dq[0] if spans == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
@@ -594,6 +629,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
     jnp alternative for non-Pallas platforms (the headline BENCH
     comparison is against dense+remat attention, not this path)."""
     b, t, h, d = q.shape
+    d_v = v.shape[-1]        # the value head, which the result has
     scale = scale if scale is not None else d ** -0.5
     block_k = _fit_block(t, block_k) if t % 128 == 0 else t
     n_k = t // block_k
@@ -602,7 +638,8 @@ def blockwise_attention(q, k, v, causal: bool = True,
     kf = jnp.transpose(k, (0, 2, 1, 3)).astype(jnp.float32)
     vf = jnp.transpose(v, (0, 2, 1, 3)).astype(jnp.float32)
     k_blocks = kf.reshape(b, h, n_k, block_k, d).transpose(2, 0, 1, 3, 4)
-    v_blocks = vf.reshape(b, h, n_k, block_k, d).transpose(2, 0, 1, 3, 4)
+    v_blocks = vf.reshape(
+        b, h, n_k, block_k, d_v).transpose(2, 0, 1, 3, 4)
 
     q_pos = lax.broadcasted_iota(jnp.int32, (t, block_k), 0)
 
@@ -626,7 +663,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
 
     m0 = jnp.full((b, h, t), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, t), jnp.float32)
-    acc0 = jnp.zeros((b, h, t, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, t, d_v), jnp.float32)
     (m, l, acc), _ = lax.scan(
         block, (m0, l0, acc0),
         (k_blocks, v_blocks, jnp.arange(n_k)))
@@ -664,7 +701,8 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 def fused_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, impl: str = 'auto'):
-    """Attention over [B, T, H, D] with implementation selection:
+    """Attention over q, k [B, T, H, Dqk] and v [B, T, Hkv, Dv] (the
+    result is [B, T, H, Dv]) with implementation selection:
 
     - ``pallas``: the fused kernel (TPU)
     - ``interpret``: the kernel under the Pallas interpreter (tests)

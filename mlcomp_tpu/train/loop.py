@@ -148,6 +148,7 @@ STEP_COUNTERS = {
     'moe.dropped': jnp.sum,
     'gated_delta.chunks': jnp.sum,
     'short_conv.rows': jnp.sum,
+    'mla_attn.rows': jnp.sum,
 }
 
 

@@ -1,5 +1,5 @@
 """Time the flash-attention kernels alone on the chip, at the shapes of
-the three token cells of ``BENCHMARK.json``.
+the four token cells of ``BENCHMARK.json``.
 
     python scripts/flash_probe.py [--module other/flash_attention.py]
                                   [--strips 1,2,4] [--block 512,1024]
@@ -8,7 +8,8 @@ Prints one JSON line a (shape, variant): forward (with lse) and backward
 in ms — the wall time of the call with its layout copies, and the Pallas
 kernels' own device time from a trace — and the latter as a share of
 197 TFLOP/s on the FLOPs causal attention REQUIRES (2 products forward,
-4 backward over T (T + 1) / 2 pairs), so two commits' lines compare.
+4 backward over T (T + 1) / 2 pairs, one of a pair as deep as the score
+head and one as wide as the value head), so two commits' lines compare.
 ``--module`` times another checkout's file (the parent's, unpacked
 beside this one) on the same chip; ``--strips`` and ``--block`` sweep
 the strips a diagonal tile is walked in and the tile, by overriding the
@@ -32,12 +33,16 @@ import jax.numpy as jnp  # noqa: E402
 
 PEAK = 197e12
 REPS = 20
-# (batch, tokens, query heads, key-value heads, head size)
+# (batch, tokens, query heads, key-value heads, score head size, value
+# head size)
 SHAPES = {
-    'olmo-1b': (4, 2048, 16, 16, 128),
-    'lfm2-8b-a1b': (2, 8192, 32, 8, 64),
-    'qwen3-next-80b-a3b': (2, 8192, 16, 2, 256),
-    'tiny': (1, 256, 2, 1, 128),      # the CPU rehearsal (interpret mode)
+    'olmo-1b': (4, 2048, 16, 16, 128, 128),
+    'lfm2-8b-a1b': (2, 8192, 32, 8, 64, 64),
+    'qwen3-next-80b-a3b': (2, 8192, 16, 2, 256, 256),
+    'kanana-2-30b-a3b': (2, 8192, 32, 32, 192, 128),
+    # the CPU rehearsals (interpret mode)
+    'tiny': (1, 256, 2, 1, 128, 128),
+    'tiny-unequal': (1, 256, 2, 2, 192, 128),
 }
 
 
@@ -102,24 +107,25 @@ def main():
     ap.add_argument('--module', default='')
     ap.add_argument('--strips', default='')
     ap.add_argument('--block', default='')
-    ap.add_argument('--shapes', default=','.join(list(SHAPES)[:3]))
+    ap.add_argument('--shapes', default=','.join(list(SHAPES)[:4]))
     args = ap.parse_args()
     fa = load(args.module)
     rule = getattr(fa, '_strips', None)
     counts = [int(x) for x in args.strips.split(',') if x] or [None]
     blocks = [int(x) for x in args.block.split(',') if x] or [None]
     for name in args.shapes.split(','):
-        b, t, h, h_kv, d = SHAPES[name]
+        b, t, h, h_kv, d, dv = SHAPES[name]
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
-        q, do = (jax.random.normal(kk, (b, t, h, d), jnp.bfloat16)
-                 for kk in ks[:2])
-        k, v = (jax.random.normal(kk, (b, t, h_kv, d), jnp.bfloat16)
-                for kk in ks[2:])
-        need = 2 * 2 * (t * (t + 1) // 2) * d * b * h   # forward FLOPs
+        q, k, v, do = (
+            jax.random.normal(kk, (b, t, heads, width), jnp.bfloat16)
+            for kk, heads, width in zip(ks, (h, h_kv, h_kv, h),
+                                        (d, d, dv, dv)))
+        # forward FLOPs
+        need = 2 * (t * (t + 1) // 2) * (d + dv) * b * h
         for block in blocks:
             for strips in counts:
                 if strips is not None:
-                    fa._strips = lambda _products, _d, n=strips: n
+                    fa._strips = lambda *_sizes, n=strips: n
                 elif rule is not None:
                     fa._strips = rule
                 kw = {'interpret': jax.default_backend() != 'tpu'}

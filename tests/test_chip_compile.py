@@ -86,8 +86,8 @@ def test_flash_attention_backward_in_spans(one_chip):
     accumulators are 201 MB): the same kernel takes it in spans, each
     span's share of dq a float32 partial."""
     from mlcomp_tpu.ops import flash_attention as fa
-    assert fa._span(32768, 1024, 256, 2) == 8192
-    assert fa._span(8192, 1024, 256, 2) == 8192
+    assert fa._span(32768, 1024, 256, 256, 2) == 8192
+    assert fa._span(8192, 1024, 256, 256, 2) == 8192
     shapes = [((1, 32768, 8, 256), jnp.bfloat16),
               ((1, 32768, 1, 256), jnp.bfloat16),
               ((1, 32768, 1, 256), jnp.bfloat16)]
@@ -367,4 +367,68 @@ def test_lfm2_moe_remat_holds_the_kernels_results(one_chip, kind):
     # gate, up, down once; three for the rows' gradients, three for the
     # weights'
     assert kernels['gmm'] == 6 and kernels['tgmm'] == 3, kernels
+    assert '' not in kernels        # no kernel but these
+
+
+# ---- the kernels of deepseek_v3 at the published widths (PR 35)
+@pytest.mark.parametrize('grad', [False, True], ids=['fwd', 'fwd_bwd'])
+def test_latent_attention_flash_at_192_and_128(one_chip, grad):
+    """32 heads whose scores are 192 deep (one and a half lane tiles)
+    and whose values are 128 wide, at 2 x 8,192 tokens: what a step of
+    ``kanana-2-30b-a3b.steady`` hands the two flash kernels."""
+    shapes = [((2, 8192, 32, 192), jnp.bfloat16),
+              ((2, 8192, 32, 192), jnp.bfloat16),
+              ((2, 8192, 32, 128), jnp.bfloat16)]
+    assert _compile(_flash(grad), one_chip, *shapes) == (2 if grad else 1)
+
+
+@pytest.mark.parametrize('sparse', [False, True], ids=['dense', 'sparse'])
+def test_deepseek_v3_remat_holds_the_kernels_results(one_chip, sparse):
+    """``jax.grad`` of one `remat`ted layer of ``kanana-2-30b-a3b.steady``
+    at its widths and 2 x 8,192 tokens: with the save-by-name policy
+    (``models/deepseek_v3.py`` ``REMAT_SAVED``) the backward pass runs no
+    forward kernel again — not the flash forward, not one grouped
+    product — and both flash kernels carry the scope ``mla_attn``. A
+    step of the cell is the dense layer and four sparse ones: 10 + 36 =
+    46 kernel calls (``step.kernel_calls``, ``PERF.md`` section 3)."""
+    import collections
+    import json
+    import re
+
+    import flax
+    from mlcomp_tpu.models import create_model, deepseek_v3
+    from mlcomp_tpu.models.decoder_parts import remat_saving
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, 'benchmark/configs/kanana-2-30b-a3b.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = create_model(**dict(
+        kwargs, attn_impl='pallas', moe_impl='gmm')).cfg
+    assert cfg.remat and cfg.n_layers - cfg.n_dense_layers == 4
+    layer = remat_saving(deepseek_v3.DeepseekV3Layer, True,
+                         deepseek_v3.REMAT_SAVED)(cfg, sparse)
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x)['params']))
+
+    def loss(p, x):
+        y = layer.apply({'params': p}, x, mutable=['intermediates'])[0]
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = collections.Counter(
+        re.search(r'mla_attn|tgmm|gmm|$', re.search(
+            r'op_name="([^"]*)"', line).group(1)).group(0)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels['mla_attn'] == 2         # forward, one backward
+    # gate, up, down once; three for the rows' gradients, three for the
+    # weights' (768 wide: a tile of 512 and a padded half)
+    assert (kernels['gmm'], kernels['tgmm']) == ((6, 3) if sparse
+                                                 else (0, 0)), kernels
     assert '' not in kernels        # no kernel but these

@@ -192,3 +192,63 @@ class TestExpertParallel:
                 f'Upstream partitioner limitation: batch-sharded '
                 f'activations vs the transposed fsdp device order; '
                 f'numerics pinned by test_ep_training_matches_dp.')
+
+
+# -- PR 35: ``SparseMoe``'s shared expert with and without its gate
+class TestSharedExpert:
+    def _apply(self, shared_gate, d_shared=16):
+        import jax
+        import jax.numpy as jnp
+        from flax.core import meta
+        from mlcomp_tpu.models.decoder_parts import MoeConfig, SparseMoe
+        cfg = MoeConfig(d_model=32, d_expert=8, n_experts=8, top_k=2,
+                        d_shared=d_shared, shared_gate=shared_gate,
+                        dtype='float32', moe_impl='ragged')
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 32))
+        module = SparseMoe(cfg)
+        params = meta.unbox(module.init(jax.random.PRNGKey(1), x)['params'])
+        return module, params, x, jnp
+
+    def test_ungated_is_the_routed_part_plus_the_shared_expert(self):
+        """``shared_gate: False`` (DeepSeek-V3): no ``shared_gate`` leaf,
+        and the layer is its routed part plus ``Shared(x)`` as it is."""
+        import jax
+        from mlcomp_tpu.models.transformer import (
+            MlpBlock, TransformerConfig)
+        module, params, x, jnp = self._apply(False)
+        assert 'shared' in params and 'shared_gate' not in params
+        y = module.apply({'params': params}, x,
+                         mutable=['intermediates'])[0]
+        routed_only, routed_params, _, _ = self._apply(False, d_shared=0)
+        routed = routed_only.apply(
+            {'params': {k: v for k, v in params.items() if k != 'shared'}},
+            x, mutable=['intermediates'])[0]
+        assert set(routed_params) == set(params) - {'shared'}
+        shared = MlpBlock(TransformerConfig(
+            d_model=32, d_ff=16, dtype='float32')).apply(
+            {'params': params['shared']}, x)
+        assert float(jnp.abs(shared).max()) > 1e-3
+        np.testing.assert_allclose(y, routed + shared, rtol=1e-5,
+                                   atol=1e-6)
+        # every leaf of the shared expert gets a gradient
+        grads = jax.grad(lambda p: module.apply(
+            {'params': p}, x, mutable=['intermediates'])[0].sum())(params)
+        for leaf in jax.tree.leaves(grads['shared']):
+            assert float(jnp.abs(leaf).max()) > 0
+
+    def test_gated_is_the_default_and_differs(self):
+        """qwen's form stays the default: a ``shared_gate`` leaf, the
+        shared expert times its sigmoid."""
+        from mlcomp_tpu.models.decoder_parts import MoeConfig
+        assert MoeConfig(d_model=1, d_expert=1, n_experts=1,
+                         top_k=1).shared_gate is True
+        module, params, x, jnp = self._apply(True)
+        assert params['shared_gate']['kernel'].shape == (32, 1)
+        gated = module.apply({'params': params}, x,
+                             mutable=['intermediates'])[0]
+        plain, _, _, _ = self._apply(False)
+        ungated = plain.apply(
+            {'params': {k: v for k, v in params.items()
+                        if k != 'shared_gate'}},
+            x, mutable=['intermediates'])[0]
+        assert float(jnp.abs(gated - ungated).max()) > 1e-3

@@ -218,7 +218,7 @@ def test_fused_flash_backward_against_dense(h, h_kv, d, causal, t, block):
     import jax
     from mlcomp_tpu.ops import flash_attention as fa
     if causal:      # the backward's walk, from its own rule
-        strips = fa._diagonal_strips(block, fa._strips(5, d))
+        strips = fa._diagonal_strips(block, fa._strips(5, d, d))
         assert len(strips) == {512: 4, 256: 2, 128: 1}[block]
     q, k, v, do = _heads(h, h_kv, t, d)
     want, pull = jax.vjp(functools.partial(
@@ -245,8 +245,8 @@ def test_fused_flash_backward_in_spans(monkeypatch, causal, spans):
     from mlcomp_tpu.ops import flash_attention as fa
     t, d, block = 512, 64, 128
     monkeypatch.setattr(fa, 'RESIDENT_BYTES',
-                        fa._resident_bytes(t // spans, d, 4))
-    assert fa._span(t, block, d, 4) == t // spans
+                        fa._resident_bytes(t // spans, d, d, 4))
+    assert fa._span(t, block, d, d, 4) == t // spans
     q, k, v, do = _heads(4, 2, t, d)
     _, pull = jax.vjp(functools.partial(
         reference_attention, causal=causal), q, k, v)
@@ -273,11 +273,11 @@ def test_pairs_the_walk_executes(t, d, most):
     need = t * (t + 1) // 2
     n = t // block
     whole = n * (n + 1) // 2 * block * block    # every live tile whole
-    assert fa._strips(5, d) == 4
-    pairs = fa.executed_pairs(t, block, fa._strips(5, d), True)
+    assert fa._strips(5, d, d) == 4
+    pairs = fa.executed_pairs(t, block, fa._strips(5, d, d), True)
     assert need <= pairs <= most * need and pairs < whole
-    forward = fa.executed_pairs(t, block, fa._strips(2, d), True)
-    assert fa._strips(2, d) == (2 if d > 128 else 1)
+    forward = fa.executed_pairs(t, block, fa._strips(2, d, d), True)
+    assert fa._strips(2, d, d) == (2 if d > 128 else 1)
     assert pairs <= forward <= whole and (forward < whole) == (d > 128)
     # the strips cover a diagonal tile's lower triangle exactly once
     for strips in (1, 2, 4, 8):
@@ -302,6 +302,98 @@ def test_causal_tiles_are_square():
             for b in (128, 256, 384, 512, 640, 1024)] == [1, 2, 3, 4, 5, 4]
     assert fa._diagonal_strips(1024, 2) == [(0, 512, 512), (512, 512, 1024)]
     assert fa._diagonal_strips(1024, 1) == [(0, 1024, 1024)]
+
+
+# -- PR 35: a score head that is not the value head (latent attention)
+def _unequal_heads(h, h_kv, t, d, dv, seed=5):
+    import jax
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (1, t, h, d)),
+            jax.random.normal(ks[1], (1, t, h_kv, d)),
+            jax.random.normal(ks[2], (1, t, h_kv, dv)),
+            jax.random.normal(ks[3], (1, t, h, dv)))
+
+
+UNEQUAL = [(2, 2, 192, 128), (2, 1, 24, 8), (2, 2, 64, 128)]
+UNEQUAL_IDS = ['mla_192_128', 'tiny_24_8_grouped', 'value_wider_64_128']
+
+
+@pytest.mark.parametrize('t,block', [(512, 256), (256, 128), (128, 128)],
+                         ids=['t512_tile256', 't256_tile128', 't128'])
+@pytest.mark.parametrize('causal', [True, False], ids=['causal', 'full'])
+@pytest.mark.parametrize('h,h_kv,d,dv', UNEQUAL, ids=UNEQUAL_IDS)
+def test_flash_with_unequal_head_sizes_against_dense(h, h_kv, d, dv,
+                                                     causal, t, block):
+    """q, k [.., d] and v, o, do [.., dv]: the forward, dq and dk at the
+    score head's width and dv at the value head's, against autodiff of
+    the dense form; the scale is the score head's."""
+    import functools
+
+    import jax
+    from mlcomp_tpu.ops import flash_attention as fa
+    q, k, v, do = _unequal_heads(h, h_kv, t, d, dv)
+    want, pull = jax.vjp(functools.partial(
+        reference_attention, causal=causal), q, k, v)
+    assert want.shape == (1, t, h, dv)
+    kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
+    out, lse = fa.flash_attention_forward(q, k, v, with_lse=True, **kw)
+    assert out.shape == want.shape and _rel(out, want) < 1e-5
+    grads = fa.flash_attention_backward(q, k, v, out, lse, do, **kw)
+    for name, a, b, like in zip('dq dk dv'.split(), grads, pull(do),
+                                (q, k, v)):
+        assert a.shape == b.shape == like.shape and a.dtype == b.dtype
+        assert _rel(a, b) < 1e-5, name
+
+
+@pytest.mark.parametrize('spans', [1, 2])
+def test_fused_attention_with_unequal_heads_in_forced_spans(monkeypatch,
+                                                            spans):
+    """``fused_attention(impl='interpret')`` at 192 / 128 under
+    ``jax.grad``, the backward's keys in one span and forced into two:
+    all three gradients against the dense form's, and the pure-jnp
+    twin (``blockwise_attention``) beside them."""
+    import jax
+    import jax.numpy as jnp
+    from mlcomp_tpu.ops import flash_attention as fa
+    t, d, dv = 2048, 192, 128
+    monkeypatch.setattr(fa, 'RESIDENT_BYTES',
+                        fa._resident_bytes(t // spans, d, dv, 4))
+    assert fa._span(t, 1024, d, dv, 4) == t // spans
+    q, k, v, do = _unequal_heads(1, 1, t, d, dv)
+
+    def loss(impl):
+        return lambda q, k, v: jnp.sum(do * fused_attention(
+            q, k, v, causal=True, impl=impl))
+
+    got = jax.grad(loss('interpret'), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss('dense'), (0, 1, 2))(q, k, v)
+    for name, a, b in zip('dq dk dv'.split(), got, want):
+        assert a.shape == b.shape and _rel(a, b) < 1e-5, name
+    assert _rel(fa.blockwise_attention(q, k, v),
+                reference_attention(q, k, v)) < 1e-5
+
+
+def test_what_the_kernels_do_beyond_the_required_pairs_at_192_128():
+    """At 2 x 8,192 tokens with heads of 192 / 128 the walk is the equal
+    heads' but for the forward's diagonal tile, which runs in two
+    strips from 3 MXU passes a pair on (equal heads take 2 or 4; the
+    backward's runs in four), and the lanes of the score head pad to
+    256 in VMEM; V is 128 wide everywhere."""
+    from mlcomp_tpu.ops import flash_attention as fa
+    t, d, dv = 8192, 192, 128
+    assert (fa._lanes(d), fa._lanes(dv)) == (256, 128)
+    assert fa._strips(2, d, dv) == 2 and fa._strips(5, d, dv) == 4
+    # the equal heads' rule is what it was
+    assert [fa._strips(2, e, e) for e in (64, 128, 256)] == [1, 1, 2]
+    assert [fa._strips(5, e, e) for e in (64, 128, 256)] == [4, 4, 4]
+    need = t * (t + 1) // 2
+    assert fa.executed_pairs(t, 1024, 2, True) / need \
+        == pytest.approx(1.0625, abs=1e-3)
+    assert fa.executed_pairs(t, 1024, 4, True) / need < 1.04
+    # a whole head's K, dK (256 lanes) and V, dV (128) stay resident
+    assert fa._resident_bytes(t, d, dv, 2) == t * 384 * 12
+    assert fa._span(t, 1024, d, dv, 2) == t
+    assert fa._resident_bytes(t, 128, 128, 2) == t * 128 * 24
 
 
 class TestFusedCE:

@@ -141,7 +141,8 @@ def test_counters_leave_the_step():
                            self_supervised=True)
     _, metrics = step(state, tokens, None)
     # every counter but the one only `lfm2_moe`'s conv mixer sows
-    assert set(STEP_COUNTERS) - {'short_conv.rows'} <= set(metrics)
+    assert set(STEP_COUNTERS) - {'short_conv.rows', 'mla_attn.rows'} \
+        <= set(metrics)
     assert 'short_conv.rows' not in metrics
     assert float(metrics['moe.dropped']) == 0
     # three linear layers of 2 sequences x 4 heads x 2 chunks of 16

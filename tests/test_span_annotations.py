@@ -235,6 +235,43 @@ def test_the_introspection_counts_the_steps_kernels(session, tmp_path,
     assert job('two') == [2.0]
 
 
+def test_a_deepseek_v3_job_writes_its_counters_and_the_kernel_gauge(
+        session, tmp_path):
+    """The latent-attention decoder through ``JaxTrain``: a row an epoch
+    of ``mla_attn.rows`` (tokens x attention layers a step) and of the
+    three ``moe.*`` series, and ONE ``step.kernel_calls`` gauge a job —
+    0 on the CPU; on the chip the step of ``kanana-2-30b-a3b.steady``
+    holds 46 (``tests/test_chip_compile.py`` counts a layer's: 2 flash
+    calls, and 9 grouped products in a sparse layer; ``PERF.md``
+    section 3)."""
+    from mlcomp_tpu.db.providers.telemetry import MetricProvider
+    from mlcomp_tpu.train import JaxTrain
+    task = make_task(session)
+    ex = JaxTrain(
+        model={'name': 'deepseek_v3', 'vocab_size': 64, 'd_model': 32,
+               'n_layers': 3, 'n_dense_layers': 1, 'd_ff': 48,
+               'n_heads': 2, 'kv_lora_rank': 16, 'qk_nope_head_dim': 8,
+               'qk_rope_head_dim': 4, 'v_head_dim': 8, 'n_experts': 8,
+               'top_k': 2, 'd_expert': 8, 'experts_held': 4,
+               'expert_bias_update_rate': 0.001, 'dtype': 'float32'},
+        dataset={'name': 'synthetic_lm', 'n_train': 32, 'n_valid': 8,
+                 'seq_len': 16, 'vocab_size': 64},
+        loss='lm_ce', batch_size=4, epochs=2, mesh={'dp': 1},
+        checkpoint_dir=str(tmp_path / 'ck'), checkpoint_every=0,
+        telemetry={'cost_analysis': True})
+    ex.step, ex.task, ex.session = QuietStep(), task, session
+    ex.dag, ex.additional_info = DagProvider(session).by_id(task.dag), {}
+    ex.work()
+    values = lambda name: MetricProvider(session).recent_values(  # noqa: E731,E501
+        task.id, name)
+    assert values('step.kernel_calls') == [0.0]
+    assert values('mla_attn.rows') == [4 * 16 * 3.0] * 2
+    assert values('moe.dropped') == [0.0] * 2
+    assert len(values('moe.local_assign_share')) == 2
+    assert all(v >= 1 for v in values('moe.load_max_over_mean'))
+    assert values('short_conv.rows') == []
+
+
 def _planted(*args, **kwargs):
     raise RuntimeError('planted')
 
