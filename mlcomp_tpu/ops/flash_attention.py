@@ -17,7 +17,25 @@ What both kernels share:
   accumulation via preferred_element_type); P and dS round to the input
   dtype for the products they feed (standard flash practice, exact for
   f32 inputs); masked scores are ``NEG_INF``; the residual is the
-  logsumexp a row, lane-tiled.
+  logsumexp a query, [B*H, 1, T] (a row of tokens; PR 36 — it was
+  written lane-tiled, sliced, and broadcast to 128 lanes again for the
+  backward: 268 MB each way a layer at kanana's shape).
+- THE OPERANDS' LAYOUT (PR 36; ``_panels_for``, ``_folded``). q, k and
+  v enter as the projections lay them out, and are held so for the
+  backward (``flash_attn.qkv``): where a head's width is not a whole
+  number of 128s (64, 192) as PANELS [B*H, D, T] — the T-minor layout
+  XLA gives q_proj's, k_proj's and kv_b_proj's matmuls, so the fold is a
+  bitcast, and a panel pads no lane — else as ROWS [B*H, T, D], as a
+  fused qkv writes them (``olmo-1b``: panels there add 9 copies a
+  layer). O and dO are rows always; dq, dk, dv leave as their operands
+  came. Both orientations run the same kernel bodies: a panel q-block
+  is turned into rows once in VMEM (and dO into a panel in the
+  backward), every other product contracts the axis the block has
+  (``_tokens``, ``_mm``, ``_outer``): S = q kT and dK^T = qT dS are plain
+  products of panels, dQ = dS kT^T. The layout code runs inside the
+  scope ``LAYOUT_SCOPE``; the copies XLA cannot make bitcasts carry it
+  (``layout_copies``, the gauge ``step.flash_layout_copies``: a kanana
+  step holds 15, 3 a layer, where the parent held 45).
 - THE TILE WALK (``_walk_tile``, ``_diagonal_strips``). Causal tiles
   are square, so the diagonal crosses tile (i, i) corner to corner and
   no other. A tile under it runs whole with NO iota / compare / select;
@@ -35,10 +53,10 @@ What both kernels share:
   products) and of nothing else.
 - WHAT IS EXECUTED OVER THE REQUIRED PAIRS AT 192 / 128 (T = 8,192): the
   walk's x1.06 forward (the diagonal tile in two strips) and x1.03
-  backward, and, in VMEM only, the lanes of the 192-wide q, k, dq, dk
-  padded to 256: S, dQ and dK take the MXU passes of a 256-deep head, 3
-  passes a pair forward for the 2.5 that 192 + 128 would need and 8
-  backward for 6.5. Nothing else: no padded V, no second S.
+  backward, and, in VMEM only, the 192-deep contraction of S and the
+  192-wide rows of dQ padded to 256 lanes (dK^T streams 192 panel rows
+  and pads nothing since PR 36). Nothing else: no padded V, no second
+  S.
 
 Forward (``_fa_kernel``): grid (batch*heads, q-blocks, k-blocks), the
 LAST axis sequential so VMEM scratch carries the running max /
@@ -78,6 +96,13 @@ of 197 TFLOP/s on the FLOPs attention REQUIRES; before -> after):
   128)`` a head forward, twice that backward): forward 12.98 ms (53.8%;
   13.30 with the diagonal tile whole), backward 24.64 ms (56.6%); with
   their layout copies 15.7 and 29.6 ms a call
+PR 36 (my chip run, call 1; operands laid out as the cells' projections
+lay them, parent -> change): at kanana's shape, panels, forward 12.98 ->
+12.64 ms (55.2%), backward 24.64 -> 22.97 ms (60.8%), with the cell's
+copies 15.68 -> 13.11 and 29.57 -> 23.86 ms; at lfm2's, panels, forward
+9.46 -> 9.45, backward 15.77 -> 12.68 ms (44.0%), with copies 10.58 ->
+9.81 and 17.67 -> 13.40; qwen's and OLMo's (rows) within 3%, the lse
+row taking 0.1–0.2 ms of copies off each call.
 The backward runs at 85–96% of the MXU's rate on the products it
 executes (half the rate at head size 64, whose products are 64 deep or
 wide); what is left in the forward is the VPU's share of a tile, which
@@ -125,8 +150,57 @@ def reference_attention(q, k, v, causal: bool = True,
     return out.astype(q.dtype)
 
 
-def _scores(q, k, scale, masked_from=None):
-    """One strip of scaled scores in float32. ``masked_from`` is the
+# ------------------------------------------------- the operands' layout
+# q, k and v enter the kernels in one of two orientations, picked by the
+# head sizes alone (``_panels_for``): ROWS [B*H, T, D], a head's tokens
+# one row each, or PANELS [B*H, D, T], a head's [D, T] panel with the
+# tokens minor. ``tok`` is the token axis of such a block (0 rows, 1
+# panels). O and dO are rows in both; dq, dk and dv leave as their
+# operands came. The kernel bodies are the same for both: what differs
+# is the BlockSpecs, which axis each product contracts (``_tokens``,
+# ``_outer``, ``_mm``) and the q rows (and dO's panel) that a panel
+# q-block is turned into once in VMEM.
+
+def _panels_for(d: int, dv: int) -> bool:
+    """Whether q, k, v go in as panels: where a head's rows would pad
+    lanes (a width that is not a whole number of 128s: 64, 192). XLA
+    lays a projection's [B, T, H, D] output out as panels there, so a
+    panel is a bitcast of it where a row is a copy; a row of 64 or 192
+    also pads to 128 or 256 lanes in HBM and VMEM, a panel does not.
+    Heads of whole 128s keep the rows a fused projection writes
+    (``olmo-1b``'s qkv), where panels would add the copies."""
+    return bool(d % 128 or dv % 128)
+
+
+def _tokens(ref, sl, tok: int):
+    """Tokens ``sl`` of a q, k or v block ``ref`` ([1, T, D] or
+    [1, D, T]), in the orientation it came."""
+    return ref[0, :, sl] if tok else ref[0, sl]
+
+
+def _mm(a, b, ca: int, cb: int, wide: bool):
+    """a x b over a's axis ``ca`` and b's ``cb``, in float32. ``wide``
+    (under the interpreter) gives the product float32 operands: the
+    same sums, since a product of two bf16 numbers is exact in float32,
+    which XLA:CPU runs in every form (it refuses bf16 operands for a
+    product it has folded a transpose into)."""
+    if wide:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _outer(x, g, tok: int, wide: bool):
+    """The gradient of a strip's keys from ``g`` [rows, cols] and the
+    strip's rows of ``x`` (q, or dO, in the operand's orientation): dK
+    [cols, D] = dS^T q for rows, dK^T [D, cols] = qT dS for panels."""
+    return _mm(x, g, 1, 0, wide) if tok else _mm(g, x, 0, 0, wide)
+
+
+def _scores(q, k, scale, tok, wide, masked_from=None):
+    """One strip of scaled scores in float32, q's rows against the keys
+    of ``k`` (rows: q kT^T; a panel: q kT, no transpose of K).
+    ``masked_from`` is the
     column of the strip's first row at which the causal mask starts
     (row ``r`` sees columns ``<= masked_from + r``): only a strip the
     diagonal crosses passes it, so a strip wholly under the diagonal
@@ -134,9 +208,7 @@ def _scores(q, k, scale, masked_from=None):
     # operands stay in the input dtype (bf16 for bf16 models): the MXU
     # multiplies bf16 pairs exactly and accumulates in f32 via
     # preferred_element_type
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+    s = _mm(q, k, 1, 1 - tok, wide) * scale
     if masked_from is not None:
         ahead = lax.broadcasted_iota(jnp.int32, s.shape, 1) \
             - lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -145,17 +217,20 @@ def _scores(q, k, scale, masked_from=None):
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
-               block_q, block_k, n_k, strips, emit_lse):
-    if emit_lse:
-        lse_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        m_scr, l_scr, acc_scr = rest
-        lse_ref = None
+               block_q, block_k, n_k, strips, emit_lse, tok, wide):
+    lse_ref = rest[0] if emit_lse else None
+    rest = rest[emit_lse:]
+    q_scr = rest[0] if tok else None
+    m_scr, l_scr, acc_scr = rest[tok:]
     i_q = pl.program_id(1)
     i_k = pl.program_id(2)
 
     @pl.when(i_k == 0)
     def _init():
+        if tok:
+            # a panel's rows, turned once a q-block and kept across the
+            # k loop
+            q_scr[:] = q_ref[0].T
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -164,8 +239,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
         """Online-softmax update of q rows ``[r0, r0 + rows)`` with the
         tile's first ``cols`` keys."""
         at = slice(r0, r0 + rows)
-        v = v_ref[0, :cols]
-        s = _scores(q_ref[0, at], k_ref[0, :cols], scale,
+        v = _tokens(v_ref, slice(0, cols), tok)
+        s = _scores(q_scr[at] if tok else q_ref[0, at],
+                    _tokens(k_ref, slice(0, cols), tok), scale, tok, wide,
                     cols - rows if masked else None)
         m_prev = m_scr[at, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -174,9 +250,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
         l_new = l_scr[at, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         # p rounds to the value dtype for the MXU (standard flash
         # practice; exact when inputs are f32)
-        acc_scr[at] = acc_scr[at] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_scr[at] = acc_scr[at] * corr + _mm(
+            p.astype(v.dtype), v, 1, tok, wide)
         m_scr[at] = jnp.broadcast_to(m_new, (rows, m_scr.shape[1]))
         l_scr[at] = jnp.broadcast_to(l_new, (rows, l_scr.shape[1]))
 
@@ -184,14 +259,13 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal,
 
     @pl.when(i_k == n_k - 1)
     def _finalise():
-        norm = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / norm).astype(o_ref.dtype)
+        norm = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / norm[:, :1]).astype(o_ref.dtype)
         if emit_lse:
-            # logsumexp per query row, replicated across the 128-lane
-            # dim (TPU blocks need (8, 128)-aligned trailing dims —
-            # the layout jax's own flash kernel uses for residuals)
-            lse_ref[0] = jnp.broadcast_to(
-                m_scr[:, :1] + jnp.log(norm[:, :1]), lse_ref.shape[1:])
+            # logsumexp a query row, written as a row of tokens: the
+            # lane-tiled column turned once a q-block, so the residual
+            # is [bh, 1, t], not 128 lanes of copies
+            lse_ref[0] = (m_scr[:] + jnp.log(norm)).T[:1]
 
 
 # ---------------------------------------------------------- the tile walk
@@ -280,30 +354,33 @@ def _kv_head(bh, group: int):
 
 
 def _causal_kv_ix(causal: bool, group: int = 1):
-    """Index map for operands streamed over k-blocks (grid order
-    (bh, iq, ik)). ``pl.when`` skips a masked block's COMPUTE but
-    Pallas still copies the tiles the index map names — half the K/V
+    """(head, token block) of the key and value blocks streamed over
+    k-blocks (grid order (bh, iq, ik)). ``pl.when`` skips a masked
+    block's COMPUTE but Pallas still copies the tiles the index map
+    names — half the K/V
     HBM traffic for nothing in causal attention. Clamping to the last
     live k-block makes every dead step re-name the tile already
     resident in VMEM, and Pallas elides copies whose block index is
     unchanged. Kernels read the TRUE ik from program_id, so masking
     and skip logic are unaffected."""
     if not causal:
-        return lambda bh, iq, ik: (_kv_head(bh, group), ik, 0)
+        return lambda bh, iq, ik: (_kv_head(bh, group), ik)
     return lambda bh, iq, ik: (
-        _kv_head(bh, group), jnp.minimum(ik, _last_live_k(iq)), 0)
+        _kv_head(bh, group), jnp.minimum(ik, _last_live_k(iq)))
 
 
 def _kv_group(q, k) -> int:
-    """Query heads a key-value head serves (1 = equal head counts)."""
-    h, h_kv = q.shape[2], k.shape[2]
+    """Query heads a key-value head serves (1 = equal head counts), of
+    folded operands [B*H, ...] and [B*Hkv, ...]."""
+    h, h_kv = q.shape[0], k.shape[0]
     if h % h_kv:
         raise ValueError(f'{h} query heads over {h_kv} key-value heads')
     return h // h_kv
 
 
 def _fold(x):
-    """[B, T, H, D] -> [B*H, T, D]: contiguous (seq, head_dim) tiles."""
+    """[B, T, H, D] -> [B*H, T, D]: a head's rows, as O and dO go in
+    and out."""
     b, t, h, d = x.shape
     return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
 
@@ -312,6 +389,43 @@ def _unfold(x, b: int):
     """[B*H, T, D] -> [B, T, H, D]."""
     _, t, d = x.shape
     return jnp.transpose(x.reshape(b, -1, t, d), (0, 2, 1, 3))
+
+
+def _panels(x):
+    """[B, T, H, D] -> [B*H, D, T]: a head's [D, T] panel, tokens minor,
+    as q, k, v and their gradients go in and out. It is the layout XLA
+    gives a projection's [B, T, H, D] output ({1,3,2,0}: per head a
+    [D, T] panel), so no copy stands between the producer and the
+    kernel, and a panel of 192 or 64 rows pads no lane where a row of
+    192 pads to 256."""
+    b, t, h, d = x.shape
+    return jnp.transpose(x, (0, 2, 3, 1)).reshape(b * h, d, t)
+
+
+def _unpanel(x, b: int):
+    """[B*H, D, T] -> [B, T, H, D]."""
+    _, d, t = x.shape
+    return jnp.transpose(x.reshape(b, -1, d, t), (0, 3, 1, 2))
+
+
+def _folded(x, panels: bool):
+    """q, k or v [B, T, H, D] as the kernels read it."""
+    return _panels(x) if panels else _fold(x)
+
+
+def _unfolded(x, b: int, panels: bool):
+    """A gradient as the kernel wrote it, back to [B, T, H, D]."""
+    return _unpanel(x, b) if panels else _unfold(x, b)
+
+
+def _spec(panels: bool, width: int, block: int, ix):
+    """BlockSpec of a q, k or v block of ``block`` tokens at head size
+    ``width``, whose (head, token block) the grid step's ``ix`` names."""
+    if panels:
+        return pl.BlockSpec((1, width, block),
+                            lambda *g: (ix(*g)[0], 0, ix(*g)[1]))
+    return pl.BlockSpec((1, block, width),
+                        lambda *g: (ix(*g)[0], ix(*g)[1], 0))
 
 
 def _lanes(d: int) -> int:
@@ -345,6 +459,62 @@ def _blocks(t: int, block_q: int, block_k: int, causal: bool):
     return block_q, block_k
 
 
+def _forward(qf, kf, vf, panels: bool, causal: bool, scale: float,
+             block_q: int, block_k: int, interpret: bool, with_lse: bool):
+    """The forward kernel over q [B*H, ..], k and v [B*Hkv, ..], folded
+    as ``panels`` says (``_folded``): the rows of O [B*H, T, Dv] and,
+    ``with_lse``, the logsumexp a query row [B*H, 1, T]."""
+    tok = int(panels)
+    bh, t = qf.shape[0], qf.shape[1 + tok]
+    d, d_v = qf.shape[2 - tok], vf.shape[2 - tok]
+    group = _kv_group(qf, kf)
+    block_q, block_k = _blocks(t, block_q, block_k, causal)
+    n_q, n_k = t // block_q, t // block_k
+
+    kernel = functools.partial(
+        _fa_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, n_k=n_k, strips=_strips(2, d, d_v),
+        emit_lse=with_lse, tok=tok, wide=interpret)
+
+    # causal dead-tile DMA elision for the streamed k/v operands (see
+    # _causal_kv_ix)
+    kv_ix = _causal_kv_ix(causal, group)
+
+    out_shape = [jax.ShapeDtypeStruct((bh, t, d_v), qf.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, d_v),
+                              lambda bh, iq, ik: (bh, iq, 0))]
+    if with_lse:
+        # lse is only materialised when the caller needs residuals
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, block_q), lambda bh, iq, ik: (bh, 0, iq)))
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid=(bh, n_q, n_k),
+        in_specs=[
+            _spec(panels, d, block_q, lambda bh, iq, ik: (bh, iq)),
+            _spec(panels, d, block_k, kv_ix),
+            _spec(panels, d_v, block_k, kv_ix),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((block_q, d), qf.dtype)] * tok + [
+            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
+            pltpu.VMEM((block_q, 128), jnp.float32),   # normaliser
+            pltpu.VMEM((block_q, d_v), jnp.float32),   # output accum
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+            # resident: the K and V tiles, double-buffered
+            vmem_limit_bytes=_vmem_limit(
+                2 * block_k * (_lanes(d) + _lanes(d_v))
+                * qf.dtype.itemsize, block_q, block_k, d, d_v,
+                qf.dtype.itemsize)),
+        interpret=interpret,
+    )(qf, kf, vf)
+
+
 def flash_attention_forward(q, k, v, causal: bool = True,
                             scale: Optional[float] = None,
                             block_q: int = 1024, block_k: int = 1024,
@@ -358,72 +528,27 @@ def flash_attention_forward(q, k, v, causal: bool = True,
     sizes (caller falls back to dense otherwise). ``with_lse`` also
     returns the per-row logsumexp [B, H, T] the fused backward needs."""
     b, t, h, d = q.shape
-    d_v = v.shape[-1]
-    group = _kv_group(q, k)
     scale = scale if scale is not None else d ** -0.5
-    block_q, block_k = _blocks(t, block_q, block_k, causal)
-    n_q, n_k = t // block_q, t // block_k
-
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-
-    kernel = functools.partial(
-        _fa_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, strips=_strips(2, d, d_v),
-        emit_lse=with_lse)
-
-    # causal dead-tile DMA elision for the streamed k/v operands (see
-    # _causal_kv_ix)
-    kv_ix = _causal_kv_ix(causal, group)
-
-    out_shape = [jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d_v),
-                              lambda bh, iq, ik: (bh, iq, 0))]
-    if with_lse:
-        # lse is only materialised when the caller needs residuals —
-        # inference forwards skip the [B*H, T, 128] write entirely
-        out_shape.append(
-            jax.ShapeDtypeStruct((b * h, t, 128), jnp.float32))
-        out_specs.append(pl.BlockSpec(
-            (1, block_q, 128), lambda bh, iq, ik: (bh, iq, 0)))
-
-    result = pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=(b * h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), kv_ix),
-            pl.BlockSpec((1, block_k, d_v), kv_ix),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # normaliser
-            pltpu.VMEM((block_q, d_v), jnp.float32),   # output accum
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary'),
-            # resident: the K and V tiles, double-buffered
-            vmem_limit_bytes=_vmem_limit(
-                2 * block_k * (_lanes(d) + _lanes(d_v))
-                * q.dtype.itemsize, block_q, block_k, d, d_v,
-                q.dtype.itemsize)),
-        interpret=interpret,
-    )(qf, kf, vf)
-
+    panels = _panels_for(d, v.shape[-1])
+    result = _forward(*(_folded(x, panels) for x in (q, k, v)), panels,
+                      causal, scale, block_q, block_k, interpret, with_lse)
     out = _unfold(result[0], b)
     if with_lse:
-        return out, result[1][:, :, 0].reshape(b, h, t)
+        return out, result[1].reshape(b, h, t)
     return out
 
 
 def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
-                   delta_scr, *,
-                   scale, causal, block_q, block_k, n_q, n_k, strips):
+                   dq_ref, dk_ref, dv_ref, *scratch,
+                   scale, causal, block_q, block_k, n_q, n_k, strips, tok,
+                   wide):
     """One q-block of one query head against the ``n_k`` k-blocks of
     its key-value head that this span holds in VMEM: S, P, dP and dS
-    are made once a strip and feed all three gradients."""
+    are made once a strip and feed all three gradients, which
+    accumulate in their operands' orientation (dQ as rows, turned once
+    a step where q is a panel)."""
+    q_scr, dot_scr = scratch[:2] if tok else (None, None)
+    dq_scr, dk_scr, dv_scr, lse_scr, delta_scr = scratch[2 * tok:]
     first_k = pl.program_id(1) * n_k     # the span's first k-block
     # the last grid axis: the group's query heads one after the other,
     # each over its q-blocks
@@ -436,9 +561,14 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     dq_scr[:] = jnp.zeros_like(dq_scr)
-    # delta_i = rowsum(dO_i · O_i), the dS correction term, made here
-    # from the q-block's own rows and kept lane-tiled like lse: no
-    # [bh, t, 128] array of it in HBM, no XLA fusion to make one
+    if tok:
+        # a panel q-block's rows and dO's panel, each turned once a step
+        q_scr[:] = q_ref[0].T
+        dot_scr[:] = do_ref[0].T
+    # lse from its row of tokens, and delta_i = rowsum(dO_i · O_i), the
+    # dS correction term, from the q-block's own rows: both lane-tiled
+    # columns in VMEM, no [bh, t, 128] array of either in HBM
+    lse_scr[:] = jnp.broadcast_to(lse_ref[0], (128, block_q)).T
     delta_scr[:] = jnp.broadcast_to(jnp.sum(
         do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
         axis=-1, keepdims=True), delta_scr.shape)
@@ -448,28 +578,23 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         ``[k0, k0 + cols)``."""
         at = slice(r0, r0 + rows)
         keys = pl.ds(k0, cols)
-        q, do = q_ref[0, at], do_ref[0, at]
-        k, v = k_ref[0, keys], v_ref[0, keys]
+        k, v = _tokens(k_ref, keys, tok), _tokens(v_ref, keys, tok)
         # the probabilities exactly as the forward made them, from the
         # saved logsumexp; p and ds round to the input dtype for the
         # gradient products (standard flash practice; exact when
         # inputs are f32)
-        s = _scores(q, k, scale, cols - rows if masked else None)
-        p = jnp.exp(s - lse_ref[0, at, :1])
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_scr[at, :1])).astype(q.dtype)
-        p = p.astype(q.dtype)
-        dv_scr[keys] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [cols, d]
-        dk_scr[keys] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        dq_scr[at] += lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        s = _scores(q_scr[at] if tok else q_ref[0, at], k, scale, tok,
+                    wide, cols - rows if masked else None)
+        p = jnp.exp(s - lse_scr[at, :1])
+        dp = _mm(do_ref[0, at], v, 1, 1 - tok, wide)
+        ds = (p * (dp - delta_scr[at, :1])).astype(k.dtype)
+        p = p.astype(k.dtype)
+        to = (slice(None), keys) if tok else (keys,)
+        dv_scr[to] += _outer(dot_scr[:, at] if tok else do_ref[0, at],
+                             p, tok, wide)
+        dk_scr[to] += _outer(_tokens(q_ref, at, tok), ds, tok,
+                             wide) * scale
+        dq_scr[at] += _mm(ds, k, 1, tok, wide) * scale
 
     def whole(i_k, carry):
         strip(0, block_q, pl.multiple_of(i_k * block_k, block_k),
@@ -490,7 +615,8 @@ def _fa_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     else:
         lax.fori_loop(0, n_k, whole, None)
 
-    dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+    dq = dq_scr[:].astype(dq_ref.dtype)
+    dq_ref[0, 0] = dq.T if tok else dq
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finalise():
@@ -541,6 +667,78 @@ def _vmem_limit(resident, block_q, block_k, d, dv, itemsize) -> int:
     return min(max(need + need // 4, 32 << 20), 110 << 20)
 
 
+def _backward(qf, kf, vf, of, lse, dof, panels: bool, causal: bool,
+              scale: float, block_q: int, block_k: int, interpret: bool):
+    """The backward kernel over the forward's q, k, v (folded as
+    ``panels`` says), its rows of O and dO [B*H, T, Dv] and its lse
+    [B*H, 1, T]: dq, dk, dv, each folded as its operand."""
+    tok = int(panels)
+    bh, t = qf.shape[0], qf.shape[1 + tok]
+    d, d_v = qf.shape[2 - tok], vf.shape[2 - tok]
+    group = _kv_group(qf, kf)
+    block_q, block_k = _blocks(t, block_q, block_k, causal)
+    n_q = t // block_q
+    span = _span(t, block_k, d, d_v, qf.dtype.itemsize)
+    spans = t // span
+
+    # grid (key-value head, span, (query head of the group, q-block)):
+    # step ``j`` of the last axis reads q-block ``j % n_q`` of query
+    # head ``bh * group + j // n_q``
+    def q_ix(bh, s, j):
+        return bh * group + j // n_q, j % n_q
+
+    def kv_ix(bh, s, j):
+        return bh, s
+
+    # q and dq at the score head's size, O and dO at the value head's
+    q_spec = _spec(panels, d, block_q, q_ix)
+    o_spec = _spec(False, d_v, block_q, q_ix)
+    lse_spec = _spec(True, 1, block_q, q_ix)
+    k_spec = _spec(panels, d, span, kv_ix)
+    v_spec = _spec(panels, d_v, span, kv_ix)
+
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, n_q=n_q,
+                          n_k=span // block_k, strips=_strips(5, d, d_v),
+                          tok=tok, wide=interpret),
+        out_shape=[
+            # one partial of dq a span; a single span's is dq itself
+            jax.ShapeDtypeStruct(
+                (spans,) + qf.shape,
+                qf.dtype if spans == 1 else jnp.float32),
+            jax.ShapeDtypeStruct(kf.shape, kf.dtype),
+            jax.ShapeDtypeStruct(vf.shape, vf.dtype),
+        ],
+        grid=(kf.shape[0], spans, group * n_q),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec],
+        out_specs=[
+            pl.BlockSpec((1,) + q_spec.block_shape,
+                         lambda bh, s, j: (s,) + q_spec.index_map(
+                             bh, s, j)),
+            k_spec, v_spec,
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), qf.dtype),      # a panel's q rows
+            pltpu.VMEM((d_v, block_q), dof.dtype),   # dO's panel
+        ] * tok + [
+            pltpu.VMEM((block_q, d), jnp.float32),   # dq, rows
+            pltpu.VMEM(k_spec.block_shape[1:], jnp.float32),
+            pltpu.VMEM(v_spec.block_shape[1:], jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),  # lse
+            pltpu.VMEM((block_q, 128), jnp.float32),  # delta
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
+            vmem_limit_bytes=_vmem_limit(
+                _resident_bytes(span, d, d_v, qf.dtype.itemsize),
+                block_q, block_k, d, d_v, qf.dtype.itemsize)),
+        interpret=interpret,
+    )(qf, kf, vf, of, dof, lse)
+    return (dq[0] if spans == 1
+            else jnp.sum(dq, axis=0).astype(qf.dtype)), dk, dv
+
+
 def flash_attention_backward(q, k, v, out, lse, do,
                              causal: bool = True,
                              scale: Optional[float] = None,
@@ -555,67 +753,12 @@ def flash_attention_backward(q, k, v, out, lse, do,
     a tile). A sequence too long to stay (``_span``) goes through in
     spans, each adding a float32 partial of dQ that is summed here."""
     b, t, h, d = q.shape
-    d_v = v.shape[-1]
-    group = _kv_group(q, k)
-    h_kv = h // group
     scale = scale if scale is not None else d ** -0.5
-    block_q, block_k = _blocks(t, block_q, block_k, causal)
-    n_q = t // block_q
-    span = _span(t, block_k, d, d_v, q.dtype.itemsize)
-    spans = t // span
-
-    qf, kf, vf, of, dof = (_fold(x) for x in (q, k, v, out, do))
-    # the row statistic lives lane-tiled ([bh, t, 128]) so its blocks
-    # meet the TPU (8, 128) trailing-dim constraint
-    lsef = jnp.broadcast_to(
-        lse.reshape(b * h, t)[..., None], (b * h, t, 128))
-
-    # grid (key-value head, span, (query head of the group, q-block)):
-    # step ``j`` of the last axis reads q-block ``j % n_q`` of query
-    # head ``bh * group + j // n_q``
-    def q_ix(bh, s, j):
-        return (bh * group + j // n_q, j % n_q, 0)
-
-    # q and dq at the score head's width, O and dO at the value head's
-    q_spec = pl.BlockSpec((1, block_q, d), q_ix)
-    o_spec = pl.BlockSpec((1, block_q, d_v), q_ix)
-    row_spec = pl.BlockSpec((1, block_q, 128), q_ix)
-    k_spec = pl.BlockSpec((1, span, d), lambda bh, s, j: (bh, s, 0))
-    v_spec = pl.BlockSpec((1, span, d_v), lambda bh, s, j: (bh, s, 0))
-
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
-                          n_k=span // block_k, strips=_strips(5, d, d_v)),
-        out_shape=[
-            # one partial of dq a span; a single span's is dq itself
-            jax.ShapeDtypeStruct(
-                (spans, b * h, t, d),
-                q.dtype if spans == 1 else jnp.float32),
-            jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, t, d_v), v.dtype),
-        ],
-        grid=(b * h_kv, spans, group * n_q),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda bh, s, j: (s,) + q_ix(bh, s, j)),
-            k_spec, v_spec,
-        ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((span, d), jnp.float32),
-                        pltpu.VMEM((span, d_v), jnp.float32),
-                        pltpu.VMEM((block_q, 128), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
-            vmem_limit_bytes=_vmem_limit(
-                _resident_bytes(span, d, d_v, q.dtype.itemsize), block_q,
-                block_k, d, d_v, q.dtype.itemsize)),
-        interpret=interpret,
-    )(qf, kf, vf, of, dof, lsef)
-    dq = dq[0] if spans == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
-
-    return _unfold(dq, b), _unfold(dk, b), _unfold(dv, b)
+    panels = _panels_for(d, v.shape[-1])
+    grads = _backward(*(_folded(x, panels) for x in (q, k, v)),
+                      _fold(out), lse.reshape(b * h, 1, t), _fold(do),
+                      panels, causal, scale, block_q, block_k, interpret)
+    return tuple(_unfolded(g, b, panels) for g in grads)
 
 
 def blockwise_attention(q, k, v, causal: bool = True,
@@ -671,29 +814,63 @@ def blockwise_attention(q, k, v, causal: bool = True,
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, causal, scale, interpret):
+# The scope of the op's layout code: the panels and rows it makes of
+# the caller's [B, T, H, D] and back, and the names of what it holds.
+# The kernels themselves stay outside it, named by the caller's scope
+# (their ops' names are what the roofline readers match). A copy XLA
+# cannot turn into a bitcast carries it (``layout_copies``).
+LAYOUT_SCOPE = 'flash_layout'
+
+
+def layout_copies(hlo_text: str, scope: str = LAYOUT_SCOPE) -> int:
+    """The ``copy`` instructions of a compiled program's text that the
+    flash op's layout code left in it (the gauge
+    ``step.flash_layout_copies``): those at the top level of a
+    computation whose ``op_name`` runs through ``scope``. A copy
+    inside a fused computation moves no bytes of its own."""
+    copies, fused = 0, False
+    for line in hlo_text.splitlines():
+        if line.startswith(('%', 'ENTRY')):     # a computation's header
+            # ``%fused_computation.3``, ``%bitcast_fusion.1``
+            fused = 'fus' in line.split(' ', 1)[0]
+        elif not fused and ' copy(' in line and f'/{scope}/' in line:
+            copies += 1
+    return copies
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, scale, interpret, panels):
     return flash_attention_forward(q, k, v, causal=causal, scale=scale,
                                    interpret=interpret)
 
 
-def _fa_fwd(q, k, v, causal, scale, interpret):
-    out, lse = flash_attention_forward(q, k, v, causal=causal,
-                                       scale=scale, interpret=interpret,
-                                       with_lse=True)
-    # named for a caller's save-by-name ``remat`` policy; without one a
-    # name is an identity that lowers to nothing
-    q, k, v = (checkpoint_name(x, 'flash_attn.qkv') for x in (q, k, v))
-    out = checkpoint_name(out, 'flash_attn.out')
-    return out, (q, k, v, out, checkpoint_name(lse, 'flash_attn.lse'))
+def _fa_fwd(q, k, v, causal, scale, interpret, panels):
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    with jax.named_scope(LAYOUT_SCOPE):
+        # named for a caller's save-by-name ``remat`` policy; without
+        # one a name is an identity that lowers to nothing. The folded
+        # operands are held, so the backward reads them as they were
+        qf, kf, vf = (checkpoint_name(_folded(x, panels), 'flash_attn.qkv')
+                      for x in (q, k, v))
+    of, lse = _forward(qf, kf, vf, panels, causal, scale, 1024, 1024,
+                       interpret, with_lse=True)
+    with jax.named_scope(LAYOUT_SCOPE):
+        of = checkpoint_name(of, 'flash_attn.out')
+        lse = checkpoint_name(lse, 'flash_attn.lse')
+        return _unfold(of, q.shape[0]), (qf, kf, vf, of, lse)
 
 
-def _fa_bwd(causal, scale, interpret, residuals, g):
+def _fa_bwd(causal, scale, interpret, panels, residuals, g):
     # fused flash backward: residuals are just (inputs, out, lse) —
     # O(T) extra memory; P tiles reconstructed in VMEM from lse
-    q, k, v, out, lse = residuals
-    return flash_attention_backward(q, k, v, out, lse, g, causal=causal,
-                                    scale=scale, interpret=interpret)
+    qf, kf, vf, of, lse = residuals
+    scale = scale if scale is not None else qf.shape[2 - panels] ** -0.5
+    with jax.named_scope(LAYOUT_SCOPE):
+        dof = _fold(g)
+    grads = _backward(qf, kf, vf, of, lse, dof, panels, causal, scale,
+                      1024, 1024, interpret)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return tuple(_unfolded(x, g.shape[0], panels) for x in grads)
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -719,7 +896,8 @@ def fused_attention(q, k, v, causal: bool = True,
     if not tiles:
         raise ValueError(
             f'pallas attention needs seq divisible by 128, got {t}')
-    return _flash_attention(q, k, v, causal, scale, impl == 'interpret')
+    return _flash_attention(q, k, v, causal, scale, impl == 'interpret',
+                            _panels_for(d, v.shape[3]))
 
 
 __all__ = ['fused_attention', 'flash_attention_forward',

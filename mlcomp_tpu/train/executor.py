@@ -745,6 +745,12 @@ class JaxTrain(Executor):
             text = compiled.as_text()
             self._telemetry.gauge('step.kernel_calls',
                                   text.count('tpu_custom_call'))
+            # and how many copies the flash op's layout code left in it:
+            # says whether the kernels read q, k, v as the projections
+            # lay them out (ops/flash_attention.py, LAYOUT_SCOPE)
+            from mlcomp_tpu.ops.flash_attention import layout_copies
+            self._telemetry.gauge('step.flash_layout_copies',
+                                  layout_copies(text))
             if wants['cost_analysis']:
                 try:
                     cost = compiled.cost_analysis()

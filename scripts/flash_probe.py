@@ -10,6 +10,13 @@ kernels' own device time from a trace — and the latter as a share of
 197 TFLOP/s on the FLOPs causal attention REQUIRES (2 products forward,
 4 backward over T (T + 1) / 2 pairs, one of a pair as deep as the score
 head and one as wide as the value head), so two commits' lines compare.
+The operands arrive as the cell's projections lay them out (``SHAPES``'
+last column, read from the compiled step, PR 36): ``tminor`` is a
+[B, T, H, D] array whose tokens are minor (a head's [D, T] panel — what
+XLA makes of q_proj, k_proj, v_proj and kv_b_proj), ``rows`` one whose
+head size is (OLMo's fused qkv); dq, dk and dv leave the same way. So
+the wall time holds the copies the cell's step holds round the kernels,
+and no others.
 ``--module`` times another checkout's file (the parent's, unpacked
 beside this one) on the same chip; ``--strips`` and ``--block`` sweep
 the strips a diagonal tile is walked in and the tile, by overriding the
@@ -34,16 +41,34 @@ import jax.numpy as jnp  # noqa: E402
 PEAK = 197e12
 REPS = 20
 # (batch, tokens, query heads, key-value heads, score head size, value
-# head size)
+# head size, how the cell's projections lay q, k, v out)
 SHAPES = {
-    'olmo-1b': (4, 2048, 16, 16, 128, 128),
-    'lfm2-8b-a1b': (2, 8192, 32, 8, 64, 64),
-    'qwen3-next-80b-a3b': (2, 8192, 16, 2, 256, 256),
-    'kanana-2-30b-a3b': (2, 8192, 32, 32, 192, 128),
+    'olmo-1b': (4, 2048, 16, 16, 128, 128, 'rows'),
+    'lfm2-8b-a1b': (2, 8192, 32, 8, 64, 64, 'tminor'),
+    'qwen3-next-80b-a3b': (2, 8192, 16, 2, 256, 256, 'tminor'),
+    'kanana-2-30b-a3b': (2, 8192, 32, 32, 192, 128, 'tminor'),
     # the CPU rehearsals (interpret mode)
-    'tiny': (1, 256, 2, 1, 128, 128),
-    'tiny-unequal': (1, 256, 2, 2, 192, 128),
+    'tiny': (1, 256, 2, 1, 128, 128, 'rows'),
+    'tiny-unequal': (1, 256, 2, 2, 192, 128, 'tminor'),
 }
+
+
+def laid_out(fn, layout, n_in, grads=False):
+    """``fn`` over q, k, v (its first ``n_in`` arguments are the three)
+    given as the producers lay them out: ``tminor`` arrays are held as
+    [B, H, D, T] and turned to [B, T, H, D] inside the jitted call, so
+    the kernels' own fold is what XLA has to copy, or not; ``grads``
+    leave the same way."""
+    if layout == 'rows':
+        return fn
+
+    def call(*args):
+        args = [jnp.transpose(a, (0, 3, 1, 2)) if i < n_in else a
+                for i, a in enumerate(args)]
+        out = fn(*args)
+        return tuple(jnp.transpose(g, (0, 2, 3, 1)) for g in out) \
+            if grads else out
+    return call
 
 
 def load(path):
@@ -114,12 +139,15 @@ def main():
     counts = [int(x) for x in args.strips.split(',') if x] or [None]
     blocks = [int(x) for x in args.block.split(',') if x] or [None]
     for name in args.shapes.split(','):
-        b, t, h, h_kv, d, dv = SHAPES[name]
+        b, t, h, h_kv, d, dv, layout = SHAPES[name]
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q, k, v, do = (
             jax.random.normal(kk, (b, t, heads, width), jnp.bfloat16)
             for kk, heads, width in zip(ks, (h, h_kv, h_kv, h),
                                         (d, d, dv, dv)))
+        # the operands as the producers hold them
+        held = [jnp.transpose(x, (0, 2, 3, 1)) if layout == 'tminor'
+                else x for x in (q, k, v)]
         # forward FLOPs
         need = 2 * (t * (t + 1) // 2) * (d + dv) * b * h
         for block in blocks:
@@ -132,35 +160,38 @@ def main():
                 if block is not None:
                     kw.update(block_q=block, block_k=block)
                 line = {'shape': name, 'module': args.module or 'this',
-                        'block': block, 'strips': strips}
+                        'layout': layout, 'block': block, 'strips': strips}
                 plain = jax.jit(functools.partial(
                     fa.flash_attention_forward, causal=True,
                     with_lse=True, interpret=kw['interpret']))
                 out, lse = plain(q, k, v)
                 try:
-                    fwd = jax.jit(functools.partial(
+                    fwd = jax.jit(laid_out(functools.partial(
                         fa.flash_attention_forward, causal=True,
-                        with_lse=True, **kw))
-                    f_ms = timed(fwd, q, k, v)
+                        with_lse=True, **kw), layout, 3))
+                    f_ms = timed(fwd, *held)
                     line.update(fwd_ms=round(f_ms, 4))
-                    f_ms = kernel_ms(fwd, q, k, v)
+                    f_ms = kernel_ms(fwd, *held)
                     if f_ms:
                         line.update(fwd_kernel_ms=round(f_ms, 4),
                                     fwd_pct=share(need, f_ms))
                 except Exception as e:  # a tile the compiler refuses
                     line['fwd_error'] = str(e)[:300]
                 try:
-                    bwd = jax.jit(functools.partial(
-                        fa.flash_attention_backward, causal=True, **kw))
-                    b_ms = timed(bwd, q, k, v, out, lse, do)
-                    grads = bwd(q, k, v, out, lse, do)
+                    bwd = jax.jit(laid_out(functools.partial(
+                        fa.flash_attention_backward, causal=True, **kw),
+                        layout, 3, grads=True))
+                    b_ms = timed(bwd, *held, out, lse, do)
+                    grads = jax.jit(functools.partial(
+                        fa.flash_attention_backward, causal=True, **kw))(
+                            q, k, v, out, lse, do)
                     line.update(
                         bwd_ms=round(b_ms, 4),
                         # to compare two files' results on one input
                         sums=[float(jnp.sum(jnp.abs(
                             x.astype(jnp.float32))))
                             for x in (out,) + tuple(grads)])
-                    b_ms = kernel_ms(bwd, q, k, v, out, lse, do)
+                    b_ms = kernel_ms(bwd, *held, out, lse, do)
                     if b_ms:
                         line.update(bwd_kernel_ms=round(b_ms, 4),
                                     bwd_pct=share(2 * need, b_ms))

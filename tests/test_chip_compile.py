@@ -80,6 +80,43 @@ def test_flash_attention(one_chip, shape, grad):
     assert _compile(_flash(grad), one_chip, *qkv) == (2 if grad else 1)
 
 
+def test_olmo_layer_keeps_the_rows_its_fused_qkv_writes(one_chip):
+    """``jax.grad`` of one `remat`ted layer of ``olmo-1b.steady`` (4 x
+    2,048 tokens, 16 heads of 128): its fused qkv projection writes the
+    kernels' rows, so heads of whole 128s take rows (PR 36,
+    ``_panels_for``; panels would add 9 copies here) and the op leaves
+    the one copy the parent's step had; the forward runs twice (no
+    save-by-name policy), the backward once."""
+    import json
+
+    import flax
+    import flax.linen as nn
+    from mlcomp_tpu.models import create_model
+    from mlcomp_tpu.models.transformer import DecoderLayer
+    from mlcomp_tpu.ops.flash_attention import layout_copies
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, 'benchmark/configs/olmo-1b.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = create_model(**dict(kwargs, attn_impl='pallas')).cfg
+    layer = nn.remat(DecoderLayer, static_argnums=(2,))(cfg)
+    x = jax.ShapeDtypeStruct((4, 2048, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x, False)['params']))
+
+    def loss(p, x):
+        return (layer.apply({'params': p}, x, False).astype(
+            jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert text.count('tpu_custom_call') == 3
+    assert layout_copies(text) <= 1
+
+
 def test_flash_attention_backward_in_spans(one_chip):
     """A key-value head of 32,768 tokens at head size 256 does not stay
     in VMEM whole (K, V, their gradients' blocks and the float32
@@ -246,6 +283,11 @@ def test_qwen3_next_remat_holds_the_kernels_results(one_chip, full):
     if full:
         # forward and the one backward kernel (PR 34)
         assert kernels['gqa_attn'] == 2
+        # heads of 256 keep their rows (PR 36: ``_panels_for``); what
+        # the op holds is its folded rows, so the backward folds them no
+        # more — 7 layout copies where the parent had 9
+        from mlcomp_tpu.ops.flash_attention import layout_copies
+        assert layout_copies(text, 'gqa_attn') == layout_copies(text) <= 7
     else:
         assert kernels['gated_delta_fwd'] == 1
         assert kernels['gated_delta_prepare'] == 2
@@ -364,6 +406,10 @@ def test_lfm2_moe_remat_holds_the_kernels_results(one_chip, kind):
         assert kernels['short_conv_bwd'] == 1
     else:
         assert kernels['gqa_attn'] == 2     # forward, one backward
+        # heads of 64 go in as panels (PR 36): 3 layout copies where
+        # the parent had 10
+        from mlcomp_tpu.ops.flash_attention import layout_copies
+        assert layout_copies(text, 'gqa_attn') == layout_copies(text) <= 3
     # gate, up, down once; three for the rows' gradients, three for the
     # weights'
     assert kernels['gmm'] == 6 and kernels['tgmm'] == 3, kernels
@@ -419,8 +465,9 @@ def test_deepseek_v3_remat_holds_the_kernels_results(one_chip, sparse):
         y = layer.apply({'params': p}, x, mutable=['intermediates'])[0]
         return (y.astype(jnp.float32) ** 2).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
     kernels = collections.Counter(
         re.search(r'mla_attn|tgmm|gmm|$', re.search(
             r'op_name="([^"]*)"', line).group(1)).group(0)
@@ -432,3 +479,13 @@ def test_deepseek_v3_remat_holds_the_kernels_results(one_chip, sparse):
     assert (kernels['gmm'], kernels['tgmm']) == ((6, 3) if sparse
                                                  else (0, 0)), kernels
     assert '' not in kernels        # no kernel but these
+    # PR 36: q, k, v reach the kernels as the head panels q_proj's and
+    # kv_b_proj's matmuls write, and dq, dk, dv leave as panels — 3
+    # layout copies a layer and step where the parent had 9 (4.4 GB of
+    # traffic), all of them the op's own (``step.flash_layout_copies``
+    # reads 15 for the cell's five layers)
+    from mlcomp_tpu.ops.flash_attention import layout_copies
+    assert layout_copies(text, 'mla_attn') == layout_copies(text) <= 3
+    assert not re.findall(r'= bf16\[2,32,8192,192\]\S* copy\(', text)
+    if not sparse:      # the parent's layer: 2.023 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.02e9

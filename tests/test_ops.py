@@ -199,24 +199,57 @@ def _rel(a, b):
 
 HEADS = [(2, 2, 128), (4, 1, 64), (8, 1, 256)]
 HEAD_IDS = ['equal_d128', '4to1_d64', '8to1_d256']
+# PR 36: q, k, v enter as rows [B*H, T, D] or as panels [B*H, D, T];
+# the head sizes pick one (``_panels_for``), and the flash tests below
+# run the other too at every size, by turning the pick round
+LAYOUTS = ['by_size', 'flipped']
+
+
+def _force(monkeypatch, layout):
+    """Make the flash op take, ``flipped``, the orientation its head
+    sizes do not pick."""
+    from mlcomp_tpu.ops import flash_attention as fa
+    if layout == 'flipped':
+        picks = fa._panels_for
+        monkeypatch.setattr(fa, '_panels_for',
+                            lambda d, dv: not picks(d, dv))
+
+
+def _logsumexp(q, k, causal):
+    """The dense logsumexp a query row [B, H, T] (scale 1/sqrt(D),
+    grouped heads repeated) that the forward's lse has to be."""
+    import jax
+    import jax.numpy as jnp
+    group = q.shape[2] // k.shape[2]
+    k = jnp.repeat(k, group, axis=2)
+    s = jnp.einsum('bqhd,bkhd->bhqk', q, k) * q.shape[-1] ** -0.5
+    if causal:
+        t = q.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+    return jax.scipy.special.logsumexp(s, axis=-1)
 # (tokens, tile): one tile of four strips; two tiles of two strips (a
 # whole tile under the diagonal, then the diagonal's own); four tiles of
 # one strip each; T = tile with a single strip
 WALKS = [(512, 512), (512, 256), (512, 128), (128, 128)]
 
 
+@pytest.mark.parametrize('layout', LAYOUTS)
 @pytest.mark.parametrize('t,block', WALKS,
                          ids=[f't{t}_tile{b}' for t, b in WALKS])
 @pytest.mark.parametrize('causal', [True, False],
                          ids=['causal', 'full'])
 @pytest.mark.parametrize('h,h_kv,d', HEADS, ids=HEAD_IDS)
-def test_fused_flash_backward_against_dense(h, h_kv, d, causal, t, block):
+def test_fused_flash_backward_against_dense(monkeypatch, h, h_kv, d,
+                                            causal, t, block, layout):
     """dq, dk, dv of the ONE backward kernel against autodiff of the
-    dense reference, and the forward with and without ``with_lse``."""
+    dense reference, the forward with and without ``with_lse``, and
+    the lse against the dense logsumexp, with q, k, v as rows and as
+    panels."""
     import functools
 
     import jax
     from mlcomp_tpu.ops import flash_attention as fa
+    _force(monkeypatch, layout)
     if causal:      # the backward's walk, from its own rule
         strips = fa._diagonal_strips(block, fa._strips(5, d, d))
         assert len(strips) == {512: 4, 256: 2, 128: 1}[block]
@@ -227,15 +260,17 @@ def test_fused_flash_backward_against_dense(h, h_kv, d, causal, t, block):
     plain = fa.flash_attention_forward(q, k, v, **kw)
     out, lse = fa.flash_attention_forward(q, k, v, with_lse=True, **kw)
     assert _rel(plain, want) < 1e-5 and _rel(out, want) < 1e-5
+    assert _rel(lse, _logsumexp(q, k, causal)) < 1e-6
     grads = fa.flash_attention_backward(q, k, v, out, lse, do, **kw)
     for name, a, b in zip('dq dk dv'.split(), grads, pull(do)):
         assert a.shape == b.shape and a.dtype == b.dtype
         assert _rel(a, b) < 1e-5, name
 
 
+@pytest.mark.parametrize('layout', LAYOUTS)
 @pytest.mark.parametrize('causal', [True, False], ids=['causal', 'full'])
 @pytest.mark.parametrize('spans', [2, 4])
-def test_fused_flash_backward_in_spans(monkeypatch, causal, spans):
+def test_fused_flash_backward_in_spans(monkeypatch, causal, spans, layout):
     """A key-value head too long to stay in VMEM whole goes through the
     same kernel in spans, dq summed from one float32 partial a span:
     the budget is made small, the COMPUTED bytes choose."""
@@ -243,6 +278,7 @@ def test_fused_flash_backward_in_spans(monkeypatch, causal, spans):
 
     import jax
     from mlcomp_tpu.ops import flash_attention as fa
+    _force(monkeypatch, layout)
     t, d, block = 512, 64, 128
     monkeypatch.setattr(fa, 'RESIDENT_BYTES',
                         fa._resident_bytes(t // spans, d, d, 4))
@@ -318,19 +354,21 @@ UNEQUAL = [(2, 2, 192, 128), (2, 1, 24, 8), (2, 2, 64, 128)]
 UNEQUAL_IDS = ['mla_192_128', 'tiny_24_8_grouped', 'value_wider_64_128']
 
 
+@pytest.mark.parametrize('layout', LAYOUTS)
 @pytest.mark.parametrize('t,block', [(512, 256), (256, 128), (128, 128)],
                          ids=['t512_tile256', 't256_tile128', 't128'])
 @pytest.mark.parametrize('causal', [True, False], ids=['causal', 'full'])
 @pytest.mark.parametrize('h,h_kv,d,dv', UNEQUAL, ids=UNEQUAL_IDS)
-def test_flash_with_unequal_head_sizes_against_dense(h, h_kv, d, dv,
-                                                     causal, t, block):
-    """q, k [.., d] and v, o, do [.., dv]: the forward, dq and dk at the
-    score head's width and dv at the value head's, against autodiff of
-    the dense form; the scale is the score head's."""
+def test_flash_with_unequal_head_sizes_against_dense(
+        monkeypatch, h, h_kv, d, dv, causal, t, block, layout):
+    """q, k [.., d] and v, o, do [.., dv]: the forward, its lse, dq and
+    dk at the score head's width and dv at the value head's, against
+    autodiff of the dense form; the scale is the score head's."""
     import functools
 
     import jax
     from mlcomp_tpu.ops import flash_attention as fa
+    _force(monkeypatch, layout)
     q, k, v, do = _unequal_heads(h, h_kv, t, d, dv)
     want, pull = jax.vjp(functools.partial(
         reference_attention, causal=causal), q, k, v)
@@ -338,6 +376,7 @@ def test_flash_with_unequal_head_sizes_against_dense(h, h_kv, d, dv,
     kw = dict(causal=causal, block_q=block, block_k=block, interpret=True)
     out, lse = fa.flash_attention_forward(q, k, v, with_lse=True, **kw)
     assert out.shape == want.shape and _rel(out, want) < 1e-5
+    assert _rel(lse, _logsumexp(q, k, causal)) < 1e-6
     grads = fa.flash_attention_backward(q, k, v, out, lse, do, **kw)
     for name, a, b, like in zip('dq dk dv'.split(), grads, pull(do),
                                 (q, k, v)):
@@ -345,9 +384,10 @@ def test_flash_with_unequal_head_sizes_against_dense(h, h_kv, d, dv,
         assert _rel(a, b) < 1e-5, name
 
 
+@pytest.mark.parametrize('layout', LAYOUTS)
 @pytest.mark.parametrize('spans', [1, 2])
 def test_fused_attention_with_unequal_heads_in_forced_spans(monkeypatch,
-                                                            spans):
+                                                            spans, layout):
     """``fused_attention(impl='interpret')`` at 192 / 128 under
     ``jax.grad``, the backward's keys in one span and forced into two:
     all three gradients against the dense form's, and the pure-jnp
@@ -355,6 +395,7 @@ def test_fused_attention_with_unequal_heads_in_forced_spans(monkeypatch,
     import jax
     import jax.numpy as jnp
     from mlcomp_tpu.ops import flash_attention as fa
+    _force(monkeypatch, layout)
     t, d, dv = 2048, 192, 128
     monkeypatch.setattr(fa, 'RESIDENT_BYTES',
                         fa._resident_bytes(t // spans, d, dv, 4))
@@ -394,6 +435,48 @@ def test_what_the_kernels_do_beyond_the_required_pairs_at_192_128():
     assert fa._resident_bytes(t, d, dv, 2) == t * 384 * 12
     assert fa._span(t, 1024, d, dv, 2) == t
     assert fa._resident_bytes(t, 128, 128, 2) == t * 128 * 24
+
+
+# the gauge ``step.flash_layout_copies`` (``JaxTrain._introspect``)
+_COMPILED = """\
+HloModule jit_step, entry_computation_layout={()->()}
+
+%fused_computation.3 (param_0.1: bf16[2,8,4]) -> bf16[2,4,8] {
+  %param_0.1 = bf16[2,8,4]{2,1,0} parameter(0)
+  ROOT %copy.9 = bf16[2,4,8]{2,1,0} copy(%param_0.1), metadata={op_name="jit(step)/attn/mla_attn/flash_layout/transpose"}
+}
+
+%bitcast_fusion.1 (bitcast_input.1: bf16[2,8,4]) -> bf16[2,4,8] {
+  %bitcast_input.1 = bf16[2,8,4]{2,1,0} parameter(0)
+  ROOT %copy.8 = bf16[2,4,8]{1,2,0} copy(%bitcast_input.1), metadata={op_name="jit(step)/attn/mla_attn/flash_layout/reshape"}
+}
+
+ENTRY %main.5 (p: bf16[2,8,4]) -> bf16[2,4,8] {
+  %p = bf16[2,8,4]{2,1,0} parameter(0)
+  %copy.1 = bf16[2,8,4]{1,2,0} copy(%p), metadata={op_name="jit(step)/attn/mla_attn/flash_layout/transpose"}
+  %copy.2 = bf16[2,8,4]{0,2,1} copy(%copy.1), metadata={op_name="jit(step)/transpose(jvp())/attn/mla_attn/flash_layout/reshape;jit(step)/attn/squeeze"}
+  %copy.3 = bf16[2,8,4]{1,2,0} copy(%p), metadata={op_name="jit(step)/attn/kv_b_proj/convert_element_type"}
+  %fusion.4 = bf16[2,4,8]{2,1,0} fusion(%copy.2), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(step)/attn/mla_attn/flash_layout/transpose"}
+  %copy_bitcast_fusion = bf16[2,4,8]{2,1,0} fusion(%copy.2), kind=kLoop, calls=%bitcast_fusion.1, metadata={op_name="jit(step)/attn/mla_attn/flash_layout/copy"}
+  ROOT %copy.4 = bf16[2,4,8]{1,2,0} copy(%fusion.4), metadata={op_name="jit(step)/flash_layout_other/transpose"}
+}
+"""
+
+
+@pytest.mark.parametrize('text,copies', [
+    (_COMPILED, 2),
+    ('', 0),
+    # a step with no flash op (ResNet's) or one whose layout is free
+    (_COMPILED.replace('/flash_layout/', '/mixer/'), 0),
+], ids=['two_of_six', 'empty', 'no_flash_op'])
+def test_layout_copies_counts_the_flash_ops_top_level_copies(text, copies):
+    """Only copy instructions at a computation's top level whose op_name
+    runs through the op's layout scope: not a fused computation's copy
+    (it moves no bytes of its own), not a fusion that merely carries
+    the name, not another scope's copy, not a scope named alike."""
+    from mlcomp_tpu.ops.flash_attention import LAYOUT_SCOPE, layout_copies
+    assert LAYOUT_SCOPE == 'flash_layout'
+    assert layout_copies(text) == copies
 
 
 class TestFusedCE:

@@ -322,8 +322,8 @@ def test_equal_heads_flash_is_what_it_was():
     assert rel(one[0], two[0]) < 1e-5
     assert rel(one[1], jnp.sum(two[1], 2, keepdims=True)) < 1e-5
     assert fa._kv_head(5, 1) == 5 and fa._kv_head(17, 8) == 2
-    assert fa._causal_kv_ix(False)(3, 1, 2) == (3, 2, 0)
-    assert fa._causal_kv_ix(False, 8)(17, 1, 2) == (2, 2, 0)
+    assert fa._causal_kv_ix(False)(3, 1, 2) == (3, 2)
+    assert fa._causal_kv_ix(False, 8)(17, 1, 2) == (2, 2)
     # heads of 256 take the tile of every other head since PR 34 (the
     # kernels ask for the VMEM their shapes need)
     assert fa._blocks(8192, 1024, 1024, True) == (1024, 1024)

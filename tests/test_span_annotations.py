@@ -215,7 +215,8 @@ def test_the_introspection_counts_the_steps_kernels(session, tmp_path,
                                                     monkeypatch):
     """``step.kernel_calls``: one gauge a job, the `tpu_custom_call`s
     of the compiled train step (none on the CPU; with two named in the
-    text, two)."""
+    text, two); beside it ``step.flash_layout_copies``, the copies the
+    flash op's layout code left in it (``layout_copies``)."""
     from jax import stages
     from mlcomp_tpu.db.providers.telemetry import MetricProvider
 
@@ -224,15 +225,19 @@ def test_the_introspection_counts_the_steps_kernels(session, tmp_path,
                             device_data=True,
                             telemetry={'cost_analysis': True})
         ex.work()
-        return MetricProvider(session).recent_values(
-            task.id, 'step.kernel_calls')
+        return [MetricProvider(session).recent_values(task.id, key)
+                for key in ('step.kernel_calls',
+                            'step.flash_layout_copies')]
 
-    assert job('cpu') == [0.0]
+    assert job('cpu') == [[0.0], [0.0]]
     as_text = stages.Compiled.as_text
+    copy = ('  %copy.1 = bf16[2,8,4]{1,2,0} copy(%p), metadata={op_name='
+            '"jit(step)/attn/flash_layout/transpose"}\n')
     monkeypatch.setattr(
         stages.Compiled, 'as_text', lambda self, *a, **k:
-        as_text(self, *a, **k) + 'tpu_custom_call\ntpu_custom_call\n')
-    assert job('two') == [2.0]
+        as_text(self, *a, **k) + 'tpu_custom_call\ntpu_custom_call\n'
+        + copy)
+    assert job('two') == [[2.0], [1.0]]
 
 
 def test_a_deepseek_v3_job_writes_its_counters_and_the_kernel_gauge(
@@ -265,6 +270,7 @@ def test_a_deepseek_v3_job_writes_its_counters_and_the_kernel_gauge(
     values = lambda name: MetricProvider(session).recent_values(  # noqa: E731,E501
         task.id, name)
     assert values('step.kernel_calls') == [0.0]
+    assert values('step.flash_layout_copies') == [0.0]
     assert values('mla_attn.rows') == [4 * 16 * 3.0] * 2
     assert values('moe.dropped') == [0.0] * 2
     assert len(values('moe.local_assign_share')) == 2
