@@ -1888,8 +1888,7 @@ def main():
                          flush_every=100, async_flush=True)
     fake_metrics = {'loss': metrics['loss']}  # live device scalar
     instr = instrumented_step(
-        lambda s, xb, yb: (s, fake_metrics), rec,
-        batch_size=batch_size)
+        lambda s, xb, yb: (s, fake_metrics), rec)
     n_rec = 20000
     t0 = time.perf_counter()
     for _ in range(n_rec):
@@ -1930,7 +1929,6 @@ def main():
                                  flush_every=10 ** 9)
         attr_run = _SA(recorder=eff_rec)
         instr_prod = instrumented_step(train_step, eff_rec,
-                                       batch_size=batch_size,
                                        attribution=attr_run)
         n_eff_steps = int(os.environ.get('BENCH_ATTR_STEPS', '40'))
         eff_rng = np.random.RandomState(7)

@@ -93,7 +93,6 @@ class CompileEventRecorder:
                     self.recorder.series(self.metric,
                                          float(duration) * 1e3,
                                          step=step)
-                    self.recorder.count('compile.count')
             except Exception:
                 pass
 
@@ -174,7 +173,6 @@ class HostSyncTripwire:
                 self.suspects += 1
                 if self.recorder is not None:
                     self.recorder.series(self.metric, dt_ms, step=step)
-                    self.recorder.count('host_sync.suspect_count')
                 return True
         self._times.append(dt_ms)
         return False

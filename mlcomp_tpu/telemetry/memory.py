@@ -48,7 +48,7 @@ import json
 #: series the postmortem bundle tails (prefix match), newest-first in
 #: the stored bundle — the signals that explain an OOM or a slow death
 POSTMORTEM_SERIES_PREFIXES = (
-    'loss', 'step_time_ms', 'throughput', 'step.phase.',
+    'loss', 'step_time_ms', 'step.phase.',
     'step.pipeline_efficiency', 'device', 'compile.backend_ms',
     'comm.', 'mfu', 'host_sync.suspect_ms', 'devtime.',
 )

@@ -682,9 +682,9 @@ class JaxTrain(Executor):
 
     def _want(self, key):
         """Per-feature introspection gate: 'cost_analysis' /
-        'memory_analysis' / 'collectives' each default ON off-CPU
-        only (the shared AOT lowering is an extra compile the CPU
-        test harness shouldn't pay) and can be forced either way
+        'memory_analysis' / 'collectives' / 'op_blocks' each default ON
+        off-CPU only (the shared AOT lowering is an extra compile the
+        CPU test harness shouldn't pay) and can be forced either way
         in the telemetry spec."""
         want = self.telemetry_spec.get(key)
         if want is None:
@@ -721,14 +721,16 @@ class JaxTrain(Executor):
         lower+compile: XLA cost analysis (the in-loop half of
         bench's MFU), static peak memory attribution
         (telemetry/memory.py), and the collective-communication
-        tally + measured wire probe (telemetry/collectives.py).
+        tally + measured wire probe (telemetry/collectives.py), and
+        the block of every op of the step (telemetry/op_blocks.py).
         The ``_introspected`` latch stops later stages from paying
         the lowering again even when a backend offers none of the
         analyses."""
         if self._telemetry is None or self._introspected:
             return
         wants = {key: self._want(key) for key in
-                 ('cost_analysis', 'memory_analysis', 'collectives')}
+                 ('cost_analysis', 'memory_analysis', 'collectives',
+                  'op_blocks')}
         if not any(wants.values()):
             return
         self._introspected = True
@@ -751,6 +753,20 @@ class JaxTrain(Executor):
             from mlcomp_tpu.ops.flash_attention import layout_copies
             self._telemetry.gauge('step.flash_layout_copies',
                                   layout_copies(text))
+            if wants['op_blocks']:
+                # which model block each op of the step belongs to, for
+                # whoever joins a device trace to it (the row
+                # ``step.op_blocks``)
+                from mlcomp_tpu.telemetry.op_blocks import (
+                    op_table, persist_op_table,
+                )
+                t0 = time.perf_counter()
+                table = op_table(text)
+                try:
+                    persist_op_table(self.session, self.task.id, table,
+                                     time.perf_counter() - t0)
+                except Exception:
+                    pass
             if wants['cost_analysis']:
                 try:
                     cost = compiled.cost_analysis()
@@ -1023,7 +1039,6 @@ class JaxTrain(Executor):
             from mlcomp_tpu.train.loop import instrumented_step
             train_step = instrumented_step(
                 train_step, self._telemetry,
-                batch_size=self.batch_size,
                 attribution=self._attribution,
                 tripwire=self._tripwire,
                 compile_events=self._compile_events,
@@ -1191,8 +1206,6 @@ class JaxTrain(Executor):
             tel = self._telemetry
             for k, v in counters.items():
                 tel.series(k, v, step=global_epoch)
-            tel.gauge('epoch_time_s', train_dt)
-            tel.gauge('epoch_throughput', n_train / train_dt)
             if self._step_flops:
                 from mlcomp_tpu.telemetry import mfu as _mfu
                 peak = float(self.telemetry_spec.get(

@@ -158,6 +158,11 @@ STEP_COUNTERS = {
 #: gradient's (``SparseMoe``'s selection bias moves by its load rule)
 LEAF_UPDATES = 'leaf_updates'
 
+#: the two scopes of the update rule a compiled step's ops are named
+#: under beside the model's own modules (``telemetry/op_blocks.py``
+#: reads them): metadata only, the lowered program is the same
+LOSS_SCOPE, OPTIMIZER_SCOPE = 'loss', 'optimizer'
+
 
 def _apply(model, state: TrainState, x, train: bool, rng=None):
     """Returns (logits, new_batch_stats, aux_loss, counters,
@@ -247,17 +252,19 @@ def _update(model, optimizer, loss_fn, state: TrainState, x, target,
         logits, new_stats, aux, counters, leaf_updates = _apply(
             model, state.replace(params=params), x, train=True,
             rng=step_rng)
-        loss, metrics = _with_sown(*loss_fn(logits, target), aux,
-                                   counters)
+        with jax.named_scope(LOSS_SCOPE):
+            loss, metrics = _with_sown(*loss_fn(logits, target), aux,
+                                       counters)
         return loss, (metrics, new_stats, leaf_updates)
 
     grads, (metrics, new_stats, leaf_updates) = jax.grad(
         loss_wrapped, has_aux=True)(state.params)
-    updates, new_opt = optimizer.update(
-        grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
-    if leaf_updates:
-        new_params = _add_leaf_updates(new_params, leaf_updates)
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        updates, new_opt = optimizer.update(
+            grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
+        if leaf_updates:
+            new_params = _add_leaf_updates(new_params, leaf_updates)
     new_state = state.replace(
         step=state.step + 1, params=new_params, opt_state=new_opt,
         batch_stats=(new_stats if new_stats is not None
@@ -335,7 +342,8 @@ def make_device_eval_step(model, loss_fn: Callable,
         if dequantize:
             x = x.astype(jnp.float32) / 255.0
         logits = _apply(model, state, x, train=False)[0]
-        _, metrics = loss_fn(logits, y, weights=w)
+        with jax.named_scope(LOSS_SCOPE):
+            _, metrics = loss_fn(logits, y, weights=w)
         return metrics
 
     return _jit_in_mesh(step, mesh)
@@ -347,13 +355,14 @@ def make_eval_step(model, loss_fn: Callable,
     def step(state: TrainState, x, y, w=None):
         logits = _apply(model, state, x, train=False)[0]
         target = x if self_supervised else y
-        _, metrics = loss_fn(logits, target, weights=w)
+        with jax.named_scope(LOSS_SCOPE):
+            _, metrics = loss_fn(logits, target, weights=w)
         return metrics
 
     return _jit_in_mesh(step, mesh)
 
 
-def instrumented_step(step_fn, recorder, batch_size: int = None,
+def instrumented_step(step_fn, recorder,
                       metric_keys=('loss',), attribution=None,
                       tripwire=None, compile_events=None,
                       memory=None, deviceprof=None):
@@ -368,8 +377,7 @@ def instrumented_step(step_fn, recorder, batch_size: int = None,
     step dispatches: with async dispatch the per-call time measures
     the python/dispatch cost only, but once the device pipeline fills,
     back-pressure makes the inter-call interval track true device step
-    time. ``throughput`` (samples/sec) derives from the same interval.
-    The first call records no timing (no previous dispatch to diff
+    time. The first call records no timing (no previous dispatch to diff
     against).
 
     Optional observability hooks (telemetry/attribution.py,
@@ -418,9 +426,6 @@ def instrumented_step(step_fn, recorder, batch_size: int = None,
         if prev is not None:
             dt = t - prev
             recorder.series('step_time_ms', dt * 1e3, step=step)
-            if batch_size and dt > 0:
-                recorder.series('throughput', batch_size / dt,
-                                step=step)
             if tripwire is not None and not compiled:
                 tripwire.observe(dt * 1e3, step=step)
         if memory is not None:

@@ -278,7 +278,7 @@ class TestUiPayloads:
                              component='train', flush_every=10 ** 9)
         for i in range(3):
             rec.series('loss', 1.0 - 0.1 * i, step=i)
-        rec.gauge('epoch_time_s', 2.5)
+        rec.gauge('mfu', 0.25)
         rec.flush()
         buf = SpanBuffer()
         with span('task.pipeline', task=task, buffer=buf):
@@ -289,7 +289,7 @@ class TestUiPayloads:
         tel = api('/api/telemetry/series', {'task': task})
         assert [p['value'] for p in tel['series']['loss']] == \
             pytest.approx([1.0, 0.9, 0.8])
-        assert tel['series']['epoch_time_s'][0]['step'] is None
+        assert tel['series']['mfu'][0]['step'] is None
         spans = api('/api/telemetry/spans', {'task': task})
         assert spans['spans'][0]['name'] == 'task.pipeline'
         assert [c['name'] for c in spans['spans'][0]['children']] == \

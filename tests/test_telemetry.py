@@ -174,7 +174,7 @@ class TestApi:
                              component='train', flush_every=10 ** 9)
         for i in range(4):
             rec.series('loss', 1.0 - 0.1 * i, step=i)
-            rec.series('throughput', 100.0 + i, step=i)
+            rec.series('step_time_ms', 100.0 + i, step=i)
         rec.flush()
         buf = SpanBuffer()
         with span('task.pipeline', task=task.id, buffer=buf):
@@ -190,7 +190,7 @@ class TestApi:
         assert out['task'] == task.id
         assert [p['value'] for p in out['series']['loss']] == \
             pytest.approx([1.0, 0.9, 0.8, 0.7])
-        assert len(out['series']['throughput']) == 4
+        assert len(out['series']['step_time_ms']) == 4
         named = api(f'/telemetry/series?task={task.id}&name=loss',
                     method='GET', token=None)
         assert list(named['series']) == ['loss']
@@ -327,11 +327,12 @@ class TestTrainLoopWiring:
         ex.work()
 
         series = MetricProvider(session).series(task_id=task.id)
-        assert 'loss' in series and 'throughput' in series
+        assert 'loss' in series and 'step_time_ms' in series
         # 2 epochs x 8 steps — every step's loss recorded in order
         assert [p['step'] for p in series['loss']] == list(range(16))
-        assert 'epoch_time_s' in series
-        assert 'epoch_throughput' in series
+        # nobody read these; the step clock and the report series say it
+        assert not {'throughput', 'epoch_time_s', 'epoch_throughput',
+                    'compile.count', 'host_sync.suspect_count'} & set(series)
 
     def test_telemetry_false_disables_recording(self, session,
                                                 tmp_path):
@@ -404,7 +405,7 @@ class TestOverheadGuard:
         rec = MetricRecorder(flush_every=10 ** 9, capacity=10 ** 6)
         fake_metrics = {'loss': np.float32(0.5)}
         instr = instrumented_step(
-            lambda s, xb, yb: (s, fake_metrics), rec, batch_size=512)
+            lambda s, xb, yb: (s, fake_metrics), rec)
         n = 20000
         t0 = time.perf_counter()
         for _ in range(n):
@@ -753,7 +754,7 @@ class TestStepAttribution:
         attr = StepAttribution(recorder=rec)
         instr = instrumented_step(
             lambda s, x, y: (s, {'loss': np.float32(0.1)}), rec,
-            batch_size=8, attribution=attr)
+            attribution=attr)
         for _ in range(4):
             attr.begin('data_wait')
             instr(None, None, None)
