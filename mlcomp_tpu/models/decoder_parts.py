@@ -21,6 +21,28 @@ top_k * held / n_experts``); ``None`` sizes it for the worst case, so
 that nothing can ever be left out. The ``moe.dropped`` counter says how
 many pairs did not fit.
 
+**The sorted buffer and its dead rows.** Row p of the buffer is the
+pair ``order[p]`` of the stable sort by expert; the rows past the pairs
+that landed and fit (``ends[-1]``) belong to no group, and the grouped
+products leave their results there undefined (``grouped_matmul``). Rows
+move between the tokens and the buffer in one of two forms, picked from
+the shapes alone (``gathers_rows``: the pairs against
+``SCATTER_ROW_COST`` times the buffer's rows):
+
+- ``through_gathers`` — both ways row gathers, by the sort and its
+  inverse (``buffer_slots``: each pair's row, or a sentinel). Into the
+  buffer every row is some token's row, a dead one too; out of it only
+  the rows a slot names are read, forward (the weighted sum over each
+  token's pairs) and backward (the weights' gradient, and the gradient
+  into the tokens, a gather of the buffer's gradient summed over each
+  token's pairs in float32). A dead row's gradient is a number no path
+  reads: a grouped product takes a row to the same row, so it stays in
+  the dead rows, and the weight gradients (``tgmm``) read no row outside
+  a group.
+- ``through_scatters`` — scatter-adds of the buffer's rows into the
+  tokens, which read every buffer row, so the dead ones are masked to
+  zero before anything multiplies them, in both passes.
+
 Counters (sown under ``intermediates``, carried out of the step by
 ``train/loop.py`` and emitted per epoch by ``JaxTrain``):
 ``moe.local_assign_share``, ``moe.load_max_over_mean``, ``moe.dropped``.
@@ -133,10 +155,32 @@ def row_tile(even_share: int, rows: int) -> int:
     return WIDE_ROW_TILE if wide else ROW_TILE
 
 
+#: a scatter-add row's device time over a gather row's, moving rows of
+#: d_model between the tokens and the sorted buffer, forward and backward.
+#: The scatter form adds ``rows`` buffer rows into the tokens; the gather
+#: form reads every one of the ``tokens * top_k`` (token, expert) pairs'
+#: rows. On a v5e (``scripts/moe_rows_probe.py``; PERF.md section 6) a
+#: scatter-form row takes 0.29-0.31 us and a gather-form row 0.21-0.22
+#: at the three sparse cells' shapes: 1.33, 1.36, 1.51. So the
+#: gathers where the buffer holds every pair (lfm2: the layer 36.4 ->
+#: 33.5 ms), the scatters at two pairs a row (kanana: 30.1 against 37.8)
+#: and at four (qwen: 28.0 against 49.0)
+SCATTER_ROW_COST = 1.36
+
+
+def gathers_rows(pairs: int, rows: int) -> bool:
+    """Whether ``SparseMoe`` moves rows between the tokens and a sorted
+    buffer of ``rows`` by gathers over all ``pairs`` (token, expert)
+    pairs (``to_buffer``, ``from_buffer``) rather than by scatter-adds
+    over the buffer's rows: whichever costs less, from the shapes."""
+    return pairs <= SCATTER_ROW_COST * rows
+
+
 def grouped_matmul(lhs, rhs, group_sizes, impl: str,
                    tile_rows: int = ROW_TILE):
     """[M,K] x [G,K,N] -> [M,N], rows grouped by ``group_sizes``; rows
-    past their sum come back undefined (the caller masks them).
+    past their sum come back undefined (kept off every live result:
+    module docstring).
     ``tile_rows``: rows of the megablox kernel's tile."""
     if impl == 'auto':
         impl = 'gmm' if jax.default_backend() == 'tpu' else 'ragged'
@@ -174,6 +218,103 @@ def _routing_top_k_bwd(k, residuals, cotangents):
 
 
 routing_top_k.defvjp(_routing_top_k_fwd, _routing_top_k_bwd)
+
+
+def buffer_slots(local, sizes, rows: int):
+    """The sorted buffer's row of every (token, expert) pair: local
+    [pairs] (the held expert, ``held`` where none), sizes [held] -> [pairs],
+    ``rows`` where the pair is not held here or did not fit. The stable
+    sort puts a pair at its expert's first row plus its rank among that
+    expert's pairs: a running count over the one-hot [pairs, held], with
+    no scatter and no gather."""
+    hit = local[:, None] == jnp.arange(sizes.shape[0])
+    rank = jnp.cumsum(hit, 0, dtype=jnp.int32) - 1
+    first = jnp.cumsum(sizes) - sizes
+    slot = jnp.sum(jnp.where(hit, rank + first, 0), -1)
+    return jnp.where(jnp.any(hit, -1) & (slot < rows), slot, rows)
+
+
+def _slot_rows(a, slots):
+    """Rows ``slots`` of a, zeros for a slot past its rows."""
+    return jnp.take(a, slots, axis=0, mode='fill', fill_value=0)
+
+
+@jax.custom_vjp
+def to_buffer(flat, token, slots):
+    """The sorted buffer: flat [n, m] -> [len(token), m], its row p the
+    token ``token[p]``'s (a buffer row past the pairs that landed holds
+    some token's row: no path reads it). ``slots`` [n, k]: each (token,
+    expert) pair's row of the buffer (``buffer_slots``), for the
+    gradient, a gather too: d flat[t] = the sum over j of d buffer at
+    ``slots[t, j]``, in float32."""
+    return flat[token]
+
+
+def _to_buffer_fwd(flat, token, slots):
+    return flat[token], slots
+
+
+def _to_buffer_bwd(slots, d_rows):
+    d_flat = jnp.sum(_slot_rows(d_rows, slots).astype(jnp.float32), 1)
+    return d_flat.astype(d_rows.dtype), None, None
+
+
+to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
+
+
+@jax.custom_vjp
+def from_buffer(ys, w, slots, order):
+    """Each token's weighted sum of its pairs' rows of the sorted buffer:
+    ys [rows, m], w [n, k] -> [n, m] in ys's dtype, out[t] = the sum over
+    j of w[t, j] ys[slots[t, j]] in float32 (a slot past ``rows`` adds
+    nothing), so ys is read at the rows a slot names and nowhere else.
+    The gradient gathers too: d ys[p] = w at the pair ``order[p]`` times
+    d out at its token (a row past the pairs that landed gets a number
+    no path reads), d w[t, j] = <d out[t], ys[slots[t, j]]>."""
+    return _from_buffer_fwd(ys, w, slots, order)[0]
+
+
+def _from_buffer_fwd(ys, w, slots, order):
+    picked = _slot_rows(ys, slots).astype(jnp.float32)
+    out = jnp.sum(picked * w[..., None], 1).astype(ys.dtype)
+    return out, (ys, w, slots, order)
+
+
+def _from_buffer_bwd(residuals, d_out):
+    ys, w, slots, order = residuals
+    weight = w.reshape(-1)[order][:, None]
+    d_ys = d_out[order // w.shape[1]].astype(jnp.float32) * weight
+    d_w = jnp.einsum('nkm,nm->nk', _slot_rows(ys, slots), d_out,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return d_ys.astype(ys.dtype), d_w, None, None
+
+
+from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
+
+
+def through_gathers(flat, top_w, local, order, sizes, experts, dtype):
+    """flat [n, m] through ``experts`` ([rows, m] -> [rows, m], the
+    sorted buffer ``order`` lays out) and back, each token's rows
+    weighted by top_w [n, k], in ``dtype``: both ways row gathers
+    (``to_buffer``, ``from_buffer``), which read every pair's row."""
+    k = top_w.shape[1]
+    slots = buffer_slots(local, sizes, order.shape[0]).reshape(-1, k)
+    ys = experts(to_buffer(flat.astype(dtype), order // k, slots))
+    return from_buffer(ys, top_w, slots, order)
+
+
+def through_scatters(flat, top_w, order, ends, experts, dtype):
+    """``through_gathers``' result by scatter-adds of the buffer's rows
+    into the tokens, in float32, which read the buffer's rows alone:
+    rows past the pairs that landed (``ends[-1]``) are undefined, in
+    both passes, so they are masked before anything multiplies them."""
+    token = order // top_w.shape[1]
+    valid = (jnp.arange(order.shape[0]) < ends[-1])[:, None]
+    xs = jnp.where(valid, flat[token], 0).astype(dtype)
+    ys = jnp.where(valid, experts(xs).astype(jnp.float32), 0) \
+        * top_w.reshape(-1)[order][:, None]
+    return jnp.zeros(flat.shape, jnp.float32).at[token].add(ys)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -306,15 +447,12 @@ class SparseMoe(nn.Module):
             tile = row_tile(n * cfg.top_k // cfg.n_experts, rows)
             order = checkpoint_name(
                 jnp.argsort(local, stable=True)[:rows], 'moe.routing')
-            token = order // cfg.top_k
             sizes = checkpoint_name(
                 jnp.bincount(local, length=held + 1)[:held], 'moe.routing')
             landed = jnp.sum(sizes)
             # groups cut to the buffer (nothing is cut at the default)
             ends = jnp.minimum(jnp.cumsum(sizes), rows)
             fitted = jnp.diff(ends, prepend=0).astype(jnp.int32)
-            valid = (jnp.arange(rows) < ends[-1])[:, None]
-            xs = jnp.where(valid, flat[token], 0).astype(dtype)
             gm = lambda a, w: grouped_matmul(  # noqa: E731
                 a, w.astype(dtype), fitted, cfg.moe_impl, tile)
             # named for a model whose `remat` policy has the room to
@@ -322,13 +460,18 @@ class SparseMoe(nn.Module):
             # identity that lowers to nothing
             named = lambda a, w, name: checkpoint_name(  # noqa: E731
                 gm(a, w), name)
-            hidden = nn.silu(named(xs, wi_gate, 'moe.hidden')) \
-                * named(xs, wi_up, 'moe.hidden')
-            # rows past the pairs that landed are undefined, in both
-            # passes: masked before anything multiplies them
-            ys = jnp.where(valid, named(hidden, wo, 'moe.out').astype(f32),
-                           0) * top_w.reshape(-1)[order][:, None]
-            out = jnp.zeros((n, m), f32).at[token].add(ys)
+
+            def experts(xs):
+                hidden = nn.silu(named(xs, wi_gate, 'moe.hidden')) \
+                    * named(xs, wi_up, 'moe.hidden')
+                return named(hidden, wo, 'moe.out')
+
+            if gathers_rows(n * cfg.top_k, rows):
+                out = through_gathers(flat, top_w, local, order, sizes,
+                                      experts, dtype)
+            else:
+                out = through_scatters(flat, top_w, order, ends, experts,
+                                       dtype)
             mean = jnp.maximum(landed / held, 1e-9)
             counters = jnp.stack([
                 landed / (n * cfg.top_k), jnp.max(sizes) / mean,
@@ -364,5 +507,7 @@ class SparseMoe(nn.Module):
 
 
 __all__ = ['MoeConfig', 'SparseMoe', 'grouped_matmul', 'routing_top_k',
+           'gathers_rows', 'buffer_slots', 'to_buffer', 'from_buffer',
+           'through_gathers', 'through_scatters',
            'buffer_rows', 'rms_norm', 'per_device', 'rotary', 'dense',
            'remat_saving']

@@ -14,7 +14,9 @@ the row ``step.op_blocks``), and whoever holds a trace joins the two:
   ``conditional`` body's, not the insides of fused computations) ->
   ``[result shape, block, backward]``;
 - ``block_split(modules, ops, table)``: a device's ``XLA Modules`` and
-  ``XLA Ops`` events + the table -> ms a step per block.
+  ``XLA Ops`` events + the table -> ms a step per block;
+- ``row_scatters(hlo_text)``: the scatters of whole rows a block's
+  top-level instructions hold (the gauge ``step.wide_scatters``).
 
 The rule, the table and the join are plain text and numbers: no jax.
 """
@@ -64,6 +66,7 @@ _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLED_RE = re.compile(
     r'\b(?:body|condition|to_apply|true_computation|false_computation)'
     r'=%?([\w.\-]+)|branch_computations=\{([^}]*)\}')
+_CALLS_RE = re.compile(r'\bcalls=%?([\w.\-]+)')
 _SUFFIX_RE = re.compile(r'\.\d+$')
 _WRAPPED_RE = re.compile(r'[\w\-]*\((.*)\)')
 
@@ -149,11 +152,10 @@ def _computations(hlo_text: str):
     return comps, entry
 
 
-def op_table(hlo_text: str) -> dict:
-    """{instruction name: [result shape, block, backward]} of every
-    top-level instruction of a compiled program's text."""
-    comps, entry = _computations(hlo_text)
-    table, todo, seen = {}, [entry], set()
+def _top_level(comps: dict, entry: str):
+    """(name, rhs, op_name) of every top-level instruction: the entry's
+    and every ``while`` / ``call`` / ``conditional`` body's."""
+    todo, seen = [entry], set()
     while todo:
         comp = todo.pop()
         if comp in seen or comp not in comps:
@@ -166,10 +168,39 @@ def op_table(hlo_text: str) -> dict:
                     todo += [one] if one else [
                         c.strip().lstrip('%') for c in many.split(',')]
             found = _OP_NAME_RE.search(rhs)
-            op_name = found.group(1) if found else ''
-            table[name] = [result_shape(rhs), block_of(op_name),
-                           int(is_backward(op_name))]
-    return table
+            yield name, rhs, found.group(1) if found else ''
+
+
+def op_table(hlo_text: str) -> dict:
+    """{instruction name: [result shape, block, backward]} of every
+    top-level instruction of a compiled program's text."""
+    comps, entry = _computations(hlo_text)
+    return {name: [result_shape(rhs), block_of(op_name),
+                   int(is_backward(op_name))]
+            for name, rhs, op_name in _top_level(comps, entry)}
+
+
+def _scatters_rows(rhs: str, comps: dict) -> bool:
+    """Whether an instruction is, or fuses, a scatter of whole rows into
+    a matrix: its result [rows, width], its indices picking rows alone."""
+    op = _OPCODE_RE.search(rhs)
+    if op and op.group(1) == 'scatter':
+        return bool(re.fullmatch(r'\w+\[\d+,\d+\]', result_shape(rhs))) \
+            and 'scatter_dims_to_operand_dims={0}' in rhs
+    called = _CALLS_RE.search(rhs)
+    return bool(called) and any(_scatters_rows(inner, comps) for _, inner
+                                in comps.get(called.group(1), ()))
+
+
+def row_scatters(hlo_text: str, block: str = 'moe_routing') -> int:
+    """The top-level instructions of ``block`` that scatter whole rows
+    (``_scatters_rows``): in ``moe_routing`` a sparse layer's scatter-adds
+    of buffer rows into the tokens [tokens, d_model], the gauge
+    ``step.wide_scatters``. The router's top-k gradient scatters into
+    (token, expert) cells, a count into a vector: neither is counted."""
+    comps, entry = _computations(hlo_text)
+    return sum(block_of(op_name) == block and _scatters_rows(rhs, comps)
+               for _, rhs, op_name in _top_level(comps, entry))
 
 
 def _event(name: str):
@@ -281,5 +312,5 @@ def load_op_table(tags) -> dict:
 
 
 __all__ = ['BLOCKS', 'ROW', 'block_of', 'scopes', 'is_backward',
-           'op_table', 'block_split', 'persist_op_table', 'load_op_table',
-           'result_shape']
+           'op_table', 'row_scatters', 'block_split', 'persist_op_table',
+           'load_op_table', 'result_shape']

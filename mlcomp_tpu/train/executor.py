@@ -753,6 +753,11 @@ class JaxTrain(Executor):
             from mlcomp_tpu.ops.flash_attention import layout_copies
             self._telemetry.gauge('step.flash_layout_copies',
                                   layout_copies(text))
+            # and how many scatters of whole rows the routing round the
+            # experts left: says whether SparseMoe moved its rows by
+            # gathers (models/decoder_parts.py)
+            from mlcomp_tpu.telemetry.op_blocks import row_scatters
+            self._telemetry.gauge('step.wide_scatters', row_scatters(text))
             if wants['op_blocks']:
                 # which model block each op of the step belongs to, for
                 # whoever joins a device trace to it (the row

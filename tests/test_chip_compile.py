@@ -489,3 +489,45 @@ def test_deepseek_v3_remat_holds_the_kernels_results(one_chip, sparse):
     assert not re.findall(r'= bf16\[2,32,8192,192\]\S* copy\(', text)
     if not sparse:      # the parent's layer: 2.023 GB
         assert compiled.memory_analysis().temp_size_in_bytes < 2.02e9
+
+
+# ---- the routing round the experts at the sparse cells' shapes
+@pytest.mark.parametrize('cell', ['lfm2-8b-a1b', 'kanana-2-30b-a3b',
+                                  'qwen3-next-80b-a3b'])
+def test_sparse_layer_moves_its_rows_by_the_rules_form(one_chip, cell):
+    """``jax.grad`` of one cell's ``SparseMoe`` (gmm) over 2 x 8,192
+    tokens: where the buffer holds every (token, expert) pair (lfm2) the
+    layer gathers both ways and its compiled program holds no scatter
+    over [16384, 2048] rows; elsewhere what ``gathers_rows`` picks, two
+    such scatters (the combine and the dispatch's gradient) or none —
+    counted as ``step.wide_scatters`` counts them."""
+    import json
+
+    import flax
+    from mlcomp_tpu.models import create_model
+    from mlcomp_tpu.models.decoder_parts import (
+        MoeConfig, SparseMoe, buffer_rows, gathers_rows,
+    )
+    from mlcomp_tpu.telemetry.op_blocks import row_scatters
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, f'benchmark/configs/{cell}.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = MoeConfig.of(create_model(**dict(kwargs, moe_impl='gmm')).cfg)
+    layer = SparseMoe(cfg, name='moe')
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x)['params']))
+
+    def loss(p, x):
+        y = layer.apply({'params': p}, x, mutable=['intermediates'])[0]
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    gathers = gathers_rows(16384 * cfg.top_k, buffer_rows(cfg, 16384))
+    assert gathers or cell != 'lfm2-8b-a1b'
+    assert row_scatters(text) == (0 if gathers else 2)

@@ -447,14 +447,17 @@ def test_every_saved_name_is_given_in_the_forward_pass(name):
 # ------------------------------------------- the model it shares code with
 #: sha256 of lfm2_moe's lowered train step (the five layers of
 #: tests/test_lfm2_moe.py's SMALL with the load rule and buffer factor 4,
-#: float32, 2 x 32 tokens, AdamW) AT THE PARENT COMMIT a21dd94, written
-#: there by the function below, private functions' counters taken off.
-#: ``SparseMoe`` gained ``shared_gate``, ``rotary`` an interleaved form,
-#: the flash kernels a value head size; with lfm2's settings they trace
-#: to what they did.
+#: float32, 2 x 32 tokens, AdamW), written by the function below,
+#: private functions' counters taken off. Read at a21dd94 until
+#: ``SparseMoe`` learnt to move its rows by gathers: at these shapes the
+#: buffer holds a row for every (token, expert) pair (128), so the layer
+#: takes ``through_gathers`` where it scattered, and the hashes were
+#: written anew with that change; ``shared_gate``, the interleaved
+#: ``rotary`` and the flash kernels' value head size still trace to what
+#: they did with lfm2's settings.
 LFM2_STEP_AT_PARENT = {
-    False: 'db565e4da1f7d77e99e6cca534269ef5ccc5b6dfb77b326c20dd0b651fa3fa97',
-    True: '451605862cffad34fbe5158e1274be6f7743016a09f5d24df14b5efd320c6cfd',
+    False: '1f7cf61e4c1002e8f2349670f781484e883ca444d72e16ddc7867544fe5ff88c',
+    True: '240c8d29390700c71a46b3832e4a403e8b05f02c64b9a4dc09b53fee0d06aba3',
 }
 
 

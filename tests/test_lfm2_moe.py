@@ -474,14 +474,18 @@ def test_every_saved_name_is_given_in_the_forward_pass(name):
 
 # ------------------------------------------- the model it shares code with
 #: sha256 of qwen3_next's lowered train step (SMALL of
-#: tests/test_qwen3_next.py, float32, 2 x 32 tokens, AdamW) AT THE PARENT
-#: COMMIT 473cefc, written there by the function below, private
-#: functions' counters taken off. ``SparseMoe`` gained a score function,
-#: a selection bias, an epsilon, a scaling factor, an optional shared
-#: expert and two names; with qwen's settings it traces to what it did.
+#: tests/test_qwen3_next.py, float32, 2 x 32 tokens, AdamW), written by
+#: the function below, private functions' counters taken off. Read at
+#: 473cefc until ``SparseMoe`` learnt to move its rows by gathers: at
+#: these shapes the worst-case buffer holds a row for every (token,
+#: expert) pair (128), so the layer takes ``through_gathers`` where it
+#: scattered, and the hashes were written anew with that change; the
+#: score function, the selection bias, the epsilon, the scaling factor,
+#: the optional shared expert and the two names still trace to nothing
+#: with qwen's settings.
 QWEN_STEP_AT_PARENT = {
-    False: 'ed8c032f0684c8022e2b8b71f0c9a1ee275e507da85b3d4f1fe70fd9ac7af362',
-    True: '756c1b6469755859ced9b066ce85444bc8dd0070fdea7a1d5026b26a72506c72',
+    False: '194ac99bb115bfca716ca3acfd43bcd0b8591787e6cc42ec6c1a826ec829963b',
+    True: '5aa05baabf4be2e2f1b1e9a18a302a33f9d38960f6424634135e20cdf34c5631',
 }
 
 

@@ -248,7 +248,33 @@ def test_a_deepseek_v3_job_writes_its_counters_and_the_kernel_gauge(
     0 on the CPU; on the chip the step of ``kanana-2-30b-a3b.steady``
     holds 46 (``tests/test_chip_compile.py`` counts a layer's: 2 flash
     calls, and 9 grouped products in a sparse layer; ``PERF.md``
-    section 3)."""
+    section 3); beside it ``step.wide_scatters``, 0: at these shapes (a
+    buffer row for every pair) the sparse layers gather."""
+    values = deepseek_job(session, tmp_path)
+    assert values('step.kernel_calls') == [0.0]
+    assert values('step.flash_layout_copies') == [0.0]
+    assert values('step.wide_scatters') == [0.0]
+    assert values('mla_attn.rows') == [4 * 16 * 3.0] * 2
+    assert values('moe.dropped') == [0.0] * 2
+    assert len(values('moe.local_assign_share')) == 2
+    assert all(v >= 1 for v in values('moe.load_max_over_mean'))
+    assert values('short_conv.rows') == []
+
+
+def test_a_deepseek_v3_job_counts_the_scatters_it_is_made_to_take(
+        session, tmp_path, monkeypatch):
+    """With the rule's constant at 0 every sparse layer scatters: the
+    gauge counts two a layer (the combine and the dispatch's gradient),
+    four for the job's two sparse layers."""
+    from mlcomp_tpu.models import decoder_parts
+    monkeypatch.setattr(decoder_parts, 'SCATTER_ROW_COST', 0.0)
+    values = deepseek_job(session, tmp_path)
+    assert values('step.wide_scatters') == [4.0]
+    assert values('moe.dropped') == [0.0] * 2
+
+
+def deepseek_job(session, tmp_path):
+    """Run a small deepseek_v3 job; returns its metric values by name."""
     from mlcomp_tpu.db.providers.telemetry import MetricProvider
     from mlcomp_tpu.train import JaxTrain
     task = make_task(session)
@@ -267,15 +293,8 @@ def test_a_deepseek_v3_job_writes_its_counters_and_the_kernel_gauge(
     ex.step, ex.task, ex.session = QuietStep(), task, session
     ex.dag, ex.additional_info = DagProvider(session).by_id(task.dag), {}
     ex.work()
-    values = lambda name: MetricProvider(session).recent_values(  # noqa: E731,E501
+    return lambda name: MetricProvider(session).recent_values(
         task.id, name)
-    assert values('step.kernel_calls') == [0.0]
-    assert values('step.flash_layout_copies') == [0.0]
-    assert values('mla_attn.rows') == [4 * 16 * 3.0] * 2
-    assert values('moe.dropped') == [0.0] * 2
-    assert len(values('moe.local_assign_share')) == 2
-    assert all(v >= 1 for v in values('moe.load_max_over_mean'))
-    assert values('short_conv.rows') == []
 
 
 def _planted(*args, **kwargs):
