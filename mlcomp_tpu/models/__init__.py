@@ -20,6 +20,7 @@ from mlcomp_tpu.models.transformer import (
 from mlcomp_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
 from mlcomp_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeLM
 from mlcomp_tpu.models.deepseek_v3 import DeepseekV3Config, DeepseekV3LM
+from mlcomp_tpu.models.ouro import OuroConfig, OuroLM
 from mlcomp_tpu.models.unet import UNet
 from mlcomp_tpu.models.vit import ViT
 
@@ -28,7 +29,7 @@ __all__ = [
     'MLP', 'ResNet', 'BasicBlock', 'Bottleneck',
     'TransformerConfig', 'TransformerLM', 'UNet', 'ViT',
     'Qwen3NextConfig', 'Qwen3NextLM', 'Lfm2MoeConfig', 'Lfm2MoeLM',
-    'DeepseekV3Config', 'DeepseekV3LM',
+    'DeepseekV3Config', 'DeepseekV3LM', 'OuroConfig', 'OuroLM',
     'ResNetEncoder', 'FPN', 'LinkNet', 'PSPNet', 'DeepLabV3',
     'PipelinedTransformerLM',
     'VGGEncoder', 'DenseNetEncoder', 'EfficientNetEncoder',

@@ -42,12 +42,14 @@ OPTIMIZER = {'optimizer'}
 #: the loss (``train/loop.py``'s scope) and the modules round the layer
 #: stack: the final norm and the untied head of ``models/transformer.py``,
 #: ``qwen3_next.py``, ``deepseek_v3.py`` (``lfm2_moe.py``'s head is its
-#: embedding table)
-EMBED_HEAD = {'loss', 'norm_final', 'lm_head'}
+#: embedding table), and ``ouro.py``'s exits (final norm, gate, head and
+#: loss, each exit's inside the scope ``exit``)
+EMBED_HEAD = {'loss', 'norm_final', 'lm_head', 'exit'}
 #: the families' language models: their own ops outside the layer stack
 #: are the embedding's lookup and its gradient, the learned positions
 #: (``transformer_lm``) and a tied head (``lfm2_moe``) — ``embed_head``
-MODELS = {'TransformerLM', 'Qwen3NextLM', 'Lfm2MoeLM', 'DeepseekV3LM'}
+MODELS = {'TransformerLM', 'Qwen3NextLM', 'Lfm2MoeLM', 'DeepseekV3LM',
+          'OuroLM'}
 #: what marks an op of the stack: a layer's scope, a scanned stack's, and
 #: ``remat``'s own (its recomputation and the gradients it hands back);
 #: the buffers a scan stacks its layers' values in are a bare

@@ -55,8 +55,8 @@ from mlcomp_tpu.train.data import (
     create_dataset, iterate_batches, place_batch, prefetch_batches,
 )
 from mlcomp_tpu.train.loop import (
-    STEP_COUNTERS, aggregate_metrics, create_train_state, loss_for_task,
-    make_eval_step,
+    SELF_SUPERVISED, STEP_COUNTERS, aggregate_metrics, create_train_state,
+    loss_for_task, make_eval_step,
     make_train_step,
 )
 from mlcomp_tpu.train.optim import make_optimizer
@@ -494,7 +494,7 @@ class JaxTrain(Executor):
         # set-up, each phase a span (a child of train.work, like the
         # epochs below); a third, `introspect`, is the AOT compile of
         # the first stage's step
-        self_supervised = self.loss_name == 'lm_ce'
+        self_supervised = self.loss_name in SELF_SUPERVISED
         inputs = self._setup_data(mesh, self_supervised)
         run = _Run(
             mesh=mesh, ck_dir=self._checkpoint_folder(),

@@ -104,7 +104,55 @@ def lm_ce_with(z_loss: float = 0.0, label_smoothing: float = 0.0,
     return loss_fn
 
 
-LOSSES = {'softmax_ce': softmax_ce, 'lm_ce': lm_ce, 'seg_ce': seg_ce}
+#: beta of the looped model's loss: the weight of the exit distribution's
+#: entropy (the paper's first-stage objective states the form, not the
+#: number; ``benchmark/configs/ouro-2.6b.json`` ``assumed``)
+ENTROPY_WEIGHT = 0.1
+
+
+def looped_lm_ce(exits, tokens, weights=None,
+                 entropy_weight: float = ENTROPY_WEIGHT):
+    """The loss of a looped language model with gated exits
+    (``models/ouro.py``): per token ``sum_t p(t) CE(z_t, next token) -
+    entropy_weight * H(p)``, the mean over each sequence's tokens and
+    then over the sequences. The model hands over ``exits``: ``states``
+    [N, B, T, D] (the normed state of each exit), ``exit_logp`` [N, B, T]
+    (log p(t)) and ``head`` [D, V]. The head and the cross-entropy run
+    exit by exit (a ``lax.map``), each under ``jax.checkpoint``, so that
+    one exit's ``[B, T, V]`` logits are live at a time, forward and
+    backward; the logits are in the states' dtype, the softmax in
+    float32. ``accuracy`` is the last exit's."""
+    states, logp, head = exits['states'], exits['exit_logp'], exits['head']
+    t = tokens.shape[1]
+    # position i predicts token i + 1; the last position predicts nothing
+    # (``live``)
+    targets = jnp.roll(tokens, -1, axis=1)
+    live = (jnp.arange(t) < t - 1).astype(jnp.float32)
+
+    @jax.checkpoint
+    def exit_ce(state):
+        with jax.named_scope('exit'):
+            logits = jnp.einsum('btd,dv->btv', state,
+                                head.astype(state.dtype))
+            logits = logits.astype(jnp.float32)
+            hit = jnp.arange(logits.shape[-1]) == targets[..., None]
+            picked = jnp.sum(jnp.where(hit, logits, 0.0), -1)
+            ce = jax.nn.logsumexp(logits, -1) - picked
+            return ce, jnp.argmax(logits, -1) == targets
+
+    ce, correct = jax.lax.map(exit_ce, states)
+    p = jnp.exp(logp)
+    per_tok = jnp.sum(p * ce, 0) + entropy_weight * jnp.sum(p * logp, 0)
+    per = jnp.sum(per_tok * live, -1) / (t - 1)
+    acc = jnp.sum(correct[-1] * live, -1) / (t - 1)
+    loss, acc = _weighted(per, acc, weights)
+    return loss, {'loss': loss, 'accuracy': acc}
+
+
+LOSSES = {'softmax_ce': softmax_ce, 'lm_ce': lm_ce, 'seg_ce': seg_ce,
+          'looped_lm_ce': looped_lm_ce}
+#: the losses whose target is the input itself (the next token)
+SELF_SUPERVISED = ('lm_ce', 'looped_lm_ce')
 
 
 def loss_for_task(task) -> Callable:
@@ -149,6 +197,8 @@ STEP_COUNTERS = {
     'gated_delta.chunks': jnp.sum,
     'short_conv.rows': jnp.sum,
     'mla_attn.rows': jnp.sum,
+    'loop.expected_exit': jnp.mean,
+    'loop.layer_rows': jnp.sum,
 }
 
 
@@ -529,5 +579,5 @@ __all__ = ['TrainState', 'make_train_step', 'make_device_train_step',
            'make_device_eval_step', 'aggregate_metrics',
            'instrumented_step',
            'create_train_state', 'state_sharding', 'place_state',
-           'loss_for_task', 'LOSSES', 'softmax_ce', 'lm_ce', 'seg_ce',
-           'lm_ce_with']
+           'loss_for_task', 'LOSSES', 'SELF_SUPERVISED', 'softmax_ce',
+           'lm_ce', 'seg_ce', 'lm_ce_with', 'looped_lm_ce']

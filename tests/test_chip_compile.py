@@ -531,3 +531,45 @@ def test_sparse_layer_moves_its_rows_by_the_rules_form(one_chip, cell):
     gathers = gathers_rows(16384 * cfg.top_k, buffer_rows(cfg, 16384))
     assert gathers or cell != 'lfm2-8b-a1b'
     assert row_scatters(text) == (0 if gathers else 2)
+
+
+def test_ouro_layer_at_its_widths(one_chip):
+    """``jax.grad`` of one `remat`ted layer of ``ouro-2.6b.steady`` at
+    its widths and 2 x 4,096 tokens: the flash kernels at 16 heads of
+    128 under the scope ``gqa_attn`` and no other kernel — the forward,
+    `remat`'s second forward (``models/ouro.py`` ``REMAT_SAVED`` holds
+    nothing: the stage's memory goes to the 32 layer applications'
+    inputs) and the one backward."""
+    import collections
+    import json
+    import re
+
+    import flax
+    from mlcomp_tpu.models import create_model, ouro
+    from mlcomp_tpu.models.decoder_parts import remat_saving
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, 'benchmark/configs/ouro-2.6b.json')) as f:
+        kwargs = json.load(f)['executor']['model']
+    cfg = create_model(**dict(kwargs, attn_impl='pallas')).cfg
+    assert cfg.remat and ouro.REMAT_SAVED == ()
+    layer = remat_saving(ouro.OuroLayer, True, ouro.REMAT_SAVED)(cfg)
+    x = jax.ShapeDtypeStruct((2, 4096, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                       sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0), x, None)['params']))
+
+    def loss(p, x):
+        y = layer.apply({'params': p}, x, None)[0]
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = collections.Counter(
+        re.search(r'gqa_attn|$', re.search(
+            r'op_name="([^"]*)"', line).group(1)).group(0)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == {'gqa_attn': 3}, kernels

@@ -140,9 +140,11 @@ def test_counters_leave_the_step():
     step = make_train_step(model, optimizer, loss_for_task('lm_ce'),
                            self_supervised=True)
     _, metrics = step(state, tokens, None)
-    # every counter but the one only `lfm2_moe`'s conv mixer sows
-    assert set(STEP_COUNTERS) - {'short_conv.rows', 'mla_attn.rows'} \
-        <= set(metrics)
+    # every counter but those only `lfm2_moe`'s conv mixer,
+    # `deepseek_v3`'s latent attention and `ouro`'s loop sow
+    assert set(STEP_COUNTERS) - {'short_conv.rows', 'mla_attn.rows',
+                                 'loop.expected_exit',
+                                 'loop.layer_rows'} <= set(metrics)
     assert 'short_conv.rows' not in metrics
     assert float(metrics['moe.dropped']) == 0
     # three linear layers of 2 sequences x 4 heads x 2 chunks of 16
